@@ -41,7 +41,9 @@ def _run_sections(sections) -> int:
 
 def main() -> int:
     from benchmarks import ablation_tiles, kernel_bench, table1_parity, table2_throughput
+    from repro.launch import compile_cache
 
+    compile_cache.enable()
     print("name,us_per_call_or_value,derived")
     if "--quick" in sys.argv[1:]:
         return _run_sections([

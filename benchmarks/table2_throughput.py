@@ -841,6 +841,9 @@ def tp_bench(
         + ([env["PYTHONPATH"]] if env.get("PYTHONPATH") else [])
     )
     env.pop("XLA_FLAGS", None)  # the child pins its own device count
+    # The child is a CPU emulation by design; on a TPU host the parent (which
+    # imported JAX) holds the chip, so the child must never reach for it.
+    env["JAX_PLATFORMS"] = "cpu"
     r = subprocess.run(
         [sys.executable, "-c", _TP_BENCH_SCRIPT, "quick" if quick else "full"],
         env=env, capture_output=True, text=True, timeout=900,

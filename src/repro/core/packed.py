@@ -38,7 +38,8 @@ class EncodingConfig:
     # Pallas interpret mode: None = auto (interpret only when no TPU backend
     # is present — see targets.resolve_interpret); True/False force it.
     interpret: bool | None = None
-    target: targets_lib.TargetSpec = targets_lib.TPU_V5E
+    # Kernel target: None = the attached device's spec (targets.current()).
+    target: targets_lib.TargetSpec | None = None
     # Pad packed tile counts to divide the mesh axes (16 in production).
     shard_multiple: int = 1
     # Serving weight quantization: "none" | "int8" (w8a8, per-channel/per-row
@@ -164,8 +165,12 @@ def linear_apply(
 
 
 def linear_out_dim(params: dict) -> int:
-    for key in ("w_packed", "w_q", "w_q4"):
+    """Padded output width; callers pass the true `n` to linear_apply."""
+    if "w_scale4" in params:  # nibbles pack N0 in halves; the scales do not
+        n1, _, n0, _ = params["w_scale4"].shape
+        return n1 * n0
+    for key in ("w_packed", "w_q"):
         if key in params:
             n1, _, n0, _ = params[key].shape
-            return n1 * n0  # padded; callers pass the true `n` to linear_apply
+            return n1 * n0
     return params["w_t"].shape[0]

@@ -56,29 +56,31 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import encoding
-from repro.kernels import pl_compat
 
 
-def _online_update(s, valid, v, m_ref, l_ref, acc_ref):
-    """One online-softmax step over a scored chunk.
+def _online_update(q, k, v, valid, m_ref, l_ref, acc_ref, i):
+    """One online-softmax step of state row-block `i` over a scored chunk.
 
-    s: (L, KV, G, C) f32 scores; valid: bool broadcastable to s;
-    v: (C, KV, D) values; scratch m/l: (L, KV, G), acc: (L, KV, G, D).
-    Fully-masked chunks leave (m, l, acc) bitwise unchanged (corr == 1).
-    """
+    q: (R, D) pre-scaled queries; k: (C, D) keys; v: (C, D) f32 values;
+    valid: bool (R, C); scratch m/l: (.., R, 1), acc: (.., R, D).  Every
+    contraction is a plain 2-D dot (the MXU's native form, whatever the GQA
+    group size).  Fully-masked chunks leave (m, l, acc) bitwise unchanged
+    (corr == 1)."""
+    s = jax.lax.dot_general(
+        q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+    )
     s = jnp.where(valid, s, -jnp.inf)
-    m_prev = m_ref[...]
-    m_new = jnp.maximum(m_prev, s.max(axis=-1))
+    m_prev = m_ref[i]
+    m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
     # Guard rows with no valid key yet: keep the exponent argument finite.
     m_safe = jnp.where(jnp.isfinite(m_new), m_new, 0.0)
-    p = jnp.exp(s - m_safe[..., None])
-    p = jnp.where(valid, p, 0.0)
+    p = jnp.where(valid, jnp.exp(s - m_safe), 0.0)
     corr = jnp.where(jnp.isfinite(m_prev), jnp.exp(m_prev - m_safe), 0.0)
-    l_ref[...] = l_ref[...] * corr + p.sum(axis=-1)
-    acc_ref[...] = acc_ref[...] * corr[..., None] + jnp.einsum(
-        "lkgc,ckd->lkgd", p, v, preferred_element_type=jnp.float32
+    l_ref[i] = l_ref[i] * corr + p.sum(axis=-1, keepdims=True)
+    acc_ref[i] = acc_ref[i] * corr + jnp.dot(
+        p, v, preferred_element_type=jnp.float32
     )
-    m_ref[...] = m_new
+    m_ref[i] = m_new
 
 
 def _init_state(m_ref, l_ref, acc_ref):
@@ -87,9 +89,28 @@ def _init_state(m_ref, l_ref, acc_ref):
     acc_ref[...] = jnp.zeros_like(acc_ref)
 
 
-def _finalize(out_ref, l_ref, acc_ref, shape, dtype):
-    out = acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)[..., None]
-    out_ref[...] = out.reshape(shape).astype(dtype)
+def _finalize(out_ref, l_ref, acc_ref):
+    out = acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
+    out_ref[0] = out.astype(out_ref.dtype)
+
+
+def _group_rows(q, kvh):
+    """(B, L, H, D) -> (B, KV, L*G, D): each KV head's query rows as one 2-D
+    slab, row l*G + r = query head kv*G + r at window position l."""
+    b, L, h, d = q.shape
+    g = h // kvh
+    return q.reshape(b, L, kvh, g, d).transpose(0, 2, 1, 3, 4).reshape(
+        b, kvh, L * g, d
+    )
+
+
+def _ungroup_rows(out, L):
+    """Inverse of _group_rows."""
+    b, kvh, lg, d = out.shape
+    g = lg // L
+    return out.reshape(b, kvh, L, g, d).transpose(0, 2, 1, 3, 4).reshape(
+        b, L, kvh * g, d
+    )
 
 
 def _norm_pos(pos, b) -> jnp.ndarray:
@@ -106,6 +127,17 @@ def _dequant_kv(kv_quant: str, k_raw, v_raw, ks, vs):
         return k_raw, v_raw
     lay = encoding.kv_layout(kv_quant)
     return lay.dequantize(k_raw, ks), lay.dequantize(v_raw, vs)
+
+
+def _head_tiles(kv_quant, kh, k_ref, v_ref, ks_ref, vs_ref):
+    """KV head `kh`'s (C, D) key tile and f32 value tile of one streamed
+    (1, C, KV, Ds) chunk, dequantized in VMEM for quantized layouts."""
+    k, v = _dequant_kv(
+        kv_quant, k_ref[0, :, kh, :], v_ref[0, :, kh, :],
+        None if ks_ref is None else ks_ref[0, :, kh, :],
+        None if vs_ref is None else vs_ref[0, :, kh, :],
+    )
+    return k, v.astype(jnp.float32)
 
 
 # ---------------------------------------------------------------------------
@@ -136,27 +168,20 @@ def _paged_decode_kernel(
     # to the last live page, so no fresh bytes moved either).
     @pl.when(j * bs <= last)
     def _():
-        d = q_ref.shape[-1]
-        qg = q_ref[0].reshape(L, kvh, g, d) * scale
-        # (bs, KV, D) — ONE pool page (+ its scale page), gathered via
-        # index map and dequantized here in VMEM for quantized layouts.
-        k, v = _dequant_kv(
-            kv_quant, k_ref[0], v_ref[0],
-            None if ks_ref is None else ks_ref[0],
-            None if vs_ref is None else vs_ref[0],
-        )
-        s = jnp.einsum(
-            "lkgd,ckd->lkgc", qg, k, preferred_element_type=jnp.float32
-        )
-        slot = j * bs + jax.lax.broadcasted_iota(jnp.int32, (1, 1, 1, bs), 3)
-        qpos = pos_b + jax.lax.broadcasted_iota(jnp.int32, (L, 1, 1, 1), 0)
+        rows = (L * g, bs)
+        slot = j * bs + jax.lax.broadcasted_iota(jnp.int32, rows, 1)
+        qpos = pos_b + jax.lax.broadcasted_iota(jnp.int32, rows, 0) // g
         valid = slot <= qpos  # masked-causal inside the verify window
-        _online_update(s, valid, v, m_ref, l_ref, acc_ref)
+        # ONE pool page (+ its scale page) per step, gathered via the index
+        # map; each KV head's (bs, D) slice serves its G query heads.
+        for kh in range(kvh):
+            k, v = _head_tiles(kv_quant, kh, k_ref, v_ref, ks_ref, vs_ref)
+            q = (q_ref[0, kh] * scale).astype(k.dtype)
+            _online_update(q, k, v, valid, m_ref, l_ref, acc_ref, kh)
 
     @pl.when(j == nb - 1)
     def _():
-        _finalize(out_ref, l_ref, acc_ref, (1, L, kvh * g, q_ref.shape[-1]),
-                  out_ref.dtype)
+        _finalize(out_ref, l_ref, acc_ref)
 
 
 @functools.partial(jax.jit, static_argnames=("kv_quant", "interpret"))
@@ -209,12 +234,10 @@ def paged_decode_attention(
             lambda bi, j, tbl, pv: (live_block(bi, j, tbl, pv), 0, 0, 0),
         )
 
-    in_specs = [
-        pl.BlockSpec((1, L, h, d), lambda bi, j, tbl, pv: (bi, 0, 0, 0)),
-        page_spec(ds),
-        page_spec(ds),
-    ]
-    operands = [q, k_pool, v_pool]
+    # Query rows grouped per KV head: (B, KV, L*G, D) slabs in and out.
+    q_spec = pl.BlockSpec((1, kvh, L * g, d), lambda bi, j, tbl, pv: (bi, 0, 0, 0))
+    in_specs = [q_spec, page_spec(ds), page_spec(ds)]
+    operands = [_group_rows(q, kvh), k_pool, v_pool]
     if quantized:
         in_specs += [page_spec(1), page_spec(1)]
         operands += [k_scale, v_scale]
@@ -223,27 +246,28 @@ def paged_decode_attention(
         num_scalar_prefetch=2,
         grid=(b, nb),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, L, h, d), lambda bi, j, tbl, pv: (bi, 0, 0, 0)),
+        out_specs=q_spec,
         scratch_shapes=[
-            pltpu.VMEM((L, kvh, g), jnp.float32),
-            pltpu.VMEM((L, kvh, g), jnp.float32),
-            pltpu.VMEM((L, kvh, g, d), jnp.float32),
+            pltpu.VMEM((kvh, L * g, 1), jnp.float32),
+            pltpu.VMEM((kvh, L * g, 1), jnp.float32),
+            pltpu.VMEM((kvh, L * g, d), jnp.float32),
         ],
     )
     kernel = functools.partial(
         _paged_decode_kernel,
         bs=bs, L=L, kvh=kvh, g=g, scale=scale, kv_quant=kv_quant,
     )
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, L, h, d), q.dtype),
-        compiler_params=pl_compat.CompilerParams(
+        out_shape=jax.ShapeDtypeStruct((b, kvh, L * g, d), q.dtype),
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,
         name="paged_decode_attention",
     )(table.astype(jnp.int32), posv, *operands)
+    return _ungroup_rows(out, L)
 
 
 # ---------------------------------------------------------------------------
@@ -276,28 +300,16 @@ def _dense_decode_kernel(
 
     @pl.when(run)
     def _():
-        d = q_ref.shape[-1]
-        qg = q_ref[0].reshape(L, kvh, g, d) * scale
-        k, v = _dequant_kv(
-            kv_quant, k_ref[0], v_ref[0],
-            None if ks_ref is None else ks_ref[0],
-            None if vs_ref is None else vs_ref[0],
-        )
-        s = jnp.einsum(
-            "lkgd,ckd->lkgc", qg, k, preferred_element_type=jnp.float32
-        )
-        slot = j * kc + jax.lax.broadcasted_iota(jnp.int32, (1, 1, 1, kc), 3)
-        qpos = pos_b + jax.lax.broadcasted_iota(jnp.int32, (L, 1, 1, 1), 0)
+        rows = (L * g, kc)
+        slot = j * kc + jax.lax.broadcasted_iota(jnp.int32, rows, 1)
+        qpos = pos_b + jax.lax.broadcasted_iota(jnp.int32, rows, 0) // g
         # Tail guard: when kc does not divide S_c the last block reads past
         # the cache (Pallas pads the edge block; content is undefined) —
         # mask those columns out of the scores AND zero their V rows so no
         # garbage bit pattern (even a NaN encoding, pre- or post-dequant)
         # can reach the accumulator through 0 * v.
         in_range = slot < s_c
-        v = jnp.where(
-            (j * kc + jax.lax.broadcasted_iota(jnp.int32, (kc, 1, 1), 0)) < s_c,
-            v, 0.0,
-        )
+        v_row = j * kc + jax.lax.broadcasted_iota(jnp.int32, (kc, 1), 0)
         if window > 0:
             # Same mask as layers.attention_decode: rows still inside the
             # window take the cheap prefix mask (nothing wrapped or aged
@@ -307,12 +319,15 @@ def _dense_decode_kernel(
             valid = jnp.where(qpos < window, slot <= qpos, ring) & in_range
         else:
             valid = (slot <= qpos) & in_range
-        _online_update(s, valid, v, m_ref, l_ref, acc_ref)
+        for kh in range(kvh):
+            k, v = _head_tiles(kv_quant, kh, k_ref, v_ref, ks_ref, vs_ref)
+            v = jnp.where(v_row < s_c, v, 0.0)
+            q = (q_ref[0, kh] * scale).astype(k.dtype)
+            _online_update(q, k, v, valid, m_ref, l_ref, acc_ref, kh)
 
     @pl.when(j == nk - 1)
     def _():
-        _finalize(out_ref, l_ref, acc_ref, (1, L, kvh * g, q_ref.shape[-1]),
-                  out_ref.dtype)
+        _finalize(out_ref, l_ref, acc_ref)
 
 
 @functools.partial(
@@ -365,12 +380,9 @@ def dense_decode_attention(
             lambda bi, j, pv: (bi, live_chunk(bi, j, pv), 0, 0),
         )
 
-    in_specs = [
-        pl.BlockSpec((1, L, h, d), lambda bi, j, pv: (bi, 0, 0, 0)),
-        chunk_spec(ds),
-        chunk_spec(ds),
-    ]
-    operands = [q, k_cache, v_cache]
+    q_spec = pl.BlockSpec((1, kvh, L * g, d), lambda bi, j, pv: (bi, 0, 0, 0))
+    in_specs = [q_spec, chunk_spec(ds), chunk_spec(ds)]
+    operands = [_group_rows(q, kvh), k_cache, v_cache]
     if quantized:
         in_specs += [chunk_spec(1), chunk_spec(1)]
         operands += [k_scale, v_scale]
@@ -379,11 +391,11 @@ def dense_decode_attention(
         num_scalar_prefetch=1,
         grid=(b, nk),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, L, h, d), lambda bi, j, pv: (bi, 0, 0, 0)),
+        out_specs=q_spec,
         scratch_shapes=[
-            pltpu.VMEM((L, kvh, g), jnp.float32),
-            pltpu.VMEM((L, kvh, g), jnp.float32),
-            pltpu.VMEM((L, kvh, g, d), jnp.float32),
+            pltpu.VMEM((kvh, L * g, 1), jnp.float32),
+            pltpu.VMEM((kvh, L * g, 1), jnp.float32),
+            pltpu.VMEM((kvh, L * g, d), jnp.float32),
         ],
     )
     kernel = functools.partial(
@@ -391,16 +403,17 @@ def dense_decode_attention(
         kc=kc, s_c=s_c, window=window, L=L, kvh=kvh, g=g, scale=scale,
         kv_quant=kv_quant,
     )
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, L, h, d), q.dtype),
-        compiler_params=pl_compat.CompilerParams(
+        out_shape=jax.ShapeDtypeStruct((b, kvh, L * g, d), q.dtype),
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,
         name="dense_decode_attention",
     )(posv, *operands)
+    return _ungroup_rows(out, L)
 
 
 # ---------------------------------------------------------------------------
@@ -410,11 +423,15 @@ def dense_decode_attention(
 def _flash_prefill_kernel(
     q_ref, k_ref, v_ref, out_ref, m_ref, l_ref, acc_ref,
     *, qc: int, kc: int, sk: int, q_offset: int, causal: bool, window: int,
-    kvh: int, g: int, scale: float,
+    g: int, scale: float,
 ):
-    i = pl.program_id(1)
-    j = pl.program_id(2)
-    nk = pl.num_programs(2)
+    """One (batch, KV head, q band, kv chunk) step.  Blocks are head-major
+    2-D slabs — q (G, qc, D) for the G query heads sharing this KV head, k/v
+    (kc, D) — so every contraction is a plain 2-D dot, whatever the group
+    size (qwen2's 12/2 heads give G = 6, which no sublane split divides)."""
+    i = pl.program_id(2)
+    j = pl.program_id(3)
+    nk = pl.num_programs(3)
 
     @pl.when(j == 0)
     def _():
@@ -425,35 +442,27 @@ def _flash_prefill_kernel(
 
     @pl.when(run)
     def _():
-        d = q_ref.shape[-1]
-        qg = q_ref[0].reshape(qc, kvh, g, d) * scale
-        s = jnp.einsum(
-            "qkgd,ckd->qkgc", qg, k_ref[0], preferred_element_type=jnp.float32
-        )  # (qc, KV, G, kc) — query-chunk axis plays the L role below
-        qpos = (
-            q_offset + i * qc
-            + jax.lax.broadcasted_iota(jnp.int32, (qc, 1, 1, 1), 0)
-        )
-        kpos = j * kc + jax.lax.broadcasted_iota(jnp.int32, (1, 1, 1, kc), 3)
+        qpos = q_offset + i * qc + jax.lax.broadcasted_iota(jnp.int32, (qc, kc), 0)
+        kpos = j * kc + jax.lax.broadcasted_iota(jnp.int32, (qc, kc), 1)
         # Edge-block guard (kc may not divide Sk): mask the scores and zero
         # the V tail so undefined padded reads can never reach the
         # accumulator (see the dense kernel note).
         valid = kpos < sk
-        v = jnp.where(
-            (j * kc + jax.lax.broadcasted_iota(jnp.int32, (kc, 1, 1), 0)) < sk,
-            v_ref[0], 0.0,
-        )
         if causal:
             valid = valid & (kpos <= qpos)
         if window > 0:
             valid = valid & (kpos > qpos - window)
-        valid = jnp.broadcast_to(valid, (qc, 1, 1, kc))
-        _online_update(s, valid, v, m_ref, l_ref, acc_ref)
+        k = k_ref[0, 0]
+        v_row = j * kc + jax.lax.broadcasted_iota(jnp.int32, (kc, 1), 0)
+        v = jnp.where(v_row < sk, v_ref[0, 0].astype(jnp.float32), 0.0)
+        for h in range(g):
+            _online_update(
+                q_ref[0, h] * scale, k, v, valid, m_ref, l_ref, acc_ref, h
+            )
 
     @pl.when(j == nk - 1)
     def _():
-        _finalize(out_ref, l_ref, acc_ref, (1, qc, kvh * g, q_ref.shape[-1]),
-                  out_ref.dtype)
+        _finalize(out_ref, l_ref, acc_ref)
 
 
 @functools.partial(
@@ -477,7 +486,9 @@ def flash_prefill_attention(
     """Tiled flash prefill: the Pallas analogue of layers.attention_chunked.
     Causal GQA with sliding-window and q-offset support (chunked prefill
     passes the absolute offset of q[:, 0]); upper-triangle KV chunks are
-    skipped (index map clamps, compute is pl.when-guarded)."""
+    skipped (index map clamps, compute is pl.when-guarded).  The kernel
+    reads head-major (B, H, S, D) operands; the transposes in and out are
+    XLA ops on this side of the call."""
     b, sq, h, d = q.shape
     _, sk, kvh, _ = k.shape
     g = h // kvh
@@ -489,36 +500,35 @@ def flash_prefill_attention(
     nq = pl.cdiv(sq, qc)
     nk = pl.cdiv(sk, kc)
 
-    def k_block(bi, i, j):
+    def k_block(bi, kv, i, j):
         if causal and window == 0:
             # Clamp beyond-diagonal chunks to the band's last needed chunk.
-            return jnp.minimum(j, (q_offset + (i + 1) * qc - 1) // kc)
-        return j
+            j = jnp.minimum(j, (q_offset + (i + 1) * qc - 1) // kc)
+        return (bi, kv, j, 0)
 
     kernel = functools.partial(
         _flash_prefill_kernel,
         qc=qc, kc=kc, sk=sk, q_offset=q_offset, causal=causal, window=window,
-        kvh=kvh, g=g, scale=scale,
+        g=g, scale=scale,
     )
+    # Query head kv*g + r serves KV head kv: a G-head block at block index kv.
+    q_spec = pl.BlockSpec((1, g, qc, d), lambda bi, kv, i, j: (bi, kv, i, 0))
+    kv_spec = pl.BlockSpec((1, 1, kc, d), k_block)
     out = pl.pallas_call(
         kernel,
-        grid=(b, nq, nk),
-        in_specs=[
-            pl.BlockSpec((1, qc, h, d), lambda bi, i, j: (bi, i, 0, 0)),
-            pl.BlockSpec((1, kc, kvh, d), lambda bi, i, j: (bi, k_block(bi, i, j), 0, 0)),
-            pl.BlockSpec((1, kc, kvh, d), lambda bi, i, j: (bi, k_block(bi, i, j), 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, qc, h, d), lambda bi, i, j: (bi, i, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((b, sq, h, d), q.dtype),
+        grid=(b, kvh, nq, nk),
+        in_specs=[q_spec, kv_spec, kv_spec],
+        out_specs=q_spec,
+        out_shape=jax.ShapeDtypeStruct((b, h, sq, d), q.dtype),
         scratch_shapes=[
-            pltpu.VMEM((qc, kvh, g), jnp.float32),
-            pltpu.VMEM((qc, kvh, g), jnp.float32),
-            pltpu.VMEM((qc, kvh, g, d), jnp.float32),
+            pltpu.VMEM((g, qc, 1), jnp.float32),
+            pltpu.VMEM((g, qc, 1), jnp.float32),
+            pltpu.VMEM((g, qc, d), jnp.float32),
         ],
-        compiler_params=pl_compat.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary", "arbitrary"),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
         name="flash_prefill_attention",
-    )(q, k, v)
-    return out
+    )(q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3))
+    return out.transpose(0, 2, 1, 3)

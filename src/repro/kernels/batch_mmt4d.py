@@ -23,7 +23,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import pl_compat
 
 
 def _batch_mmt4d_kernel(lhs_ref, rhs_ref, out_ref, acc_ref, *, k_steps: int):
@@ -83,7 +82,7 @@ def batch_mmt4d_pallas(
         ),
         out_shape=jax.ShapeDtypeStruct((bsz, m1, n1, m0, n0), out_dtype),
         scratch_shapes=[pltpu.VMEM((1, bm1, bn1, m0, n0), acc_dtype)],
-        compiler_params=pl_compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

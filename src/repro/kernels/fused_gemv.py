@@ -16,8 +16,9 @@ bandwidth-bound — so the pack and unpack move *into* the kernel:
     out  : (M, N)   plain 2-D, written in (M, BN1*N0) slabs
 
 The grid walks N only (weight streaming); the full activation row block stays
-resident in VMEM for the whole kernel, exactly like `mmt4d_gemv.py`, and the
-rhs tile relayout ((BN1, K1, N0, K0) -> (K1*K0, BN1*N0)) happens VMEM-locally.
+resident in VMEM for the whole kernel, exactly like `mmt4d_gemv.py`.  Each
+packed weight tile feeds the MXU as stored, one (N0, K0) tile per dot, so the
+kernel holds no relayouted copy of the weight block (`stream_k_tiles`).
 
 `fused_gemv_q8_pallas` is the w8a8 variant: int8 activation rows + int8 packed
 weights, s32 accumulation, with the factorized-scale epilogue
@@ -33,23 +34,43 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import pl_compat
+
+# Contract both operands' last dim: x (M, K0) @ w (N0, K0)^T — the MXU's
+# native "NT" form, so a packed weight tile feeds the dot as stored.
+_NT = (((1,), (1,)), ((), ()))
+
+
+def stream_k_tiles(lhs_ref, k1, k0, tile, acc_shape, acc_dtype):
+    """Accumulate one packed N tile's output over the K tiles:
+    sum_c lhs[:, c-th K0 slab] @ tile(c)^T.  `tile(x, c)` returns the
+    (x, w) operand pair of K tile c (a dequantizing format converts both
+    here).  The loop stays rolled, so the kernel's code size does not grow
+    with K, and every operand is a 2-D (rows, 128) slab: no in-kernel 4-D
+    relayout, and no VMEM copy of the streamed weight block."""
+
+    def body(c, acc):
+        x = lhs_ref[:, pl.ds(pl.multiple_of(c * k0, k0), k0)]
+        x, w = tile(x, c)
+        return acc + jax.lax.dot_general(
+            x, w, _NT, preferred_element_type=acc_dtype
+        )
+
+    return jax.lax.fori_loop(0, k1, body, jnp.zeros(acc_shape, acc_dtype))
 
 
 def _fused_gemv_kernel(lhs_ref, rhs_ref, out_ref):
-    """One grid step: out[:, j-block] = lhs @ relayout(rhs-block)^T (full K)."""
+    """One grid step: out[:, j-block] = lhs @ W[j-block]^T, one (M, N0)
+    column slab per packed N tile, each accumulated over all K tiles."""
     bn1, k1, n0, k0 = rhs_ref.shape
-    lhs = lhs_ref[...]  # (M, K1*K0) — implicit "pack": consumed directly.
-    # Weight tile relayout (VMEM-local): (BN1, K1, N0, K0) -> (K1*K0, BN1*N0).
-    rhs = rhs_ref[...].transpose(1, 3, 0, 2).reshape(k1 * k0, bn1 * n0)
-    # Single-shot dot per grid step: no K-revisit, no accumulator scratch.
-    out_ref[...] = jax.lax.dot_general(
-        lhs,
-        rhs,
-        dimension_numbers=(((1,), (0,)), ((), ())),
-        preferred_element_type=out_ref.dtype,
-    ).astype(out_ref.dtype)
+    m = lhs_ref.shape[0]
+    for b in range(bn1):
+        acc = stream_k_tiles(
+            lhs_ref, k1, k0, lambda x, c, b=b: (x, rhs_ref[b, c]),
+            (m, n0), jnp.float32,
+        )
+        out_ref[:, b * n0:(b + 1) * n0] = acc.astype(out_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("bn1", "out_dtype", "interpret"))
@@ -84,7 +105,7 @@ def fused_gemv_pallas(
         ],
         out_specs=pl.BlockSpec((m, bn1 * n0), lambda j: (0, j)),
         out_shape=jax.ShapeDtypeStruct((m, n1 * n0), out_dtype),
-        compiler_params=pl_compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
         ),
         interpret=interpret,
@@ -94,18 +115,17 @@ def fused_gemv_pallas(
 
 def _fused_gemv_q8_kernel(lhs_ref, rhs_ref, sa_ref, sw_ref, out_ref):
     bn1, k1, n0, k0 = rhs_ref.shape
-    lhs = lhs_ref[...]  # (M, K1*K0) int8
-    rhs = rhs_ref[...].transpose(1, 3, 0, 2).reshape(k1 * k0, bn1 * n0)
-    acc = jax.lax.dot_general(
-        lhs,
-        rhs,
-        dimension_numbers=(((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.int32,
-    )
-    # Fused factorized-scale epilogue: out = acc * s_a[m] * s_w[n].
-    sa = sa_ref[...]                      # (M, 1) f32
-    sw = sw_ref[...].reshape(1, bn1 * n0)  # (BN1, N0) -> row vector
-    out_ref[...] = (acc.astype(jnp.float32) * sa * sw).astype(out_ref.dtype)
+    m = lhs_ref.shape[0]
+    sa = sa_ref[...]  # (M, 1) f32
+    for b in range(bn1):
+        acc = stream_k_tiles(
+            lhs_ref, k1, k0, lambda x, c, b=b: (x, rhs_ref[b, c]),
+            (m, n0), jnp.int32,
+        )
+        # Fused factorized-scale epilogue: out = acc * s_a[m] * s_w[n].
+        out_ref[:, b * n0:(b + 1) * n0] = (
+            acc.astype(jnp.float32) * sa * sw_ref[b]
+        ).astype(out_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("bn1", "out_dtype", "interpret"))
@@ -135,13 +155,15 @@ def fused_gemv_q8_pallas(
             pl.BlockSpec((m, k), lambda j: (0, 0)),
             pl.BlockSpec((bn1, k1, n0, k0), lambda j: (j, 0, 0, 0)),
             pl.BlockSpec((m, 1), lambda j: (0, 0)),
-            pl.BlockSpec((bn1, n0), lambda j: (j, 0)),
+            # Scales as (N1, 1, N0): a block of bn1 rows of one lane row
+            # each, legal for any bn1 (a (bn1, N0) block needs 8 | bn1).
+            pl.BlockSpec((bn1, 1, n0), lambda j: (j, 0, 0)),
         ],
         out_specs=pl.BlockSpec((m, bn1 * n0), lambda j: (0, j)),
         out_shape=jax.ShapeDtypeStruct((m, n1 * n0), out_dtype),
-        compiler_params=pl_compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
         ),
         interpret=interpret,
         name="fused_gemv_q8",
-    )(lhs_q, rhs4_q, s_a, s_w)
+    )(lhs_q, rhs4_q, s_a, s_w.reshape(n1, 1, n0))

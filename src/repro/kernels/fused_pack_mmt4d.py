@@ -25,7 +25,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import pl_compat
 
 
 def _fused_kernel(lhs_ref, rhs_ref, out_ref, acc_ref, *, k_steps: int):
@@ -92,7 +91,7 @@ def fused_pack_mmt4d_pallas(
         out_specs=pl.BlockSpec((bm, bn1 * n0), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n1 * n0), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn1 * n0), acc_dtype)],
-        compiler_params=pl_compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

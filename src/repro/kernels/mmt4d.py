@@ -22,7 +22,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import pl_compat
 
 
 def _mmt4d_kernel(lhs_ref, rhs_ref, out_ref, acc_ref, *, k_steps: int):
@@ -93,7 +92,7 @@ def mmt4d_pallas(
         out_specs=pl.BlockSpec((bm1, bn1, m0, n0), lambda i, j, k: (i, j, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((m1, n1, m0, n0), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm1, bn1, m0, n0), acc_dtype)],
-        compiler_params=pl_compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

@@ -16,7 +16,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels import pl_compat
+from jax.experimental.pallas import tpu as pltpu
 
 
 def _mmt4d_gemv_kernel(lhs_ref, rhs_ref, out_ref):
@@ -69,7 +69,7 @@ def mmt4d_gemv_pallas(
         ],
         out_specs=pl.BlockSpec((1, bn1, m0, n0), lambda j: (0, j, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((1, n1, m0, n0), out_dtype),
-        compiler_params=pl_compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
         ),
         interpret=interpret,

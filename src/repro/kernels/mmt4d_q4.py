@@ -4,17 +4,23 @@ GEMV (decode) — the paper's Llama.cpp-Q4-class weight format, data-tiled.
 The 4-bit path exists for one reason: decode is weight-streaming-bound
 (§Roofline), and int4 halves the dominant HBM term again over w8a8.  Weights
 are stored in the mmt4d packed layout with two's-complement nibbles packed two
-per byte along K0 (byte j of a tile row holds elements 2j, 2j+1) plus one f32
-scale per `group` (default 32) consecutive K elements:
+per byte across the halves of N0 (byte [r, k] of a tile holds rows r and
+r + N0/2 of column k) plus one scale per `group` (default 16) consecutive K
+elements:
 
-    rhs4_p (N1, K1, N0, K0/2) uint8      s_w4 (N1, K1, N0, K0/group) f32
+    rhs4_p (N1, K1, N0/2, K0) uint8      s_w4 (N1, K1, N0, K0/group) bf16
+
+Packing across N0 keeps every unpack a sublane operation: the two nibble
+planes of a tile are stacked along rows, never interleaved along lanes.
 
 Unlike w8a8, the per-K-group scale cannot factor out of the contraction into
 the epilogue — each group's partial sum carries its own scale — so both
 kernels fuse the dequant *into* the contraction: nibbles unpack and scale to
 f32 VMEM-locally (per streamed weight tile, never materialized in HBM) and the
-MXU contracts f32.  Products |a_q * w_q| <= 127*7 are exact in f32; the
-activation's per-row scale s_a still factors into the epilogue.
+MXU contracts f32.  The group scales widen to one per K lane through a 0/1
+expansion matmul, exact for bf16 scales.  Products |a_q * w_q| <= 127*7 are
+exact in f32; the activation's per-row scale s_a still factors into the
+epilogue.
 
     fused_gemv_q4_pallas : decode — plain int8 activation rows in, N-streaming
                            grid, plain f32 rows out (pack/unpack-free, the
@@ -32,37 +38,42 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import pl_compat
+from repro.kernels.fused_gemv import stream_k_tiles
 
 
-def _dequant_tile(rhs_block: jnp.ndarray, sw_block: jnp.ndarray, group: int):
-    """(..., N0, K0/2) packed nibbles + (..., N0, K0/group) scales
-    -> (..., N0, K0) f32, VMEM-local."""
-    bi = rhs_block.astype(jnp.int32)
+def _dequant_tile(rhs_tile: jnp.ndarray, sw_tile: jnp.ndarray, group: int):
+    """(N0/2, K0) packed nibbles + (N0, K0/group) scales -> (N0, K0) f32,
+    VMEM-local."""
+    bi = rhs_tile.astype(jnp.int32)
     lo = ((bi & 0xF) ^ 8) - 8
     hi = ((bi >> 4) ^ 8) - 8
-    w = jnp.stack([lo, hi], axis=-1).reshape(
-        *rhs_block.shape[:-1], 2 * rhs_block.shape[-1]
+    w = jnp.concatenate([lo, hi], axis=0).astype(jnp.float32)
+    n_groups, k0 = sw_tile.shape[-1], w.shape[-1]
+    # expand[g, k] = 1 where K lane k belongs to group g.
+    expand = (
+        jax.lax.broadcasted_iota(jnp.int32, (n_groups, k0), 1) // group
+        == jax.lax.broadcasted_iota(jnp.int32, (n_groups, k0), 0)
     ).astype(jnp.float32)
-    s = jnp.broadcast_to(
-        sw_block.astype(jnp.float32)[..., :, None], (*sw_block.shape, group)
-    ).reshape(w.shape)
+    s = jnp.dot(
+        sw_tile.astype(jnp.float32), expand, preferred_element_type=jnp.float32
+    )
     return w * s
 
 
 def _fused_gemv_q4_kernel(lhs_ref, rhs_ref, sa_ref, sw_ref, out_ref, *, group):
-    bn1, k1, n0, k0p = rhs_ref.shape
-    k0 = 2 * k0p
-    lhs = lhs_ref[...].astype(jnp.float32)  # (M, K1*K0) int8 rows
-    w = _dequant_tile(rhs_ref[...], sw_ref[...], group)  # (BN1, K1, N0, K0)
-    rhs = w.transpose(1, 3, 0, 2).reshape(k1 * k0, bn1 * n0)
-    acc = jax.lax.dot_general(
-        lhs,
-        rhs,
-        dimension_numbers=(((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )
-    out_ref[...] = (acc * sa_ref[...]).astype(out_ref.dtype)
+    bn1, k1, _, k0 = rhs_ref.shape
+    m, n0 = lhs_ref.shape[0], sw_ref.shape[2]
+    sa = sa_ref[...]  # (M, 1) f32
+    for b in range(bn1):
+        acc = stream_k_tiles(
+            lhs_ref, k1, k0,
+            lambda x, c, b=b: (
+                x.astype(jnp.float32),
+                _dequant_tile(rhs_ref[b, c], sw_ref[b, c], group),
+            ),
+            (m, n0), jnp.float32,
+        )
+        out_ref[:, b * n0:(b + 1) * n0] = (acc * sa).astype(out_ref.dtype)
 
 
 @functools.partial(
@@ -70,9 +81,9 @@ def _fused_gemv_q4_kernel(lhs_ref, rhs_ref, sa_ref, sw_ref, out_ref, *, group):
 )
 def fused_gemv_q4_pallas(
     lhs_q: jnp.ndarray,   # (M, K) int8 activation rows
-    rhs4_p: jnp.ndarray,  # (N1, K1, N0, K0/2) uint8 nibble-packed weights
+    rhs4_p: jnp.ndarray,  # (N1, K1, N0/2, K0) uint8 nibble-packed weights
     s_a: jnp.ndarray,     # (M, 1) f32 per-row activation scales
-    s_w4: jnp.ndarray,    # (N1, K1, N0, K0/group) f32 per-group weight scales
+    s_w4: jnp.ndarray,    # (N1, K1, N0, K0/group) per-group weight scales
     *,
     bn1: int = 1,
     group: int = 32,
@@ -81,8 +92,8 @@ def fused_gemv_q4_pallas(
 ) -> jnp.ndarray:
     """w4a8 fused decode GEMV: out (M, N1*N0) = (a_q @ deq(w4)^T) * s_a."""
     m, k = lhs_q.shape
-    n1, k1, n0, k0p = rhs4_p.shape
-    k0 = 2 * k0p
+    n1, k1, n0h, k0 = rhs4_p.shape
+    n0 = 2 * n0h
     assert k == k1 * k0, (lhs_q.shape, rhs4_p.shape)
     assert k0 % group == 0, (k0, group)
     assert s_a.shape == (m, 1), (s_a.shape, m)
@@ -95,13 +106,13 @@ def fused_gemv_q4_pallas(
         grid=grid,
         in_specs=[
             pl.BlockSpec((m, k), lambda j: (0, 0)),
-            pl.BlockSpec((bn1, k1, n0, k0p), lambda j: (j, 0, 0, 0)),
+            pl.BlockSpec((bn1, k1, n0h, k0), lambda j: (j, 0, 0, 0)),
             pl.BlockSpec((m, 1), lambda j: (0, 0)),
             pl.BlockSpec((bn1, k1, n0, k0 // group), lambda j: (j, 0, 0, 0)),
         ],
         out_specs=pl.BlockSpec((m, bn1 * n0), lambda j: (0, j)),
         out_shape=jax.ShapeDtypeStruct((m, n1 * n0), out_dtype),
-        compiler_params=pl_compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
         ),
         interpret=interpret,
@@ -135,9 +146,10 @@ def _mmt4d_q4_kernel(
 
     @pl.when(k == k_steps - 1)
     def _epilogue():
-        acc = acc_ref[...]
-        sa = sa_ref[...]  # (BM1, M0)
-        out_ref[...] = (acc * sa[:, None, :, None]).astype(out_ref.dtype)
+        for a in range(bm1):
+            for b in range(bn1):
+                # s_a arrives as (M0, 1) columns: a per-row scale.
+                out_ref[a, b] = (acc_ref[a, b] * sa_ref[a]).astype(out_ref.dtype)
 
 
 @functools.partial(
@@ -145,9 +157,9 @@ def _mmt4d_q4_kernel(
 )
 def mmt4d_q4_pallas(
     lhs4_q: jnp.ndarray,  # (M1, K1, M0, K0) int8
-    rhs4_p: jnp.ndarray,  # (N1, K1, N0, K0/2) uint8
+    rhs4_p: jnp.ndarray,  # (N1, K1, N0/2, K0) uint8
     s_a: jnp.ndarray,     # (M1, M0) f32
-    s_w4: jnp.ndarray,    # (N1, K1, N0, K0/group) f32
+    s_w4: jnp.ndarray,    # (N1, K1, N0, K0/group)
     *,
     blocks: tuple[int, int, int] = (1, 1, 1),
     group: int = 32,
@@ -155,8 +167,9 @@ def mmt4d_q4_pallas(
     interpret: bool = False,
 ) -> jnp.ndarray:
     m1, k1, m0, k0 = lhs4_q.shape
-    n1, k1r, n0, k0p = rhs4_p.shape
-    assert (k1, k0) == (k1r, 2 * k0p), (lhs4_q.shape, rhs4_p.shape)
+    n1, k1r, n0h, k0r = rhs4_p.shape
+    n0 = 2 * n0h
+    assert (k1, k0) == (k1r, k0r), (lhs4_q.shape, rhs4_p.shape)
     assert k0 % group == 0, (k0, group)
     assert s_w4.shape == (n1, k1, n0, k0 // group), (s_w4.shape, rhs4_p.shape)
     bm1, bn1, bk1 = blocks
@@ -168,8 +181,9 @@ def mmt4d_q4_pallas(
         grid=grid,
         in_specs=[
             pl.BlockSpec((bm1, bk1, m0, k0), lambda i, j, k: (i, k, 0, 0)),
-            pl.BlockSpec((bn1, bk1, n0, k0p), lambda i, j, k: (j, k, 0, 0)),
-            pl.BlockSpec((bm1, m0), lambda i, j, k: (i, 0)),
+            pl.BlockSpec((bn1, bk1, n0h, k0), lambda i, j, k: (j, k, 0, 0)),
+            # Row scales as (M1, M0, 1) columns: legal for any bm1.
+            pl.BlockSpec((bm1, m0, 1), lambda i, j, k: (i, 0, 0)),
             pl.BlockSpec(
                 (bn1, bk1, n0, k0 // group), lambda i, j, k: (j, k, 0, 0)
             ),
@@ -177,9 +191,9 @@ def mmt4d_q4_pallas(
         out_specs=pl.BlockSpec((bm1, bn1, m0, n0), lambda i, j, k: (i, j, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((m1, n1, m0, n0), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm1, bn1, m0, n0), jnp.float32)],
-        compiler_params=pl_compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
         name="mmt4d_q4",
-    )(lhs4_q, rhs4_p, s_a, s_w4)
+    )(lhs4_q, rhs4_p, s_a.reshape(m1, m0, 1), s_w4)

@@ -21,7 +21,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import pl_compat
 
 
 def _mmt4d_q8_kernel(lhs_ref, rhs_ref, sa_ref, sw_ref, out_ref, acc_ref, *, k_steps):
@@ -47,13 +46,12 @@ def _mmt4d_q8_kernel(lhs_ref, rhs_ref, sa_ref, sw_ref, out_ref, acc_ref, *, k_st
 
     @pl.when(k == k_steps - 1)
     def _epilogue():
-        # (BM1, BN1, M0, N0) * s_a (BM1, M0) * s_w (BN1, N0)
-        acc = acc_ref[...].astype(jnp.float32)
-        sa = sa_ref[...]  # (BM1, M0)
-        sw = sw_ref[...]  # (BN1, N0)
-        out_ref[...] = (
-            acc * sa[:, None, :, None] * sw[None, :, None, :]
-        ).astype(out_ref.dtype)
+        # Per tile: acc (M0, N0) * s_a column (M0, 1) * s_w row (1, N0).
+        for a in range(bm1):
+            for b in range(bn1):
+                out_ref[a, b] = (
+                    acc_ref[a, b].astype(jnp.float32) * sa_ref[a] * sw_ref[b]
+                ).astype(out_ref.dtype)
 
 
 @functools.partial(
@@ -82,15 +80,18 @@ def mmt4d_q8_pallas(
         in_specs=[
             pl.BlockSpec((bm1, bk1, m0, k0), lambda i, j, k: (i, k, 0, 0)),
             pl.BlockSpec((bn1, bk1, n0, k0), lambda i, j, k: (j, k, 0, 0)),
-            pl.BlockSpec((bm1, m0), lambda i, j, k: (i, 0)),
-            pl.BlockSpec((bn1, n0), lambda i, j, k: (j, 0)),
+            # Scales as (M1, M0, 1) columns and (N1, 1, N0) rows: blocks
+            # of these are legal for any bm1/bn1 (a (bn1, N0) block of a
+            # 2-D array would need 8 | bn1).
+            pl.BlockSpec((bm1, m0, 1), lambda i, j, k: (i, 0, 0)),
+            pl.BlockSpec((bn1, 1, n0), lambda i, j, k: (j, 0, 0)),
         ],
         out_specs=pl.BlockSpec((bm1, bn1, m0, n0), lambda i, j, k: (i, j, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((m1, n1, m0, n0), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm1, bn1, m0, n0), jnp.int32)],
-        compiler_params=pl_compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
         name="mmt4d_q8",
-    )(lhs4_q, rhs4_q, s_a, s_w)
+    )(lhs4_q, rhs4_q, s_a.reshape(m1, m0, 1), s_w.reshape(n1, 1, n0))

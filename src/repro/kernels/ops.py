@@ -72,7 +72,7 @@ def pack_rhs(
     w_t: jnp.ndarray,
     *,
     tiles: encoding.TileSizes | None = None,
-    target: targets_lib.TargetSpec = targets_lib.TPU_V5E,
+    target: targets_lib.TargetSpec | None = None,
     shard_multiple: int = 1,
 ) -> jnp.ndarray:
     """Pack a transposed weight (N, K) into (N1, K1, N0, K0). One-time cost.
@@ -83,7 +83,8 @@ def pack_rhs(
     """
     if tiles is None:
         tiles = encoding.select_tile_sizes(
-            encoding.Phase.PREFILL, lhs_dtype=w_t.dtype, target=target
+            encoding.Phase.PREFILL, lhs_dtype=w_t.dtype,
+            target=targets_lib.resolve(target),
         )
     p4 = ref.pack(w_t, (tiles.n0, tiles.k0))
     if shard_multiple > 1:
@@ -122,7 +123,7 @@ def encoded_matmul(
     backend: str = "xla",
     m0: int | None = None,
     blocks: tuple[int, int, int] | None = None,
-    target: targets_lib.TargetSpec = targets_lib.TPU_V5E,
+    target: targets_lib.TargetSpec | None = None,
     out_dtype: Any = None,
     acc_dtype: Any = jnp.float32,
     interpret: bool | None = None,
@@ -136,6 +137,7 @@ def encoded_matmul(
     interpreted Pallas only when no TPU backend is present.
     """
     assert backend in BACKENDS, backend
+    target = targets_lib.resolve(target)
     interpret = targets_lib.resolve_interpret(interpret)
     out_dtype = out_dtype or x.dtype
     n1, k1, n0, k0 = rhs4.shape
@@ -265,19 +267,24 @@ def _fused_gemv_plan(
     Unlike the packed GEMV (whose lhs is one sublane-group row block), the
     fused kernel keeps the full (rows, K) activation block and an
     (rows, bn1*N0) f32 output slab resident alongside each streamed weight
-    tile — all three must fit the kernel's half-VMEM budget (the other half
-    is double-buffering headroom for the weight stream).  `per_tile_bytes`
-    overrides the dense-rhs tile footprint for formats whose streamed bytes
-    are not k1*n0*k0*itemsize (the nibble-packed w4a8 tile + its scales).
+    block.  Pallas double-buffers every operand (the resident lhs too), so
+    the kernel holds two of each; the weight tiles feed the MXU as stored,
+    with no relayouted copy (fused_gemv.stream_k_tiles).  That footprint
+    must fit the kernel's scoped VMEM less an eighth kept for Mosaic's own
+    scratch: at qwen2-1.5b's down projection (K = 8960, one weight tile
+    2.2 MiB) this admits bn1 = 3, the widest block the v5e compiler
+    accepts.  `per_tile_bytes` overrides the dense-rhs tile footprint for
+    formats whose streamed bytes are not k1*n0*k0*itemsize (the
+    nibble-packed w4a8 tile + its scales).
     """
-    budget = target.vmem_bytes // 2
+    budget = target.vmem_bytes * 7 // 8
     lhs_bytes = rows * k1 * k0 * lhs_itemsize
     per_tile = (
         per_tile_bytes if per_tile_bytes is not None else k1 * n0 * k0 * rhs_itemsize
     )
 
     def fits(bn1: int) -> bool:
-        return lhs_bytes + bn1 * per_tile + rows * bn1 * n0 * 4 <= budget
+        return 2 * (lhs_bytes + bn1 * per_tile + rows * bn1 * n0 * 4) <= budget
 
     bn1 = _largest_divisor_leq(n1, max(1, want_bn1))
     while bn1 > 1 and not fits(bn1):
@@ -329,7 +336,7 @@ def encoded_matmul_q8(
     phase: Phase,
     backend: str = "xla",
     blocks: tuple[int, int, int] | None = None,
-    target: targets_lib.TargetSpec = targets_lib.TPU_V5E,
+    target: targets_lib.TargetSpec | None = None,
     out_dtype: Any = None,
     interpret: bool | None = None,
 ) -> jnp.ndarray:
@@ -339,6 +346,7 @@ def encoded_matmul_q8(
     backend="fused" at decode skips the activation pack and the output unpack
     entirely: quantized rows feed `fused_gemv_q8_pallas`, whose epilogue also
     folds in the s_a*s_w scale product (one dispatch, no HBM round-trips)."""
+    target = targets_lib.resolve(target)
     interpret = targets_lib.resolve_interpret(interpret)
     out_dtype = out_dtype or x.dtype
     n1, k1, n0, k0 = rhs4_q.shape
@@ -411,8 +419,8 @@ def pack_rhs_q4(
 ) -> tuple[jnp.ndarray, jnp.ndarray]:
     """Group-quantize + pack a transposed weight (N, K) for the w4a8 path.
 
-    Returns (rhs4_p (N1, K1, N0, K0/2) uint8 nibble-packed,
-             s_w4 (N1, K1, N0, K0/group) f32 per-group scales).
+    Returns (rhs4_p (N1, K1, N0/2, K0) uint8 nibble-packed across N0,
+             s_w4 (N1, K1, N0, K0/group) bf16 per-group scales).
 
     Quantization is per-(row, K-group) int4 with MSE-clip search (one-time
     load cost); the scales tensor mirrors the weight's tile structure so the
@@ -433,7 +441,7 @@ def pack_rhs_q4(
         if pn or pk:
             rhs4 = jnp.pad(rhs4, ((0, pn), (0, pk), (0, 0), (0, 0)))
             s_w4 = jnp.pad(s_w4, ((0, pn), (0, pk), (0, 0), (0, 0)))
-    return ref.pack_nibbles(rhs4), s_w4
+    return ref.pack_nibbles(rhs4, axis=-2), s_w4
 
 
 def encoded_matmul_q4(
@@ -446,7 +454,7 @@ def encoded_matmul_q4(
     group: int = ref.Q4_GROUP,
     backend: str = "xla",
     blocks: tuple[int, int, int] | None = None,
-    target: targets_lib.TargetSpec = targets_lib.TPU_V5E,
+    target: targets_lib.TargetSpec | None = None,
     out_dtype: Any = None,
     interpret: bool | None = None,
 ) -> jnp.ndarray:
@@ -458,10 +466,11 @@ def encoded_matmul_q4(
     factors into the epilogue.  backend="fused" at decode is the
     pack/unpack-free GEMV; "pallas" (or fused outside the GEMV regime) is
     the blocked packed kernel; "xla" is the ref.mmt4d_q4 oracle."""
+    target = targets_lib.resolve(target)
     interpret = targets_lib.resolve_interpret(interpret)
     out_dtype = out_dtype or x.dtype
-    n1, k1, n0, k0p = rhs4_p.shape
-    k0 = 2 * k0p
+    n1, k1, n0h, k0 = rhs4_p.shape
+    n0 = 2 * n0h
     k = x.shape[-1]
     lead = x.shape[:-1]
     x2d = x.reshape(-1, k)
@@ -477,7 +486,7 @@ def encoded_matmul_q4(
 
     # Streamed w4 tile: nibble bytes + group-scale bytes (not a dense tile).
     scale_itemsize = jnp.dtype(s_w4.dtype).itemsize
-    q4_tile_bytes = k1 * n0 * (k0p + (k0 // group) * scale_itemsize)
+    q4_tile_bytes = k1 * (n0h * k0 + n0 * (k0 // group) * scale_itemsize)
 
     if backend == "fused" and phase is Phase.DECODE and m <= _FUSED_GEMV_MAX_ROWS:
         sub = targets_lib.sublanes_for_dtype(target, 1)

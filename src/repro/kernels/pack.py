@@ -17,7 +17,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels import pl_compat
+from jax.experimental.pallas import tpu as pltpu
 
 
 def _pack_kernel(x_ref, out_ref):
@@ -54,7 +54,7 @@ def pack_pallas(
         in_specs=[pl.BlockSpec((br1 * t0, bc1 * t1), lambda i, j: (i, j))],
         out_specs=pl.BlockSpec((br1, bc1, t0, t1), lambda i, j: (i, j, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((r1, c1, t0, t1), x.dtype),
-        compiler_params=pl_compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel"),
         ),
         interpret=interpret,
@@ -80,7 +80,7 @@ def unpack_pallas(
         in_specs=[pl.BlockSpec((br1, bc1, t0, t1), lambda i, j: (i, j, 0, 0))],
         out_specs=pl.BlockSpec((br1 * t0, bc1 * t1), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((r1 * t0, c1 * t1), x4.dtype),
-        compiler_params=pl_compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel"),
         ),
         interpret=interpret,

@@ -11,6 +11,7 @@ from __future__ import annotations
 
 import math
 
+import jax
 import jax.numpy as jnp
 
 
@@ -164,32 +165,37 @@ def quantize_rows_q4_grouped(
     return q2d, best_s
 
 
-def pack_nibbles(q: jnp.ndarray) -> jnp.ndarray:
-    """int4-valued int8 (..., C) -> uint8 (..., C/2), two's-complement nibbles.
+def pack_nibbles(q: jnp.ndarray, axis: int = -1) -> jnp.ndarray:
+    """int4-valued int8 -> uint8 with `axis` halved, two's-complement nibbles.
 
-    Byte j holds elements (2j, 2j+1): low nibble = even index.  C must be
-    even (the packed K0 tile is 128, always even)."""
-    assert q.shape[-1] % 2 == 0, q.shape
+    Byte j along `axis` holds elements j (low nibble) and j + C/2 (high
+    nibble) of that axis, so unpacking is a concatenation of the two nibble
+    planes.  Packed weights pack the N0 axis (-2): the planes then stack
+    along sublanes inside the kernel.  C must be even."""
+    axis = axis % q.ndim
+    c = q.shape[axis]
+    assert c % 2 == 0, q.shape
     qi = q.astype(jnp.int32) & 0xF
-    lo = qi[..., 0::2]
-    hi = qi[..., 1::2]
+    lo = jax.lax.slice_in_dim(qi, 0, c // 2, axis=axis)
+    hi = jax.lax.slice_in_dim(qi, c // 2, c, axis=axis)
     return (lo | (hi << 4)).astype(jnp.uint8)
 
 
-def unpack_nibbles(b: jnp.ndarray) -> jnp.ndarray:
-    """uint8 (..., P) -> int32 in [-8, 7] (..., 2P), inverse of pack_nibbles."""
+def unpack_nibbles(b: jnp.ndarray, axis: int = -1) -> jnp.ndarray:
+    """uint8 -> int32 in [-8, 7] with `axis` doubled; inverse of
+    pack_nibbles."""
     bi = b.astype(jnp.int32)
     lo = ((bi & 0xF) ^ 8) - 8
     hi = ((bi >> 4) ^ 8) - 8
-    return jnp.stack([lo, hi], axis=-1).reshape(*b.shape[:-1], 2 * b.shape[-1])
+    return jnp.concatenate([lo, hi], axis=axis)
 
 
 def dequant_rhs4_q4(
     rhs4_p: jnp.ndarray, s_w4: jnp.ndarray, group: int = Q4_GROUP
 ) -> jnp.ndarray:
-    """Nibble-packed rhs (N1, K1, N0, K0/2) + scales (N1, K1, N0, K0/group)
+    """Nibble-packed rhs (N1, K1, N0/2, K0) + scales (N1, K1, N0, K0/group)
     -> f32 (N1, K1, N0, K0): the dequantized packed weight."""
-    w = unpack_nibbles(rhs4_p).astype(jnp.float32)
+    w = unpack_nibbles(rhs4_p, axis=-2).astype(jnp.float32)
     n1, k1, n0, k0 = w.shape
     s = jnp.broadcast_to(
         s_w4.astype(jnp.float32)[..., :, None], (*s_w4.shape, group)

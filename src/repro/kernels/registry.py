@@ -344,7 +344,7 @@ def select(
     quant: str,
     phase: Phase,
     m: int,
-    target: targets_lib.TargetSpec = targets_lib.TPU_V5E,
+    target: targets_lib.TargetSpec | None = None,
     requested: str | None = None,
     blocks: tuple[int, int, int] | None = None,
     table_path: str | None = None,
@@ -357,7 +357,8 @@ def select(
     kernel that failed the finite check must not keep serving).  `shard`
     scopes the quarantine lookup: None = the effective (SPMD) level, k =
     shard k's own view (per-shard observability)."""
-    key = dispatch_key(quant, phase, m, getattr(target, "name", str(target)))
+    target_name = targets_lib.resolve(target).name
+    key = dispatch_key(quant, phase, m, target_name)
     entry = _tuned_entry(key, table_path)
     tuned_blocks = None
     if entry is not None and isinstance(entry.get("blocks"), (list, tuple)):
@@ -367,8 +368,7 @@ def select(
     resolved_blocks = blocks if blocks is not None else tuned_blocks
 
     ladder = _matmul_ladder(
-        quant, phase, m_bucket(m), getattr(target, "name", str(target)),
-        requested, table_path,
+        quant, phase, m_bucket(m), target_name, requested, table_path,
     )
     backend, source = _apply_quarantine(key, ladder, shard)
     if source == "fallback":
@@ -563,7 +563,7 @@ def select_attn(
     *,
     phase: Phase,
     s: int,
-    target: targets_lib.TargetSpec = targets_lib.TPU_V5E,
+    target: targets_lib.TargetSpec | None = None,
     requested: str | None = None,
     blocks: tuple[int, ...] | None = None,
     table_path: str | None = None,
@@ -579,7 +579,7 @@ def select_attn(
     tuning are tracked per kv-quant key (a kernel that fails on int4 pages
     must not quarantine the bf16 path), with tuned blocks inherited from
     the bf16 entry when the kv-quant key has none of its own."""
-    target_name = getattr(target, "name", str(target))
+    target_name = targets_lib.resolve(target).name
     key = attn_dispatch_key(phase, s, target_name, kv)
     bucket = s_bucket(s) if isinstance(phase, Phase) else ""
     entry = _attn_tuned_lookup(phase, bucket, kv, target_name, table_path)

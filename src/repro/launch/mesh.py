@@ -20,16 +20,8 @@ import numpy as np
 
 
 def _make_mesh(shape: tuple[int, ...], axes: tuple[str, ...]):
-    """Version-portable mesh construction.  jax >= 0.5 wants explicit
-    axis_types; 0.4.x (the pinned CI minimum) has neither AxisType nor the
-    axis_types= kwarg, so fall back to the plain device-grid Mesh."""
-    if hasattr(jax.sharding, "AxisType"):
-        return jax.make_mesh(
-            shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes)
-        )
-    n = math.prod(shape)
-    return jax.sharding.Mesh(
-        np.asarray(jax.devices()[:n]).reshape(shape), axes
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes)
     )
 
 

@@ -1,6 +1,10 @@
-"""End-to-end serving driver: continuous-batching engine on a reduced model.
+"""End-to-end serving driver: continuous-batching engine.
 
-  PYTHONPATH=src python -m repro.launch.serve --arch llama3.2-1b --requests 8
+Serves the architecture at its published widths; `--reduced` serves the
+tiny CPU smoke variant of the same family (configs/base.reduced):
+
+  PYTHONPATH=src python -m repro.launch.serve --arch qwen2-1.5b --requests 8
+  PYTHONPATH=src python -m repro.launch.serve --arch llama3.2-1b --reduced
 
 Token-budget continuous batching (one mixed chunked-prefill + decode
 dispatch per step, serving/engine.py) with streamed output:
@@ -19,6 +23,7 @@ import numpy as np
 
 from repro.configs import registry
 from repro.core.packed import EncodingConfig
+from repro.launch import compile_cache
 from repro.models import transformer as T
 from repro.serving import engine as engine_lib
 from repro.serving.config import EngineConfig
@@ -32,6 +37,8 @@ def main():
     ap.add_argument("--prompt-len", type=int, default=16)
     ap.add_argument("--max-new", type=int, default=16)
     ap.add_argument("--max-seq", type=int, default=128)
+    ap.add_argument("--reduced", action="store_true",
+                    help="serve the tiny smoke-test variant of --arch")
     ap.add_argument("--backend", default="xla", choices=["xla", "pallas", "fused", "reference"])
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--cache-mode", default="paged", choices=["paged", "dense"])
@@ -75,9 +82,14 @@ def main():
                     help="print tokens as they are committed (stream_cb)")
     args = ap.parse_args()
 
+    compile_cache.enable()
     config = EngineConfig.from_args(args)
-    cfg = registry.get_reduced(args.arch)
-    enc = EncodingConfig(enabled=True, backend=args.backend, interpret=True)
+    cfg = (registry.get_reduced if args.reduced else registry.get_config)(
+        args.arch
+    )
+    # interpret=None: Pallas kernels compile for an attached TPU and run in
+    # the interpreter only where there is none (core/targets.py).
+    enc = EncodingConfig(enabled=True, backend=args.backend)
     params = T.model_init(jax.random.PRNGKey(args.seed), cfg, enc)
 
     def stream_cb(req, tok):

@@ -22,6 +22,7 @@ from repro.checkpoint import checkpoint as ckpt_lib
 from repro.configs import registry
 from repro.core.packed import EncodingConfig
 from repro.data import pipeline as data_lib
+from repro.launch import compile_cache
 from repro.models import transformer as T
 from repro.parallel import compression
 from repro.runtime import watchdog as wd_lib
@@ -47,11 +48,9 @@ def build(args):
         over["vocab_size"] = args.vocab
     if over:
         cfg = dataclasses.replace(cfg, **over)
-    enc = EncodingConfig(
-        enabled=not args.no_encoding,
-        backend=args.backend,
-        interpret=True,
-    )
+    # interpret=None: Pallas kernels compile for an attached TPU and run in
+    # the interpreter only where there is none (core/targets.py).
+    enc = EncodingConfig(enabled=not args.no_encoding, backend=args.backend)
     return cfg, enc
 
 
@@ -78,6 +77,7 @@ def main():
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
 
+    compile_cache.enable()
     cfg, enc = build(args)
     print(f"[train] arch={cfg.name} layers={cfg.num_layers} d={cfg.d_model} "
           f"params~{cfg.param_count()/1e6:.1f}M backend={args.backend} "

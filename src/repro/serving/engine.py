@@ -69,6 +69,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import encoding as encoding_lib
+from repro.core import targets as targets_lib
 from repro.core.encoding import Phase
 from repro.core.packed import EncodingConfig
 from repro.kernels import registry as registry_lib
@@ -914,7 +915,7 @@ class Engine:
         These are what pre_dispatch faults match and what a quarantine
         demotes."""
         phase = Phase.PREFILL if kind == "prefill" else Phase.DECODE
-        target_name = getattr(self.enc.target, "name", str(self.enc.target))
+        target_name = targets_lib.resolve(self.enc.target).name
         quant = {"none": "none", "int8": "w8a8", "int4": "w4a8"}.get(
             getattr(self.enc, "weight_quant", "none"), "none"
         )

@@ -17,14 +17,6 @@ from repro.runtime import elastic, watchdog as wd_lib
 from repro.train import optimizer as opt_lib
 from repro.train import trainer as trainer_lib
 
-# jax 0.4.37 (the pinned CI minimum) predates jax.sharding.AxisType /
-# make_mesh(axis_types=...): these tests exercise the newer-jax SPMD API
-# and skip on the pinned leg (they run on the latest-jax CI leg).
-requires_axis_types = pytest.mark.skipif(
-    not hasattr(jax.sharding, "AxisType"),
-    reason="jax.sharding.AxisType not available on this jax version",
-)
-
 ENC = EncodingConfig(enabled=True, backend="xla")
 
 
@@ -103,7 +95,6 @@ def test_async_checkpointer(tmp_path):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
-@requires_axis_types
 def test_reshard_restore(tmp_path):
     """Restore with explicit shardings (single-device mesh here; the path is
     the same one the 512->256 elastic reshard takes)."""
@@ -170,7 +161,6 @@ def test_elastic_plan():
     assert p.data * p.model == 7
 
 
-@requires_axis_types
 def test_elastic_resume(tmp_path):
     cfg, state = _tiny_state()
     ckpt_lib.save(str(tmp_path), state, step=9)

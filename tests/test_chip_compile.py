@@ -1,0 +1,137 @@
+"""The serving path's Pallas kernels, compiled for a described TPU v5e chip.
+
+Interpret mode runs a kernel as written; only Mosaic, the TPU kernel
+compiler, refuses a relayout it cannot lower or a block that overflows the
+scoped VMEM.  Each case lowers one kernel at qwen2-1.5b's published widths
+(d_model 1536, 12/2 heads of 128, d_ff 8960) through the same entry point
+the model calls, with the blocks the dispatch registry picks, and compiles
+it for one chip of a `v5e:2x2` topology (~2 s each, no chip attached).
+
+The topology is described inside a module fixture only: describing it loads
+the TPU library, which one process at a time may hold.
+"""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.core import targets
+from repro.core.encoding import Phase
+from repro.kernels import attn, ops
+
+D, Q, KV, FF = 1536, 1536, 256, 8960  # qwen2-1.5b projection widths
+HEADS, KV_HEADS, HEAD_DIM = 12, 2, 128
+SLOTS, PROMPT, MAX_SEQ, BLOCK = 8, 512, 1024, 16  # chip_smoke.py's serving shape
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler in this installation
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # A program compiled for a described chip is written to the persistent
+    # cache but cannot be read back without one: keep the cache out of it.
+    cache_on = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", cache_on)
+
+
+def _compile(fn, sharding, *args):
+    """Compile `fn` for the described chip; args are ShapeDtypeStructs (or
+    pytrees of them).  Returns the compiled program's HLO text."""
+    placed = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sharding), args
+    )
+    hlo = jax.jit(fn).lower(*placed).compile().as_text()
+    assert "tpu_custom_call" in hlo  # a Mosaic kernel, not an XLA fallback
+    return hlo
+
+
+def _weight(pack, n, k):
+    """Shapes of a packed (N, K) weight, as the model stores it."""
+    return jax.eval_shape(pack, jax.ShapeDtypeStruct((n, k), jnp.bfloat16))
+
+
+@pytest.mark.parametrize(
+    "n,k", [(Q, D), (KV, D), (FF, D), (D, FF)], ids=["q_o", "k_v", "gate_up", "down"]
+)
+def test_decode_fused_gemv_compiles(one_chip, n, k):
+    """Decode matmul, 8 slots: the registry's fused GEMV with its tuned bn1,
+    capped by the VMEM plan (down, K = 8960, is the widest weight tile)."""
+    w = _weight(ops.pack_rhs, n, k)
+    _compile(
+        lambda x, w: ops.encoded_matmul(
+            x, w, n=n, phase=Phase.DECODE, backend="auto",
+            target=targets.TPU_V5E, interpret=False,
+        ),
+        one_chip, jax.ShapeDtypeStruct((SLOTS, k), jnp.bfloat16), w,
+    )
+
+
+def test_prefill_fused_gemm_compiles(one_chip):
+    """Prefill matmul over 8 prompts of 512: the fused pack+GEMM slab at
+    the down projection's K."""
+    w = _weight(ops.pack_rhs, D, FF)
+    _compile(
+        lambda x, w: ops.encoded_matmul(
+            x, w, n=D, phase=Phase.PREFILL, backend="auto",
+            target=targets.TPU_V5E, interpret=False,
+        ),
+        one_chip, jax.ShapeDtypeStruct((SLOTS * PROMPT, FF), jnp.bfloat16), w,
+    )
+
+
+@pytest.mark.parametrize("quant", ["w8a8", "w4a8"])
+def test_decode_quantized_gemv_compiles(one_chip, quant):
+    """The quantized decode GEMVs the registry selects (fused), at the down
+    projection."""
+    if quant == "w8a8":
+        rhs, scale = _weight(ops.pack_rhs_q8, D, FF)
+        fn = lambda x, w, s: ops.encoded_matmul_q8(  # noqa: E731
+            x, w, s, n=D, phase=Phase.DECODE, backend="auto",
+            target=targets.TPU_V5E, interpret=False,
+        )
+    else:
+        rhs, scale = _weight(ops.pack_rhs_q4, D, FF)
+        fn = lambda x, w, s: ops.encoded_matmul_q4(  # noqa: E731
+            x, w, s, n=D, phase=Phase.DECODE, backend="auto",
+            target=targets.TPU_V5E, interpret=False,
+        )
+    _compile(fn, one_chip, jax.ShapeDtypeStruct((SLOTS, FF), jnp.bfloat16), rhs, scale)
+
+
+@pytest.mark.parametrize("window", [1, 4], ids=["decode", "verify4"])
+def test_paged_decode_attention_compiles(one_chip, window):
+    """Paged decode attention, 12 query heads over 2 KV heads (groups of 6),
+    block size 16; window 4 is a speculative-decode verify window."""
+    pages = 1 + SLOTS * MAX_SEQ // BLOCK
+    pool = jax.ShapeDtypeStruct((pages, BLOCK, KV_HEADS, HEAD_DIM), jnp.bfloat16)
+    _compile(
+        lambda q, k, v, t, p: attn.paged_decode_attention(q, k, v, t, p),
+        one_chip,
+        jax.ShapeDtypeStruct((SLOTS, window, HEADS, HEAD_DIM), jnp.bfloat16),
+        pool, pool,
+        jax.ShapeDtypeStruct((SLOTS, MAX_SEQ // BLOCK), jnp.int32),
+        jax.ShapeDtypeStruct((SLOTS,), jnp.int32),
+    )
+
+
+def test_flash_prefill_attention_compiles(one_chip):
+    """Causal flash prefill over 8 prompts of 512 with the tuned 128/128
+    chunks."""
+    q = jax.ShapeDtypeStruct((SLOTS, PROMPT, HEADS, HEAD_DIM), jnp.bfloat16)
+    kv = jax.ShapeDtypeStruct((SLOTS, PROMPT, KV_HEADS, HEAD_DIM), jnp.bfloat16)
+    _compile(
+        lambda q, k, v: attn.flash_prefill_attention(
+            q, k, v, q_chunk=128, kv_chunk=128
+        ),
+        one_chip, q, kv, kv,
+    )

@@ -69,7 +69,13 @@ def test_fused_gemv_q8_matches_packed_q8(mnk):
 
 
 def test_fused_gemv_kernel_direct_bn1_sweep():
-    """Direct kernel call: bn1 streaming widths give identical results."""
+    """Direct kernel call: bn1 streaming widths agree.
+
+    Not bitwise: the slab width changes how XLA's interpreter fuses and
+    orders the f32 accumulation across K, which moves the last bits.  The
+    tolerance is a few f32 ulps of the K = 256 sums of unit-scale products
+    (|out| ~ 16): far below any indexing or tiling error, which would be
+    O(1)."""
     m, n, k = 8, 1024, 256
     x = _rand((m, k), jnp.float32)
     rhs4 = ops.pack_rhs(_rand((n, k), jnp.float32, seed=7))
@@ -80,7 +86,9 @@ def test_fused_gemv_kernel_direct_bn1_sweep():
         if n1 % b == 0
     ]
     for o in outs[1:]:
-        np.testing.assert_array_equal(np.asarray(outs[0]), np.asarray(o))
+        np.testing.assert_allclose(
+            np.asarray(o), np.asarray(outs[0]), rtol=1e-6, atol=1e-4
+        )
 
 
 def test_fused_backend_prefill_still_uses_gemm_slab():

@@ -6,17 +6,6 @@ import subprocess
 import sys
 import textwrap
 
-import jax
-import pytest
-
-# jax 0.4.37 (the pinned CI minimum) predates jax.sharding.AxisType /
-# make_mesh(axis_types=...): these tests exercise the newer-jax SPMD API
-# and skip on the pinned leg (they run on the latest-jax CI leg).
-requires_axis_types = pytest.mark.skipif(
-    not hasattr(jax.sharding, "AxisType"),
-    reason="jax.sharding.AxisType not available on this jax version",
-)
-
 _PP_SCRIPT = textwrap.dedent("""
     import os
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
@@ -61,7 +50,6 @@ _PP_SCRIPT = textwrap.dedent("""
 """)
 
 
-@requires_axis_types
 def test_gpipe_two_stage_subprocess():
     env = dict(os.environ)
     env["PYTHONPATH"] = os.path.abspath(

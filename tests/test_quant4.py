@@ -123,8 +123,11 @@ def test_model_level_w4a8_decision_preservation():
     where any rounding flips the pick.  The claims that hold, asserted here:
       * logits stay close: relative MSE < 0.05 (measured 0.036 at the g=16
         serving default; g=32 doubles it — docs/PERF.md),
-      * token-identical to the fp reference at every CONFIDENT position
-        (fp top-2 margin >= the median margin),
+      * token-identical to the fp reference at every DECIDED position:
+        fp top-2 margin above two standard deviations of the int4 noise on
+        a logit difference (2 * sqrt(2) * rms logit error).  Below that the
+        two candidates are a near-tie that weight rounding may order either
+        way, so a flip there is not a defect,
       * bounded regret at flip positions: the w4a8 pick's fp logit is within
         the fp max-margin of the optimum (never a materially worse token),
       * END-TO-END decode continuity: greedy decode through the serving
@@ -151,10 +154,11 @@ def test_model_level_w4a8_decision_preservation():
     am_q4 = jnp.argmax(l_q4, -1)
     top2 = jax.lax.top_k(l_fp, 2)[0]
     margin = top2[..., 0] - top2[..., 1]
-    med = jnp.median(margin)
-    confident = margin >= med
-    agree_conf = jnp.sum((am_fp == am_q4) & confident) / jnp.sum(confident)
-    assert float(agree_conf) == 1.0, float(agree_conf)
+    rms_err = jnp.sqrt(jnp.mean(jnp.square(l_q4 - l_fp)))
+    decided = margin > 2.0 * jnp.sqrt(2.0) * rms_err
+    assert int(jnp.sum(decided)) >= 4, (int(jnp.sum(decided)), float(rms_err))
+    agree = jnp.sum((am_fp == am_q4) & decided) / jnp.sum(decided)
+    assert float(agree) == 1.0, float(agree)
     # Bounded regret everywhere (in fp logit units).
     l_of_q4 = jnp.take_along_axis(l_fp, am_q4[..., None], axis=-1)[..., 0]
     l_of_fp = jnp.take_along_axis(l_fp, am_fp[..., None], axis=-1)[..., 0]

@@ -72,6 +72,54 @@ def test_unknown_target_falls_back_to_reference():
     assert choice.blocks is None
 
 
+class _Device:
+    def __init__(self, kind):
+        self.device_kind = kind
+
+
+@pytest.mark.parametrize(
+    "kind,expected",
+    [("TPU v5 lite", targets_lib.TPU_V5E), ("TPU v5e", targets_lib.TPU_V5E),
+     ("TPU v9 ultra", None)],
+)
+def test_attached_tpu_target_is_keyed_by_device_kind(monkeypatch, kind, expected):
+    """On a TPU backend, select()'s default target comes from the device
+    kind; a kind missing from DEVICE_TARGETS is an error, never another
+    chip's figures."""
+    import jax
+
+    monkeypatch.setattr(targets_lib, "has_tpu_backend", lambda: True)
+    monkeypatch.setattr(jax, "devices", lambda *a: [_Device(kind)])
+    targets_lib.current.cache_clear()
+    try:
+        if expected is None:
+            with pytest.raises(ValueError, match=kind):
+                registry.select(quant="none", phase=Phase.DECODE, m=1)
+        else:
+            assert targets_lib.resolve(None) is expected
+            choice = registry.select(quant="none", phase=Phase.DECODE, m=1)
+            assert choice.source != "fallback"
+    finally:
+        targets_lib.current.cache_clear()
+
+
+def test_broken_runtime_does_not_turn_on_interpret_mode(monkeypatch):
+    """A JAX whose backend fails to start raises; it is not taken for a CPU
+    host with interpret mode switched on."""
+    import jax
+
+    def broken():
+        raise RuntimeError("TPU backend failed to initialize")
+
+    monkeypatch.setattr(jax, "default_backend", broken)
+    targets_lib.has_tpu_backend.cache_clear()
+    try:
+        with pytest.raises(RuntimeError, match="failed to initialize"):
+            targets_lib.resolve_interpret(None)
+    finally:
+        targets_lib.has_tpu_backend.cache_clear()
+
+
 def test_unknown_quant_falls_back_to_reference():
     choice = registry.select(quant="w2a2", phase=Phase.DECODE, m=1)
     assert choice.backend == "reference"

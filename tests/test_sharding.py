@@ -17,21 +17,12 @@ from repro.core.packed import EncodingConfig
 from repro.models import transformer as T
 from repro.parallel import sharding
 
-# jax 0.4.37 (the pinned CI minimum) predates jax.sharding.AxisType /
-# make_mesh(axis_types=...): these tests exercise the newer-jax SPMD API
-# and skip on the pinned leg (they run on the latest-jax CI leg).
-requires_axis_types = pytest.mark.skipif(
-    not hasattr(jax.sharding, "AxisType"),
-    reason="jax.sharding.AxisType not available on this jax version",
-)
-
 
 def _mesh11():
     return jax.make_mesh((1, 1), ("data", "model"),
                          axis_types=(jax.sharding.AxisType.Auto,) * 2)
 
 
-@requires_axis_types
 def test_sanitize_drops_nondividing_axes():
     mesh = _mesh11()
     # 1x1 mesh divides everything; use spec structure checks instead.
@@ -39,7 +30,6 @@ def test_sanitize_drops_nondividing_axes():
     assert s == P("data", "model")
 
 
-@requires_axis_types
 def test_param_specs_classification():
     mesh = _mesh11()
     cfg = registry.get_reduced("qwen2-1.5b")
@@ -59,7 +49,6 @@ def test_param_specs_classification():
     assert all(x is None for x in norm)
 
 
-@requires_axis_types
 def test_moe_expert_specs():
     mesh = _mesh11()
     cfg = registry.get_reduced("mixtral-8x22b")
@@ -116,7 +105,6 @@ _SPMD_SCRIPT = textwrap.dedent("""
 """)
 
 
-@requires_axis_types
 def test_spmd_multidevice_train_subprocess():
     """Real 8-device SPMD training steps (4x2 mesh) in a subprocess."""
     env = dict(os.environ)
@@ -169,7 +157,6 @@ _DECODE_SPMD_SCRIPT = textwrap.dedent("""
 """)
 
 
-@requires_axis_types
 def test_spmd_decode_subprocess():
     """Sharded MoE prefill+decode on 8 devices."""
     env = dict(os.environ)
