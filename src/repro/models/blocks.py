@@ -47,22 +47,25 @@ def attn_block_init(key, cfg: ModelConfig, enc) -> dict:
 
 
 def attn_block_apply(params, x, *, cfg, enc, phase, cache, pos, extra=None):
-    h, new_cache = L.attention_apply(
-        params["attn"],
-        L.norm_apply(params["ln1"], x, cfg),
-        cfg=cfg,
-        enc=enc,
-        phase=phase,
-        cache=cache,
-        pos=pos,
-    )
-    x = x + h
-    y = L.norm_apply(params["ln2"], x, cfg)
-    if cfg.num_experts:
-        f, aux = L.moe_apply(params["moe"], y, cfg=cfg, enc=enc, phase=phase)
-    else:
-        f, aux = L.mlp_apply(params["mlp"], y, cfg=cfg, enc=enc, phase=phase), _zero_aux()
-    return x + f, new_cache, aux
+    # Named scopes tag every device op of a trace with its region.
+    with jax.named_scope("attn"):
+        h, new_cache = L.attention_apply(
+            params["attn"],
+            L.norm_apply(params["ln1"], x, cfg),
+            cfg=cfg,
+            enc=enc,
+            phase=phase,
+            cache=cache,
+            pos=pos,
+        )
+        x = x + h
+    with jax.named_scope("mlp"):
+        y = L.norm_apply(params["ln2"], x, cfg)
+        if cfg.num_experts:
+            f, aux = L.moe_apply(params["moe"], y, cfg=cfg, enc=enc, phase=phase)
+        else:
+            f, aux = L.mlp_apply(params["mlp"], y, cfg=cfg, enc=enc, phase=phase), _zero_aux()
+        return x + f, new_cache, aux
 
 
 def attn_cache_init(cfg, batch, max_seq):

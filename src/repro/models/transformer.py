@@ -260,8 +260,21 @@ def forward(
         if tail:
             (x, aux), _ = tail_body((x, aux), (params["tail"], tuple([None] * len(tail))))
     else:
+        groups = params["groups"]
+
+        def serve_body(carry, xs):
+            # The layer's parameters are sliced here, under their own scope,
+            # so that a trace tells the per-layer weight copies apart from
+            # the cache's.
+            i, gc = xs
+            with jax.named_scope("layer_params"):
+                gp = jax.tree.map(
+                    lambda a: jax.lax.dynamic_index_in_dim(a, i, keepdims=False), groups
+                )
+            return body(carry, (gp, gc))
+
         (x, aux), new_group_caches = jax.lax.scan(
-            body, (x, aux0), (params["groups"], caches["groups"])
+            serve_body, (x, aux0), (jnp.arange(n_groups), caches["groups"])
         )
         new_caches = {"groups": new_group_caches}
         if tail:
@@ -285,16 +298,18 @@ def forward(
         x = jnp.take_along_axis(x, idx[..., None], axis=1)
     elif last_logits_only:
         x = x[:, -1:, :]
-    x = L.norm_apply(params["final_norm"], x, cfg)
-    if cfg.tie_embeddings:
-        logits = jnp.einsum(
-            "bsd,vd->bsv", x, params["embed"], preferred_element_type=jnp.float32
-        )[..., : cfg.vocab_size]
-    else:
-        logits = packed.linear_apply(
-            params["head"], x, n=cfg.vocab_size, phase=phase, enc=enc,
-            out_dtype=jnp.float32,
-        )
+    with jax.named_scope("final_norm"):
+        x = L.norm_apply(params["final_norm"], x, cfg)
+    with jax.named_scope("head"):
+        if cfg.tie_embeddings:
+            logits = jnp.einsum(
+                "bsd,vd->bsv", x, params["embed"], preferred_element_type=jnp.float32
+            )[..., : cfg.vocab_size]
+        else:
+            logits = packed.linear_apply(
+                params["head"], x, n=cfg.vocab_size, phase=phase, enc=enc,
+                out_dtype=jnp.float32,
+            )
     return logits, new_caches, aux
 
 

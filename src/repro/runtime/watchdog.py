@@ -9,18 +9,20 @@ flags, requests an elastic re-mesh (runtime/elastic.py).
 
 `DecodeStepWatchdog` promotes the same EWMA machinery into the serving
 engine's step loop (serving/engine.py wires it into Engine.stats): per-step
-latency EWMA, stall detection (a step slower than `threshold x EWMA` after
-warmup), and p50/p99 over a bounded recent-step window.  A stalled decode
-stream is the first symptom of every fault class the chaos harness injects
-(pool livelock, quarantine recompile storms, clock skew), so the watchdog is
-the observable the degradation ladder is judged by (docs/ROBUSTNESS.md).
+latency EWMA and stall detection (a step slower than `threshold x EWMA`
+after warmup).  It keeps no clock: the engine feeds it each step's
+`engine.step` span (serving/tracing.py), and its p50/p99 are taken over the
+durations the caller passes to `summary` (the tracer's recent step
+records).  A stalled decode stream is the first symptom of every fault
+class the chaos harness injects (pool livelock, quarantine recompile storms,
+clock skew), so the watchdog is the observable the degradation ladder is
+judged by (docs/ROBUSTNESS.md).
 
-Clock is injectable so tests drive it deterministically.
+StepWatchdog's clock is injectable so tests drive it deterministically.
 """
 
 from __future__ import annotations
 
-import collections
 import dataclasses
 import time
 from typing import Callable
@@ -84,42 +86,26 @@ class StepWatchdog:
 class DecodeStepWatchdog:
     """Serving-side step watchdog: EWMA + stall flags + latency percentiles.
 
-    One instance per Engine.  `step_start()` / `step_end()` bracket each
-    engine step (step_end is exception-safe via try/finally in the engine
-    loop); `summary()` is merged into Engine.stats["watchdog"].  `window`
-    bounds the percentile buffer so a long-lived engine never grows state.
+    One instance per Engine.  `observe(seconds)` takes each engine step's
+    duration, the engine's `engine.step` span (also for a step that
+    raised); `summary(durations)` is merged into Engine.stats["watchdog"],
+    its percentiles taken over `durations`.
     """
 
-    def __init__(
-        self,
-        cfg: WatchdogConfig = WatchdogConfig(),
-        *,
-        clock: Callable[[], float] = time.monotonic,
-        window: int = 512,
-    ):
+    def __init__(self, cfg: WatchdogConfig = WatchdogConfig()):
         self.cfg = cfg
-        self.clock = clock
         self.ewma: float | None = None
         self.steps = 0
         self.stalls = 0
         self.last_stalled = False
         self.last_duration: float = 0.0
-        self._start: float | None = None
-        self._recent: collections.deque[float] = collections.deque(maxlen=window)
 
-    def step_start(self) -> None:
-        self._start = self.clock()
-
-    def step_end(self) -> bool:
-        """Record one step; returns True when this step counts as a stall
-        (post-warmup step slower than threshold x the running EWMA)."""
-        if self._start is None:
-            return False  # step_start never ran (exception before the bracket)
-        dur = max(self.clock() - self._start, 0.0)
-        self._start = None
+    def observe(self, seconds: float) -> bool:
+        """Record one step of `seconds`; returns True when it counts as a
+        stall (post-warmup step slower than threshold x the running EWMA)."""
+        dur = max(float(seconds), 0.0)
         self.steps += 1
         self.last_duration = dur
-        self._recent.append(dur)
         stalled = (
             self.steps > self.cfg.warmup_steps
             and self.ewma is not None
@@ -139,18 +125,15 @@ class DecodeStepWatchdog:
             self.ewma = a * dur + (1 - a) * self.ewma
         return bool(stalled)
 
-    def percentile(self, q: float) -> float:
-        if not self._recent:
-            return 0.0
-        return float(np.percentile(np.asarray(self._recent), q))
-
-    def summary(self) -> dict:
+    def summary(self, durations) -> dict:
+        """EWMA, stall counts, and p50/p99 over step `durations` (seconds)."""
+        d = np.asarray(durations, np.float64)
         return {
             "steps": self.steps,
             "ewma_ms": 1e3 * (self.ewma or 0.0),
             "last_ms": 1e3 * self.last_duration,
-            "p50_ms": 1e3 * self.percentile(50),
-            "p99_ms": 1e3 * self.percentile(99),
+            "p50_ms": 1e3 * float(np.percentile(d, 50)) if d.size else 0.0,
+            "p99_ms": 1e3 * float(np.percentile(d, 99)) if d.size else 0.0,
             "stalls": self.stalls,
             "stalled": self.last_stalled,
         }

@@ -51,10 +51,20 @@ offending slot (finish-with-error; co-batched rows commit normally), and a
 dispatch that raises demotes its registry key down the requested -> tuned ->
 policy -> fallback ladder (kernels/registry.demote) for the rest of the
 process, recorded in stats["degraded"].  A DecodeStepWatchdog
-(runtime/watchdog.py) brackets every step: EWMA step latency, stall flags,
-p50/p99 — surfaced in stats["watchdog"].  All fault paths are driven through
+(runtime/watchdog.py) reads every step's `engine.step` span: EWMA step
+latency and stall flags, with p50/p99 over the tracer's last 512 step
+records — surfaced in stats["watchdog"].  All fault paths are driven through
 injectable hooks (`fault_hooks`, `clock`) so the chaos layer
 (serving/faults.py) needs no monkeypatching.
+
+Tracing (serving/tracing.py): `Engine.tracer` puts spans at the step's phase
+boundaries — engine.step, engine.reap, engine.admit (with engine.prefill and
+engine.scatter inside it), engine.pages, engine.tables, engine.dispatch,
+engine.wait (every host block on device results) and engine.commit — as
+profiler TraceAnnotations timed on the engine clock, and keeps one record per
+step with its counters.  stats["trace"] holds the per-span totals (count,
+seconds, self seconds) and the counters' totals.  The step builders and the
+model carry `jax.named_scope`s, so device ops in a trace name their region.
 """
 
 from __future__ import annotations
@@ -80,6 +90,7 @@ from repro.runtime import watchdog as watchdog_lib
 from repro.serving import faults as faults_lib
 from repro.serving import paged as paged_lib
 from repro.serving import spec as spec_lib
+from repro.serving import tracing as tracing_lib
 from repro.serving.config import EngineConfig
 
 
@@ -88,10 +99,11 @@ def make_prefill_step(cfg, enc: EncodingConfig) -> Callable:
         batch = {"tokens": tokens, **(extras or {})}
         # Serving prefill only needs the final position's logits (the first
         # sampled token); (B, S, V) is never materialized.
-        logits, caches, _ = T.forward(
-            params, batch, cfg=cfg, enc=enc, phase=Phase.PREFILL, caches=caches,
-            last_logits_only=True,
-        )
+        with jax.named_scope("prefill"):
+            logits, caches, _ = T.forward(
+                params, batch, cfg=cfg, enc=enc, phase=Phase.PREFILL, caches=caches,
+                last_logits_only=True,
+            )
         return logits, caches
 
     return prefill
@@ -107,10 +119,11 @@ def make_suffix_prefill_step(cfg, enc: EncodingConfig) -> Callable:
     the attention slice `cache[:, :pos]` needs a compile-time length."""
 
     def suffix_prefill(params, tokens, caches, pos):
-        logits, caches, _ = T.forward(
-            params, {"tokens": tokens}, cfg=cfg, enc=enc, phase=Phase.PREFILL,
-            caches=caches, pos=pos, last_logits_only=True,
-        )
+        with jax.named_scope("prefill"):
+            logits, caches, _ = T.forward(
+                params, {"tokens": tokens}, cfg=cfg, enc=enc, phase=Phase.PREFILL,
+                caches=caches, pos=pos, last_logits_only=True,
+            )
         return logits, caches
 
     return suffix_prefill
@@ -185,19 +198,21 @@ def make_decode_step(cfg, enc: EncodingConfig, *, sample: str = "greedy") -> Cal
         def decode(params, caches, token, pos):
             """token: (B, 1) int32; pos: () or (B,) int32 — position of
             `token` (per-row when vectorized over slot positions)."""
-            logits, caches = _forward(params, caches, token, pos)
-            nxt = jnp.argmax(logits[:, -1, :], axis=-1).astype(jnp.int32)
+            with jax.named_scope("decode"):
+                logits, caches = _forward(params, caches, token, pos)
+                nxt = jnp.argmax(logits[:, -1, :], axis=-1).astype(jnp.int32)
             return nxt[:, None], logits, caches
 
         return decode
 
     def decode_sampled(params, caches, token, pos, key, temp):
-        logits, caches = _forward(params, caches, token, pos)
-        last = logits[:, -1, :].astype(jnp.float32)
-        greedy = jnp.argmax(last, axis=-1).astype(jnp.int32)
-        scaled = last / jnp.maximum(temp, 1e-6)[:, None]
-        sampled = jax.random.categorical(key, scaled, axis=-1).astype(jnp.int32)
-        nxt = jnp.where(temp > 0, sampled, greedy)
+        with jax.named_scope("decode"):
+            logits, caches = _forward(params, caches, token, pos)
+            last = logits[:, -1, :].astype(jnp.float32)
+            greedy = jnp.argmax(last, axis=-1).astype(jnp.int32)
+            scaled = last / jnp.maximum(temp, 1e-6)[:, None]
+            sampled = jax.random.categorical(key, scaled, axis=-1).astype(jnp.int32)
+            nxt = jnp.where(temp > 0, sampled, greedy)
         return nxt[:, None], logits, caches
 
     return decode_sampled
@@ -217,15 +232,16 @@ def make_verify_step(cfg, enc: EncodingConfig) -> Callable:
     """
 
     def verify(params, caches, tokens, pos):
-        logits, caches, _ = T.forward(
-            params,
-            {"tokens": tokens},
-            cfg=cfg,
-            enc=enc,
-            phase=Phase.DECODE,
-            caches=caches,
-            pos=pos,
-        )
+        with jax.named_scope("verify"):
+            logits, caches, _ = T.forward(
+                params,
+                {"tokens": tokens},
+                cfg=cfg,
+                enc=enc,
+                phase=Phase.DECODE,
+                caches=caches,
+                pos=pos,
+            )
         return logits, caches
 
     return verify
@@ -249,16 +265,17 @@ def make_mixed_step(cfg, enc: EncodingConfig) -> Callable:
     """
 
     def mixed(params, caches, tokens, pos, logits_idx):
-        logits, caches, _ = T.forward(
-            params,
-            {"tokens": tokens},
-            cfg=cfg,
-            enc=enc,
-            phase=Phase.DECODE,
-            caches=caches,
-            pos=pos,
-            logits_idx=logits_idx,
-        )
+        with jax.named_scope("mixed"):
+            logits, caches, _ = T.forward(
+                params,
+                {"tokens": tokens},
+                cfg=cfg,
+                enc=enc,
+                phase=Phase.DECODE,
+                caches=caches,
+                pos=pos,
+                logits_idx=logits_idx,
+            )
         return logits, caches
 
     return mixed
@@ -317,6 +334,9 @@ def count_calls(fn):
     wrapped.calls = 0
     return wrapped
 
+
+# Steps the watchdog's p50/p99 are taken over (the tracer's newest records).
+WATCHDOG_WINDOW = 512
 
 REQUEST_STATUSES = (
     "queued", "running", "ok", "cancelled", "expired", "error", "rejected",
@@ -612,19 +632,21 @@ class Engine:
         # ---- lifecycle / robustness (docs/ROBUSTNESS.md) -------------------
         # max_queue: admission-queue bound — submit() returns Rejected
         #   ("queue_full") past it instead of growing without bound.
-        # clock: injectable monotonic clock (seconds) for deadlines and the
-        #   step watchdog; the chaos layer passes FaultSchedule.clock so
-        #   clock-skew faults are visible.
+        # clock: injectable monotonic clock (seconds) for deadlines, the
+        #   tracer's spans and so the step watchdog; perf_counter by default,
+        #   the clock a benchmark's window bounds use.  The chaos layer
+        #   passes FaultSchedule.clock so clock-skew faults are visible.
         # fault_hooks: object with on_step_begin / pre_dispatch /
         #   corrupt_slots / held_pages (serving/faults.FaultSchedule) —
         #   injection points, all no-ops when None.
         # logits_guard: non-finite check on committed logits; quarantines the
         #   offending slot only (measured overhead in docs/ROBUSTNESS.md).
         self.max_queue = config.max_queue
-        self.clock = clock if clock is not None else time.monotonic
+        self.clock = clock if clock is not None else time.perf_counter
         self.hooks = fault_hooks
         self.logits_guard = bool(config.logits_guard)
-        self.watchdog = watchdog_lib.DecodeStepWatchdog(clock=self.clock)
+        self.tracer = tracing_lib.Tracer(self.clock)
+        self.watchdog = watchdog_lib.DecodeStepWatchdog()
         self.rejected: list[Request] = []
         self.degraded: list[dict] = []
         self.lifecycle = {
@@ -1004,6 +1026,7 @@ class Engine:
         req.status = status
         req.error = error
         self.finished.append(req)
+        self.tracer.note("finished", req.uid)
         self.lifecycle[status] = self.lifecycle.get(status, 0) + 1
 
     def _admission_reap(self, req: Request) -> None:
@@ -1067,9 +1090,10 @@ class Engine:
                 logits = logits.at[jnp.asarray(forced, jnp.int32)].set(jnp.nan)
         if not self.logits_guard:
             return frozenset()
-        ok = np.asarray(
-            jnp.all(jnp.isfinite(logits), axis=tuple(range(1, logits.ndim)))
-        )
+        with self.tracer.span("engine.wait"):
+            ok = np.asarray(
+                jnp.all(jnp.isfinite(logits), axis=tuple(range(1, logits.ndim)))
+            )
         bad = frozenset(s for s in active if not ok[s])
         if bad:
             self.lifecycle["guard_trips"] += len(bad)
@@ -1123,6 +1147,7 @@ class Engine:
         req.done = True
         req.status = "ok"
         self.finished.append(req)
+        self.tracer.note("finished", req.uid)
 
     def _admit_paged(self):
         free = [s for s in range(self.slots) if self.slot_req[s] is None]
@@ -1187,17 +1212,21 @@ class Engine:
                 min(1 << (maxlen - 1).bit_length(),
                     self.num_blocks * self.block_size),
             )
-            toks = np.zeros((len(batch), lp), np.int32)
-            for i, (_, r, _) in enumerate(batch):
-                toks[i, : len(r.prompt)] = r.prompt
-            tmp = T.cache_init(self.cfg, len(batch), lp)
-            _, tmp = self._dispatch(
-                "prefill", "prefill_fn", self.params, jnp.asarray(toks), tmp
-            )
-            self._scatter_prefill(tmp, batch)
+            with self.tracer.span("engine.prefill"):
+                toks = np.zeros((len(batch), lp), np.int32)
+                for i, (_, r, _) in enumerate(batch):
+                    toks[i, : len(r.prompt)] = r.prompt
+                tmp = T.cache_init(self.cfg, len(batch), lp)
+                _, tmp = self._dispatch(
+                    "prefill", "prefill_fn", self.params, jnp.asarray(toks), tmp
+                )
+            self.tracer.add("prefill_tokens", sum(len(r.prompt) for _, r, _ in batch))
+            with self.tracer.span("engine.scatter"):
+                self._scatter_prefill(tmp, batch)
         for s, r, plan, lead in suffix:
             self._prefill_suffix(r, plan, lead)
         for s, r, plan in batch + [(s, r, p) for s, r, p, _ in suffix]:
+            self.tracer.note("admitted", r.uid)
             self.slot_req[s] = r
             r.status = "running"
             self.slot_pos[s] = len(r.prompt)
@@ -1324,15 +1353,18 @@ class Engine:
         skip = lead * bs
         plen = len(req.prompt)
         lp = max(bs, min(1 << (plen - 1).bit_length(), self.num_blocks * bs))
-        tmp = T.cache_init(self.cfg, 1, lp)
-        tmp = self._gather_prefix(tmp, plan.pages[:lead])
-        toks = np.zeros((1, lp - skip), np.int32)
-        toks[0, : plen - skip] = np.asarray(req.prompt[skip:], np.int32)
-        _, tmp = self._dispatch(
-            "prefill", "suffix_prefill_fn", self.params, jnp.asarray(toks),
-            tmp, skip,
-        )
-        self._scatter_prefill(tmp, [(None, req, plan)])
+        with self.tracer.span("engine.prefill"):
+            tmp = T.cache_init(self.cfg, 1, lp)
+            tmp = self._gather_prefix(tmp, plan.pages[:lead])
+            toks = np.zeros((1, lp - skip), np.int32)
+            toks[0, : plen - skip] = np.asarray(req.prompt[skip:], np.int32)
+            _, tmp = self._dispatch(
+                "prefill", "suffix_prefill_fn", self.params, jnp.asarray(toks),
+                tmp, skip,
+            )
+        self.tracer.add("prefill_tokens", plen - skip)
+        with self.tracer.span("engine.scatter"):
+            self._scatter_prefill(tmp, [(None, req, plan)])
 
     def _live_table_width(self) -> int:
         """Logical block-table width the NEXT decode dispatch needs: the max
@@ -1388,6 +1420,7 @@ class Engine:
         self.queue.appendleft(req)
         self._tables_dirty = True
         self.preemptions += 1
+        self.tracer.note("preempted", req.uid)
 
     def _victim_key(self, v: int):
         """Preemption priority — the MAX of this key over live slots is
@@ -1469,7 +1502,12 @@ class Engine:
             ).backend,
             # ---- robustness observables (docs/ROBUSTNESS.md) ---------------
             "steps": self.step_count,
-            "watchdog": self.watchdog.summary(),
+            "watchdog": self.watchdog.summary(
+                [r.seconds for r in self.tracer.recent(WATCHDOG_WINDOW)]
+            ),
+            # Per-span lifetime totals (count, seconds, self seconds, parent)
+            # and the step counters' totals (serving/tracing.py).
+            "trace": self.tracer.summary(),
             "lifecycle": dict(self.lifecycle),
             # Kernel-quarantine events this process: [{key, step, level,
             # from, to, reason}] — the degradation ladder's audit trail.
@@ -1630,28 +1668,34 @@ class Engine:
             # rounds up to a power of two so the jitted prefill compiles for
             # O(slots * log(max_seq)) shapes, not one per distinct maxlen.
             slots_sel = [s for s, _ in batch]
-            maxlen = max(len(r.prompt) for _, r in batch)
-            maxlen = min(1 << (maxlen - 1).bit_length(), self.max_seq)
-            toks = np.zeros((len(batch), maxlen), np.int32)
-            for i, (_, r) in enumerate(batch):
-                toks[i, : len(r.prompt)] = r.prompt
-            part = slot_gather(self.caches, slots_sel)
-            _, part = self._dispatch(
-                "prefill", "prefill_fn", self.params, jnp.asarray(toks), part
-            )
-            self.caches = slot_merge(
-                self.caches, part, slots_sel, list(range(len(batch)))
-            )
+            with self.tracer.span("engine.prefill"):
+                maxlen = max(len(r.prompt) for _, r in batch)
+                maxlen = min(1 << (maxlen - 1).bit_length(), self.max_seq)
+                toks = np.zeros((len(batch), maxlen), np.int32)
+                for i, (_, r) in enumerate(batch):
+                    toks[i, : len(r.prompt)] = r.prompt
+                part = slot_gather(self.caches, slots_sel)
+                _, part = self._dispatch(
+                    "prefill", "prefill_fn", self.params, jnp.asarray(toks), part
+                )
+            with self.tracer.span("engine.scatter"):
+                self.caches = slot_merge(
+                    self.caches, part, slots_sel, list(range(len(batch)))
+                )
         else:
             for s, r in batch:
                 # Per-slot prefill: batch of 1 through a slot-sliced cache view.
-                toks = jnp.asarray(r.prompt, jnp.int32)[None]
-                slot_cache = slot_slice(self.caches, s)
-                _, slot_cache = self._dispatch(
-                    "prefill", "prefill_fn", self.params, toks, slot_cache
-                )
-                self.caches = slot_merge(self.caches, slot_cache, [s], [0])
+                with self.tracer.span("engine.prefill"):
+                    toks = jnp.asarray(r.prompt, jnp.int32)[None]
+                    slot_cache = slot_slice(self.caches, s)
+                    _, slot_cache = self._dispatch(
+                        "prefill", "prefill_fn", self.params, toks, slot_cache
+                    )
+                with self.tracer.span("engine.scatter"):
+                    self.caches = slot_merge(self.caches, slot_cache, [s], [0])
+        self.tracer.add("prefill_tokens", sum(len(r.prompt) for _, r in batch))
         for s, r in batch:
+            self.tracer.note("admitted", r.uid)
             self.slot_req[s] = r
             r.status = "running"
             self.slot_pos[s] = len(r.prompt)
@@ -1748,6 +1792,7 @@ class Engine:
             else:
                 s = free.pop(0)
             self.queue.remove(req)
+            self.tracer.note("admitted", req.uid)
             self.slot_req[s] = req
             req.status = "running"
             self.slot_prefill_done[s] = done
@@ -1782,6 +1827,7 @@ class Engine:
         req.status = status
         req.error = error
         self.finished.append(req)
+        self.tracer.note("finished", req.uid)
         if status != "ok":
             self.lifecycle[status] = self.lifecycle.get(status, 0) + 1
         self.slot_req[s] = None
@@ -1874,8 +1920,10 @@ class Engine:
             # leaves; the decode dispatch gathers K/V pages by table.
             # Unchanged tables flow through the donated decode call, so
             # steady-state steps skip the host->device refresh.
-            self.caches = self._with_tables(self.caches)
+            with self.tracer.span("engine.tables"):
+                self.caches = self._with_tables(self.caches)
             self._tables_dirty = False
+            self.tracer.add("tables_refreshed")
 
     def _plan_drafts(self, active: list[int], k_max: int | None = None):
         """(L, {slot: draft}) for this step's verify window, or None to take
@@ -1959,67 +2007,72 @@ class Engine:
     def _spec_step(self, active: list[int], L: int, drafts: dict) -> int:
         """ONE batched verify dispatch scores every slot's draft window;
         commit each slot's longest greedy-consistent prefix + bonus token."""
-        mat = np.zeros((self.slots, L), np.int32)
-        mat[:, :1] = self._last_tokens(active)
-        for s in active:
-            mat[s, 1 : 1 + drafts[s].size] = drafts[s]
-        pos_vec = np.maximum(self.slot_pos.astype(np.int32) - 1, 0)
-        logits, self.caches = self._dispatch(
-            "verify", "verify_fn",
-            self.params, self.caches, jnp.asarray(mat), jnp.asarray(pos_vec),
-        )
+        tr = self.tracer
+        with tr.span("engine.dispatch"):
+            mat = np.zeros((self.slots, L), np.int32)
+            mat[:, :1] = self._last_tokens(active)
+            for s in active:
+                mat[s, 1 : 1 + drafts[s].size] = drafts[s]
+            pos_vec = np.maximum(self.slot_pos.astype(np.int32) - 1, 0)
+            logits, self.caches = self._dispatch(
+                "verify", "verify_fn",
+                self.params, self.caches, jnp.asarray(mat), jnp.asarray(pos_vec),
+            )
+        tr.add("decode_rows", len(active))
         bad = self._guard_slots(logits, active)
         # tgt[s, j]: the model's greedy token AFTER consuming mat[s, :j+1] —
         # the acceptance target for draft j and the bonus token at the cut.
-        tgt = np.asarray(jnp.argmax(logits, axis=-1), np.int32)
+        with tr.span("engine.wait"):
+            tgt = np.asarray(jnp.argmax(logits, axis=-1), np.int32)
         st = self.spec_stats
         st["steps"] += 1
         emitted = 0
-        for s in active:
-            if s in bad:
-                self._finish_slot(
-                    s, status="error",
-                    error="non-finite logits (guard tripped, verify)",
-                )
-                continue
-            if self.slot_req[s].cancel_requested:
-                # The cancel landed while the draft window was in flight: no
-                # token from this verify is ever emitted; the slot's pages
-                # (draft positions included) free through _finish_slot.
-                self._finish_slot(
-                    s, status="cancelled", error="cancelled mid-dispatch"
-                )
-                continue
-            d = drafts[s]
-            a = 0
-            while a < d.size and int(d[a]) == int(tgt[s, a]):
-                a += 1
-            commit = [int(t) for t in d[:a]] + [int(tgt[s, a])]
-            req = self.slot_req[s]
-            got = self._commit_tokens(s, commit)
-            # A finish condition inside the window (EOS among the accepted
-            # drafts, max_new_tokens, max_seq) truncates the commit.  The
-            # draft tail past the cut was scored but never influenced
-            # output — counting it inflated draft_proposed and skewed
-            # acceptance_rate low on EOS-heavy workloads.  Count only the
-            # drafts actually consumed: on truncation every emitted token
-            # IS an accepted draft (the bonus never lands), so proposed ==
-            # accepted == got for that row.
-            if got == len(commit):
-                scored, used = int(d.size), a
-            else:
-                scored = used = min(got, a)
-            req.draft_proposed += scored
-            req.draft_accepted += used
-            self.slot_proposed[s] += scored
-            self.slot_accepted[s] += used
-            st["slot_steps"] += 1
-            st["proposed"] += scored
-            st["accepted"] += used
-            st["committed"] += got
-            emitted += got
-            if self.cache_mode == "paged" and self.slot_req[s] is not None:
-                self._truncate_slot_pages(s)
+        with tr.span("engine.commit"):
+            for s in active:
+                if s in bad:
+                    self._finish_slot(
+                        s, status="error",
+                        error="non-finite logits (guard tripped, verify)",
+                    )
+                    continue
+                if self.slot_req[s].cancel_requested:
+                    # The cancel landed while the draft window was in flight: no
+                    # token from this verify is ever emitted; the slot's pages
+                    # (draft positions included) free through _finish_slot.
+                    self._finish_slot(
+                        s, status="cancelled", error="cancelled mid-dispatch"
+                    )
+                    continue
+                d = drafts[s]
+                a = 0
+                while a < d.size and int(d[a]) == int(tgt[s, a]):
+                    a += 1
+                commit = [int(t) for t in d[:a]] + [int(tgt[s, a])]
+                req = self.slot_req[s]
+                got = self._commit_tokens(s, commit)
+                # A finish condition inside the window (EOS among the accepted
+                # drafts, max_new_tokens, max_seq) truncates the commit.  The
+                # draft tail past the cut was scored but never influenced
+                # output — counting it inflated draft_proposed and skewed
+                # acceptance_rate low on EOS-heavy workloads.  Count only the
+                # drafts actually consumed: on truncation every emitted token
+                # IS an accepted draft (the bonus never lands), so proposed ==
+                # accepted == got for that row.
+                if got == len(commit):
+                    scored, used = int(d.size), a
+                else:
+                    scored = used = min(got, a)
+                req.draft_proposed += scored
+                req.draft_accepted += used
+                self.slot_proposed[s] += scored
+                self.slot_accepted[s] += used
+                st["slot_steps"] += 1
+                st["proposed"] += scored
+                st["accepted"] += used
+                st["committed"] += got
+                emitted += got
+                if self.cache_mode == "paged" and self.slot_req[s] is not None:
+                    self._truncate_slot_pages(s)
         return emitted
 
     # ---- token-budget mixed step (chunked prefill beside decode) -----------
@@ -2137,7 +2190,8 @@ class Engine:
                 ends[s] = start[s] + int(drafts.get(s, spec_lib._EMPTY).size)
             for s in prefill_rows:
                 ends[s] = start[s] + chunks[s] - 1  # within the admitted plan
-            self._ensure_pages(ends)
+            with self.tracer.span("engine.pages"):
+                self._ensure_pages(ends)
             if any(self.slot_req[s] is None for s in active):
                 # Pool growth preempted someone mid-plan: replan the whole
                 # window against the surviving slots rather than reason
@@ -2152,112 +2206,118 @@ class Engine:
                 self._tables_dirty = True
         self._refresh_tables()
 
-        k_cols = 1 + self.draft_k if self.spec_decode else 1
-        mat = np.zeros((self.slots, L), np.int32)
-        pos_vec = np.zeros(self.slots, np.int32)
-        idx = np.zeros((self.slots, k_cols), np.int32)
-        for s in decode_rows:
-            req = self.slot_req[s]
-            mat[s, 0] = (
-                req.generated[-1] if req.generated else int(req.prompt[-1])
-            )
-            d = drafts.get(s, spec_lib._EMPTY)
-            if d.size:
-                mat[s, 1 : 1 + d.size] = d
-            pos_vec[s] = start[s]
-            idx[s] = np.minimum(np.arange(k_cols), L - 1)
-        for s in prefill_rows:
-            req = self.slot_req[s]
-            done, c = int(self.slot_prefill_done[s]), chunks[s]
-            mat[s, :c] = np.asarray(req.prompt[done : done + c], np.int32)
-            pos_vec[s] = done
-            idx[s] = c - 1  # the final chunk's bonus logit; unused otherwise
+        tr = self.tracer
+        with tr.span("engine.dispatch"):
+            k_cols = 1 + self.draft_k if self.spec_decode else 1
+            mat = np.zeros((self.slots, L), np.int32)
+            pos_vec = np.zeros(self.slots, np.int32)
+            idx = np.zeros((self.slots, k_cols), np.int32)
+            for s in decode_rows:
+                req = self.slot_req[s]
+                mat[s, 0] = (
+                    req.generated[-1] if req.generated else int(req.prompt[-1])
+                )
+                d = drafts.get(s, spec_lib._EMPTY)
+                if d.size:
+                    mat[s, 1 : 1 + d.size] = d
+                pos_vec[s] = start[s]
+                idx[s] = np.minimum(np.arange(k_cols), L - 1)
+            for s in prefill_rows:
+                req = self.slot_req[s]
+                done, c = int(self.slot_prefill_done[s]), chunks[s]
+                mat[s, :c] = np.asarray(req.prompt[done : done + c], np.int32)
+                pos_vec[s] = done
+                idx[s] = c - 1  # the final chunk's bonus logit; unused otherwise
 
-        self._mixed_m = self.slots * L
-        cont["mixed_steps"] += 1
-        cont["decode_tokens"] += decode_cost
-        cont["prefill_tokens"] += sum(chunks.values())
-        logits, self.caches = self._dispatch(
-            "mixed", "mixed_fn",
-            self.params, self.caches,
-            jnp.asarray(mat), jnp.asarray(pos_vec), jnp.asarray(idx),
-        )
+            self._mixed_m = self.slots * L
+            cont["mixed_steps"] += 1
+            cont["decode_tokens"] += decode_cost
+            cont["prefill_tokens"] += sum(chunks.values())
+            logits, self.caches = self._dispatch(
+                "mixed", "mixed_fn",
+                self.params, self.caches,
+                jnp.asarray(mat), jnp.asarray(pos_vec), jnp.asarray(idx),
+            )
+        tr.add("decode_rows", len(decode_rows))
+        tr.add("prefill_tokens", sum(chunks.values()))
         bad = self._guard_slots(logits, active)
         # tgt[s, j]: the greedy token after consuming mat[s, :idx[s, j]+1].
-        tgt = np.asarray(jnp.argmax(logits, axis=-1), np.int32)
+        with tr.span("engine.wait"):
+            tgt = np.asarray(jnp.argmax(logits, axis=-1), np.int32)
         st = self.spec_stats if (self.spec_decode and drafts) else None
         if st is not None:
             st["steps"] += 1
         emitted = 0
         decode_emitted = 0
-        for s in active:
-            if self.slot_req[s] is None:
-                continue
-            if s in bad:
-                self._finish_slot(
-                    s, status="error",
-                    error="non-finite logits (guard tripped, mixed)",
-                )
-                continue
-            req = self.slot_req[s]
-            if req.cancel_requested:
-                self._finish_slot(
-                    s, status="cancelled", error="cancelled mid-dispatch"
-                )
-                continue
-            if s in chunks:
-                # Prefill row: the chunk's K/V landed in cache this dispatch.
-                done = int(self.slot_prefill_done[s]) + chunks[s]
-                self.slot_prefill_done[s] = done
-                self.slot_pos[s] = done
-                if self.cache_mode == "paged":
-                    # Fully covered prompt blocks are now valid prefix
-                    # content for later prefix-sharing admissions — and
-                    # retainable in the radix cache once released.
-                    self.alloc.mark_written(
-                        self.slot_pages[s][: done // self.block_size]
+        with tr.span("engine.commit"):
+            for s in active:
+                if self.slot_req[s] is None:
+                    continue
+                if s in bad:
+                    self._finish_slot(
+                        s, status="error",
+                        error="non-finite logits (guard tripped, mixed)",
                     )
-                if done >= len(req.prompt):
-                    # Final chunk: its last window index scored position
-                    # plen - 1 — the first decode.  Committing it here keeps
-                    # prefill completion and first token in one dispatch.
-                    cont["completed_prefills"] += 1
-                    got = self._commit_tokens(s, [int(tgt[s, 0])])
-                    emitted += got
-                continue
-            # Decode row: greedy-consistent draft prefix + bonus token
-            # (plain decode is the d.size == 0 degenerate: bonus only).
-            d = drafts.get(s, spec_lib._EMPTY)
-            a = 0
-            while a < d.size and int(d[a]) == int(tgt[s, a]):
-                a += 1
-            commit = [int(t) for t in d[:a]] + [int(tgt[s, a])]
-            got = self._commit_tokens(s, commit)
-            emitted += got
-            decode_emitted += got
-            if st is not None:
-                # Same truncation-aware accounting as _spec_step.
-                if got == len(commit):
-                    scored, used = int(d.size), a
-                else:
-                    scored = used = min(got, a)
-                req.draft_proposed += scored
-                req.draft_accepted += used
-                self.slot_proposed[s] += scored
-                self.slot_accepted[s] += used
-                st["slot_steps"] += 1
-                st["proposed"] += scored
-                st["accepted"] += used
-                st["committed"] += got
-            if self.cache_mode == "paged" and self.slot_req[s] is not None:
-                self._truncate_slot_pages(s)
-        if decode_rows and decode_emitted == 0 and any(
-            self.slot_req[s] is not None for s in decode_rows
-        ):
-            # A live decode row emitted nothing this step — the stall the
-            # token budget exists to prevent (0 by construction; the bench
-            # gate pins it).
-            cont["decode_stall_steps"] += 1
+                    continue
+                req = self.slot_req[s]
+                if req.cancel_requested:
+                    self._finish_slot(
+                        s, status="cancelled", error="cancelled mid-dispatch"
+                    )
+                    continue
+                if s in chunks:
+                    # Prefill row: the chunk's K/V landed in cache this dispatch.
+                    done = int(self.slot_prefill_done[s]) + chunks[s]
+                    self.slot_prefill_done[s] = done
+                    self.slot_pos[s] = done
+                    if self.cache_mode == "paged":
+                        # Fully covered prompt blocks are now valid prefix
+                        # content for later prefix-sharing admissions — and
+                        # retainable in the radix cache once released.
+                        self.alloc.mark_written(
+                            self.slot_pages[s][: done // self.block_size]
+                        )
+                    if done >= len(req.prompt):
+                        # Final chunk: its last window index scored position
+                        # plen - 1 — the first decode.  Committing it here keeps
+                        # prefill completion and first token in one dispatch.
+                        cont["completed_prefills"] += 1
+                        got = self._commit_tokens(s, [int(tgt[s, 0])])
+                        emitted += got
+                    continue
+                # Decode row: greedy-consistent draft prefix + bonus token
+                # (plain decode is the d.size == 0 degenerate: bonus only).
+                d = drafts.get(s, spec_lib._EMPTY)
+                a = 0
+                while a < d.size and int(d[a]) == int(tgt[s, a]):
+                    a += 1
+                commit = [int(t) for t in d[:a]] + [int(tgt[s, a])]
+                got = self._commit_tokens(s, commit)
+                emitted += got
+                decode_emitted += got
+                if st is not None:
+                    # Same truncation-aware accounting as _spec_step.
+                    if got == len(commit):
+                        scored, used = int(d.size), a
+                    else:
+                        scored = used = min(got, a)
+                    req.draft_proposed += scored
+                    req.draft_accepted += used
+                    self.slot_proposed[s] += scored
+                    self.slot_accepted[s] += used
+                    st["slot_steps"] += 1
+                    st["proposed"] += scored
+                    st["accepted"] += used
+                    st["committed"] += got
+                if self.cache_mode == "paged" and self.slot_req[s] is not None:
+                    self._truncate_slot_pages(s)
+            if decode_rows and decode_emitted == 0 and any(
+                self.slot_req[s] is not None for s in decode_rows
+            ):
+                # A live decode row emitted nothing this step — the stall the
+                # token budget exists to prevent (0 by construction; the bench
+                # gate pins it).
+                cont["decode_stall_steps"] += 1
         return emitted
 
     # ---- the engine loop ---------------------------------------------------
@@ -2265,21 +2325,32 @@ class Engine:
     def step(self) -> int:
         """One engine iteration: fire fault hooks, reap cancelled/expired
         requests, admit, then ONE decode (or ONE speculative verify)
-        dispatch for every active slot — bracketed by the step watchdog
+        dispatch for every active slot — one `engine.step` span and step
+        record (serving/tracing.py), whose duration the watchdog reads
         (exception-safe: a dispatch that raises still records its
         latency)."""
         self.step_count += 1
-        self.watchdog.step_start()
+        step = self.tracer.step(self.step_count, self.slots)
         try:
-            return self._step_inner()
+            with step as rec:
+                try:
+                    rec.emitted = self._step_inner()
+                finally:
+                    if self.cache_mode == "paged":
+                        rec.pages_in_use = self.alloc.in_use()
+                        rec.pages_total = self.alloc.capacity
         finally:
-            self.watchdog.step_end()
+            self.watchdog.observe(step.record.seconds)
+        return rec.emitted
 
     def _step_inner(self) -> int:
+        tr = self.tracer
         if self.hooks is not None:
             self.hooks.on_step_begin(self)
-        self._reap_lifecycle()
-        self._admit()
+        with tr.span("engine.reap"):
+            self._reap_lifecycle()
+        with tr.span("engine.admit"):
+            self._admit()
         if self.scheduler is not None:
             # Token-budget continuous batching: one mixed dispatch serves
             # decode AND chunked prefill; the phase-split paths below never
@@ -2293,7 +2364,8 @@ class Engine:
             if spec_plan is not None and not self._draft_pages_fit(active, spec_plan[0]):
                 self.spec_stats["pool_deferred"] += 1
                 spec_plan = None
-            self._ensure_decode_pages(extra=(spec_plan[0] - 1) if spec_plan else 0)
+            with tr.span("engine.pages"):
+                self._ensure_decode_pages(extra=(spec_plan[0] - 1) if spec_plan else 0)
             # Decode growth may have preempted slots (requests requeued).
             active = [s for s in range(self.slots) if self.slot_req[s] is not None]
             if not active:
@@ -2309,42 +2381,55 @@ class Engine:
         if spec_plan is not None:
             self._refresh_tables()
             return self._spec_step(active, *spec_plan)
-        last_tokens = self._last_tokens(active)
         if self.decode_mode == "vectorized":
             self._refresh_tables()
-            # One dispatch serves all active slots regardless of position skew:
-            # each row decodes at its own pos.  Inactive rows decode (and write
-            # their cache row at pos 0) with token 0; that write is harmless
-            # because every cache position is written before it is attended —
-            # the next admission's prefill rewrites the row from position 0 up.
-            pos_vec = np.maximum(self.slot_pos.astype(np.int32) - 1, 0)
-            args = (
-                self.params, self.caches,
-                jnp.asarray(last_tokens), jnp.asarray(pos_vec),
-            )
-            if self.sample == "temperature":
-                args = args + self._sample_args(active)
-            nxt, logits, self.caches = self._dispatch("decode", "decode_fn", *args)
+            with tr.span("engine.dispatch"):
+                # One dispatch serves all active slots regardless of position
+                # skew: each row decodes at its own pos.  Inactive rows decode
+                # (and write their cache row at pos 0) with token 0; that write
+                # is harmless because every cache position is written before
+                # it is attended — the next admission's prefill rewrites the
+                # row from position 0 up.
+                last_tokens = self._last_tokens(active)
+                pos_vec = np.maximum(self.slot_pos.astype(np.int32) - 1, 0)
+                args = (
+                    self.params, self.caches,
+                    jnp.asarray(last_tokens), jnp.asarray(pos_vec),
+                )
+                if self.sample == "temperature":
+                    args = args + self._sample_args(active)
+                nxt, logits, self.caches = self._dispatch("decode", "decode_fn", *args)
+            tr.add("decode_rows", len(active))
             bad = self._guard_slots(logits, active)
-            return self._commit(active, np.asarray(nxt), bad)
+            with tr.span("engine.wait"):
+                nxt = np.asarray(nxt)
+            with tr.span("engine.commit"):
+                return self._commit(active, nxt, bad)
         # Grouped baseline: slots admitted with different prompt lengths decode
         # on their own pos via per-pos grouping; each group's cache rows merge
         # back selectively so other groups' histories stay untouched.
+        with tr.span("engine.dispatch"):
+            last_tokens = self._last_tokens(active)
+        tr.add("decode_rows", len(active))
         groups: dict[int, list[int]] = {}
         for s in active:
             groups.setdefault(int(self.slot_pos[s]), []).append(s)
         emitted = 0
         for p, slots in groups.items():
-            args = (
-                self.params, self.caches,
-                jnp.asarray(last_tokens), jnp.asarray(p - 1, jnp.int32),
-            )
-            if self.sample == "temperature":
-                args = args + self._sample_args(slots)
-            nxt, logits, new_caches = self._dispatch("decode", "decode_fn", *args)
-            self.caches = slot_merge(self.caches, new_caches, slots)
+            with tr.span("engine.dispatch"):
+                args = (
+                    self.params, self.caches,
+                    jnp.asarray(last_tokens), jnp.asarray(p - 1, jnp.int32),
+                )
+                if self.sample == "temperature":
+                    args = args + self._sample_args(slots)
+                nxt, logits, new_caches = self._dispatch("decode", "decode_fn", *args)
+                self.caches = slot_merge(self.caches, new_caches, slots)
             bad = self._guard_slots(logits, slots)
-            emitted += self._commit(slots, np.asarray(nxt), bad)
+            with tr.span("engine.wait"):
+                nxt = np.asarray(nxt)
+            with tr.span("engine.commit"):
+                emitted += self._commit(slots, nxt, bad)
         return emitted
 
     def run(self) -> list[Request]:

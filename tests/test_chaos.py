@@ -504,25 +504,21 @@ def test_audit_leak_names_owner():
 
 
 def test_watchdog_stall_detection_and_percentiles():
-    t = [0.0]
-    wd = watchdog_lib.DecodeStepWatchdog(clock=lambda: t[0])
+    wd = watchdog_lib.DecodeStepWatchdog()
+    durations = []
     for _ in range(8):  # warmup + steady 10ms steps
-        wd.step_start()
-        t[0] += 0.010
-        assert wd.step_end() is False
-    wd.step_start()
-    t[0] += 0.200  # 20x the EWMA: a stall
-    assert wd.step_end() is True
-    s = wd.summary()
+        durations.append(0.010)
+        assert wd.observe(0.010) is False
+    durations.append(0.200)  # 20x the EWMA: a stall
+    assert wd.observe(0.200) is True
+    s = wd.summary(durations)
     assert s["stalls"] == 1 and s["stalled"]
     assert s["p50_ms"] == pytest.approx(10.0, rel=0.2)
     assert s["p99_ms"] > s["p50_ms"]
     # The stalled sample was clamped: the EWMA didn't absorb the spike.
     assert s["ewma_ms"] < 50.0
     # Recovery: the next normal step is not a stall.
-    wd.step_start()
-    t[0] += 0.010
-    assert wd.step_end() is False
+    assert wd.observe(0.010) is False
 
 
 def test_watchdog_wired_into_engine_stats():
