@@ -40,7 +40,7 @@ attention (select_attn()):
                dense decode kernel, flash prefill).
 * blocks     : (q_chunk, kv_chunk) streaming granularity for the Pallas
                kernels (decode uses kv_chunk only; the paged kernel streams
-               at page granularity and ignores blocks).
+               blocks of attn.pages_per_block pages and ignores blocks).
 
 Resolution order (both classes):
   1. an explicit `requested` backend always wins (tests/benches pin paths);

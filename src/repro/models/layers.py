@@ -428,11 +428,12 @@ def attention_apply(
             requested=enc.attn_backend, kv=layout.name,
         )
         if choice.backend == "pallas":
-            # Fused paged-decode kernel: K/V pages gathered tile-by-tile
-            # INSIDE the dispatch (scalar-prefetched block table), only the
-            # slot's live pages streamed — the (B, NB*bs, KV, D) logical
+            # Fused paged-decode kernel: K/V pages gathered INSIDE the
+            # dispatch (scalar-prefetched block table), a block of
+            # attn.pages_per_block pages per online-softmax update, only
+            # the slot's live pages copied — the (B, NB*bs, KV, D) logical
             # view is never materialized.  Quantized layouts stream the
-            # scale pages alongside and dequantize tile-locally in VMEM.
+            # scale pages alongside and dequantize in VMEM.
             out = attn_kernels.paged_decode_attention(
                 q, k_pool, v_pool, table, posm[:, 0],
                 k_scale=k_scale, v_scale=v_scale, kv_quant=layout.name,

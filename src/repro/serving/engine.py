@@ -82,6 +82,7 @@ from repro.core import encoding as encoding_lib
 from repro.core import targets as targets_lib
 from repro.core.encoding import Phase
 from repro.core.packed import EncodingConfig
+from repro.kernels import attn as attn_lib
 from repro.kernels import registry as registry_lib
 from repro.launch import mesh as mesh_lib
 from repro.models import transformer as T
@@ -1393,6 +1394,7 @@ class Engine:
         """Refresh every `table` cache leaf from the host block table,
         narrowed to the live-width bucket (_live_table_width)."""
         tbl = self.block_table[:, : self._live_table_width()]
+        self._table_width = tbl.shape[-1]
 
         def one(path, leaf):
             if str(getattr(path[-1], "key", "")) == "table":
@@ -1401,6 +1403,20 @@ class Engine:
             return leaf
 
         return jax.tree_util.tree_map_with_path(one, caches)
+
+    def _note_attn_blocks(self, rows: list[int]) -> None:
+        """Count the paged decode kernel's blocks for one dispatch over
+        `rows` into the step record: `attn_blocks_live`, the blocks of
+        pages_per_block pages that hold a row's pages, and
+        `attn_blocks_grid`, the blocks its grid spans at the dispatch's
+        table width (the width `_with_tables` last set)."""
+        if self.cache_mode != "paged":
+            return
+        width = self._table_width
+        ppb = attn_lib.pages_per_block(self.block_size, width)
+        self.tracer.add("attn_blocks_live", sum(
+            -(-min(len(self.slot_pages[s]), width) // ppb) for s in rows))
+        self.tracer.add("attn_blocks_grid", self.slots * -(-width // ppb))
 
     def _preempt(self, s: int) -> None:
         """Evict slot `s`: free its pages and requeue its request at the
@@ -2019,6 +2035,7 @@ class Engine:
                 self.params, self.caches, jnp.asarray(mat), jnp.asarray(pos_vec),
             )
         tr.add("decode_rows", len(active))
+        self._note_attn_blocks(active)
         bad = self._guard_slots(logits, active)
         # tgt[s, j]: the model's greedy token AFTER consuming mat[s, :j+1] —
         # the acceptance target for draft j and the bonus token at the cut.
@@ -2240,6 +2257,7 @@ class Engine:
             )
         tr.add("decode_rows", len(decode_rows))
         tr.add("prefill_tokens", sum(chunks.values()))
+        self._note_attn_blocks(active)
         bad = self._guard_slots(logits, active)
         # tgt[s, j]: the greedy token after consuming mat[s, :idx[s, j]+1].
         with tr.span("engine.wait"):
@@ -2400,6 +2418,7 @@ class Engine:
                     args = args + self._sample_args(active)
                 nxt, logits, self.caches = self._dispatch("decode", "decode_fn", *args)
             tr.add("decode_rows", len(active))
+            self._note_attn_blocks(active)
             bad = self._guard_slots(logits, active)
             with tr.span("engine.wait"):
                 nxt = np.asarray(nxt)

@@ -14,8 +14,10 @@ and times itself with the engine's clock:
 `engine.step` span.  A record holds the step's index, start and end on the
 engine clock, seconds per span name, and the step's counters (`decode_rows`,
 `slots`, `prefill_tokens`, `emitted`, `admitted` / `finished` / `preempted`
-request uids, `pages_in_use`, `pages_total`, `tables_refreshed`).  The uids
-link a request to the steps that served it.
+request uids, `pages_in_use`, `pages_total`, `tables_refreshed`, and
+`attn_blocks_live` / `attn_blocks_grid`: the paged decode kernel's blocks of
+pages that hold live pages and that its grid spans, over the step's
+dispatches).  The uids link a request to the steps that served it.
 
 Records live in a ring of RING_STEPS steps.  `records(t0, t1)` returns the
 steps that ended in [t0, t1], and raises where the ring has dropped a step
@@ -43,7 +45,7 @@ RING_STEPS = 65536
 # Counters summed into the tracer's lifetime totals (list-valued ones by
 # their length).  pages_in_use / pages_total / slots are gauges.
 ADDITIVE = ("decode_rows", "prefill_tokens", "emitted", "admitted", "finished",
-            "preempted", "tables_refreshed")
+            "preempted", "tables_refreshed", "attn_blocks_live", "attn_blocks_grid")
 
 _LIVE: weakref.WeakSet = weakref.WeakSet()
 
@@ -69,6 +71,8 @@ class StepRecord:
     pages_in_use: int | None = None
     pages_total: int | None = None
     tables_refreshed: int = 0
+    attn_blocks_live: int = 0   # paged attention blocks holding live pages
+    attn_blocks_grid: int = 0   # paged attention blocks the kernel grid spans
 
     @property
     def seconds(self) -> float:

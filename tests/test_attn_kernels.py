@@ -171,21 +171,43 @@ def _paged_case(rng, b, nb, bs, kv, d, h, Lq, share=False):
     pool_v = _rand(rng, 1 + b * nb, bs, kv, d)
     table = (1 + rng.permutation(b * nb).reshape(b, nb)).astype(np.int32)
     if share and b > 1:
-        table[1, 0] = table[0, 0]  # prefix-reuse: two slots share a page
+        # prefix reuse: two slots share their first half of pages
+        table[1, : max(1, nb // 2)] = table[0, : max(1, nb // 2)]
     table = jnp.asarray(table)
     q = _rand(rng, b, Lq, h, d)
     pos = jnp.asarray(rng.randint(0, nb * bs - Lq + 1, b), jnp.int32)
     return q, pool_k, pool_v, table, pos
 
 
+# (table width, page size, head_dim): one block a row; a width of 13 pages
+# in blocks of 8 (the last block partial) through the BlockSpec page stream
+# (head_dim 16) and through the page DMAs (head_dim 128).
+PAGED_GEOMETRIES = {
+    "one_block": (5, 8, 16),
+    "two_blocks_spec": (13, 32, 16),
+    "two_blocks_dma": (13, 32, 128),
+}
+
+
 @pytest.mark.parametrize("share", [False, True])
 @pytest.mark.parametrize("Lq", [1, 3])
-def test_paged_decode_parity_vs_gather_reference(share, Lq):
+@pytest.mark.parametrize("geom", list(PAGED_GEOMETRIES))
+def test_paged_decode_parity_vs_gather_reference(geom, share, Lq):
     """In-kernel block-table gather == paged_gather + attention_decode, for
-    arbitrary tables (including shared prefix pages) and verify windows."""
+    shared prefix pages and verify windows: row 0's context ends mid-page
+    (with two blocks, two positions short of the block boundary, so the
+    L = 3 window crosses it), row 1's ends mid-page in the last, partial
+    block, and row 2 is an idle slot whose only page is the scratch page."""
+    nb, bs, d = PAGED_GEOMETRIES[geom]
     rng = np.random.RandomState(6)
-    b, nb, bs, kv, d, h = 3, 5, 8, 2, 16, 8
-    q, pool_k, pool_v, table, pos = _paged_case(rng, b, nb, bs, kv, d, h, Lq, share)
+    b, kv, h = 3, 2, 8
+    q, pool_k, pool_v, table, _ = _paged_case(rng, b, nb, bs, kv, d, h, Lq, share)
+    ppb = attn_lib.pages_per_block(bs, nb)
+    assert (nb > ppb) == geom.startswith("two_blocks")
+    assert attn_lib._copyable_pages(pool_k) == geom.endswith("dma")
+    row0 = ppb * bs - 2 if nb > ppb else nb * bs // 2 + 3
+    pos = jnp.asarray([row0, nb * bs - bs - 5, 0], jnp.int32)
+    table = table.at[2].set(0)  # every entry the scratch page
     want = L.attention_decode(
         q, L.paged_gather(pool_k, table), L.paged_gather(pool_v, table),
         pos=pos, window=0,
@@ -196,22 +218,40 @@ def test_paged_decode_parity_vs_gather_reference(share, Lq):
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), **TOL)
 
 
-def test_paged_vs_dense_kernel_bit_consistency():
-    """At matched streaming granularity (dense kv_chunk == page block size)
-    the paged kernel and the dense kernel on the gathered view are BITWISE
-    identical — the in-kernel gather changes where bytes come from, never
-    a single float op."""
+@pytest.mark.parametrize("geom", list(PAGED_GEOMETRIES))
+def test_paged_vs_dense_kernel_bit_consistency(geom):
+    """At matched streaming granularity (dense kv_chunk == the paged
+    kernel's block of pages) the paged kernel and the dense kernel on the
+    gathered view are BITWISE identical — the in-kernel gather changes
+    where bytes come from, never a single float op."""
+    nb, bs, d = PAGED_GEOMETRIES[geom]
     rng = np.random.RandomState(7)
-    b, nb, bs, kv, d, h, Lq = 3, 4, 8, 2, 16, 8, 2
+    b, kv, h, Lq = 3, 2, 8, 2
     q, pool_k, pool_v, table, pos = _paged_case(rng, b, nb, bs, kv, d, h, Lq)
     paged = attn_lib.paged_decode_attention(
         q, pool_k, pool_v, table, pos, interpret=True
     )
     dense = attn_lib.dense_decode_attention(
         q, L.paged_gather(pool_k, table), L.paged_gather(pool_v, table),
-        pos, window=0, kv_chunk=bs, interpret=True,
+        pos, window=0, kv_chunk=attn_lib.pages_per_block(bs, nb) * bs, interpret=True,
     )
     np.testing.assert_array_equal(np.asarray(paged), np.asarray(dense))
+
+
+@pytest.mark.parametrize("bs", [8, 16, 32])
+def test_pages_per_block_divides_or_clamps(bs):
+    """Blocks of _BLOCK_TOKENS tokens where the table is that wide, else
+    one block of the whole table; ceil(nb / ppb) blocks cover the table and
+    only the last may be partial."""
+    for nb in range(1, 129):
+        ppb = attn_lib.pages_per_block(bs, nb)
+        assert 1 <= ppb <= nb
+        if nb * bs >= attn_lib._BLOCK_TOKENS:
+            assert ppb * bs == attn_lib._BLOCK_TOKENS
+        else:
+            assert ppb == nb
+        nbb = -(-nb // ppb)
+        assert (nbb - 1) * ppb < nb <= nbb * ppb
 
 
 def test_paged_gather_nb_blocks_bound():

@@ -108,19 +108,33 @@ def test_decode_quantized_gemv_compiles(one_chip, quant):
     _compile(fn, one_chip, jax.ShapeDtypeStruct((SLOTS, FF), jnp.bfloat16), rhs, scale)
 
 
-@pytest.mark.parametrize("window", [1, 4], ids=["decode", "verify4"])
-def test_paged_decode_attention_compiles(one_chip, window):
+@pytest.mark.parametrize(
+    "window,kv_quant", [(1, "bf16"), (4, "bf16"), (1, "kv8")],
+    ids=["decode", "verify4", "decode_kv8"],
+)
+def test_paged_decode_attention_compiles(one_chip, window, kv_quant):
     """Paged decode attention, 12 query heads over 2 KV heads (groups of 6),
-    block size 16; window 4 is a speculative-decode verify window."""
+    block size 16; window 4 is a speculative-decode verify window.  bf16
+    pages stream by page DMAs; kv8's int8 pages and scale pages (tiles the
+    DMA cannot slice) through the BlockSpec page stream."""
     pages = 1 + SLOTS * MAX_SEQ // BLOCK
-    pool = jax.ShapeDtypeStruct((pages, BLOCK, KV_HEADS, HEAD_DIM), jnp.bfloat16)
+    q = jax.ShapeDtypeStruct((SLOTS, window, HEADS, HEAD_DIM), jnp.bfloat16)
+    table = jax.ShapeDtypeStruct((SLOTS, MAX_SEQ // BLOCK), jnp.int32)
+    pos = jax.ShapeDtypeStruct((SLOTS,), jnp.int32)
+    if kv_quant == "bf16":
+        pool = jax.ShapeDtypeStruct((pages, BLOCK, KV_HEADS, HEAD_DIM), jnp.bfloat16)
+        _compile(
+            lambda q, k, v, t, p: attn.paged_decode_attention(q, k, v, t, p),
+            one_chip, q, pool, pool, table, pos,
+        )
+        return
+    pool = jax.ShapeDtypeStruct((pages, BLOCK, KV_HEADS, HEAD_DIM), jnp.int8)
+    scale = jax.ShapeDtypeStruct((pages, BLOCK, KV_HEADS, 1), jnp.float32)
     _compile(
-        lambda q, k, v, t, p: attn.paged_decode_attention(q, k, v, t, p),
-        one_chip,
-        jax.ShapeDtypeStruct((SLOTS, window, HEADS, HEAD_DIM), jnp.bfloat16),
-        pool, pool,
-        jax.ShapeDtypeStruct((SLOTS, MAX_SEQ // BLOCK), jnp.int32),
-        jax.ShapeDtypeStruct((SLOTS,), jnp.int32),
+        lambda q, k, v, t, p, ks, vs: attn.paged_decode_attention(
+            q, k, v, t, p, k_scale=ks, v_scale=vs, kv_quant=kv_quant
+        ),
+        one_chip, q, pool, pool, table, pos, scale, scale,
     )
 
 

@@ -129,7 +129,7 @@ def test_paged_kernel_bit_consistent_with_dense_kernel(
     )
     dense = attn_lib.dense_decode_attention(
         q, L.paged_gather(pool_k, table), L.paged_gather(pool_v, table),
-        pos, window=0, kv_chunk=bs, interpret=True,
+        pos, window=0, kv_chunk=attn_lib.pages_per_block(bs, nb) * bs, interpret=True,
     )
     np.testing.assert_array_equal(np.asarray(paged), np.asarray(dense))
     want = L.attention_decode(
@@ -283,7 +283,7 @@ def test_paged_kernel_bit_consistent_with_dense_kernel_kv8(
     )
     dense = attn_lib.dense_decode_attention(
         q, L.paged_gather(pool_k, table), L.paged_gather(pool_v, table),
-        pos, window=0, kv_chunk=bs,
+        pos, window=0, kv_chunk=attn_lib.pages_per_block(bs, nb) * bs,
         k_scale=L.paged_gather(ks, table), v_scale=L.paged_gather(vs, table),
         kv_quant="kv8", interpret=True,
     )

@@ -13,8 +13,10 @@ import pytest
 
 from repro.configs import registry
 from repro.core.packed import EncodingConfig
+from repro.kernels import attn
 from repro.models import transformer as T
 from repro.serving import engine as engine_lib
+from repro.serving import paged
 from repro.serving import tracing
 
 ENC = EncodingConfig(enabled=True, backend="xla")
@@ -98,6 +100,45 @@ def test_counters_agree_with_the_engine(driven):
     assert counters["tables_refreshed"] == sum(r.tables_refreshed for r in recs) > 0
 
 
+def test_attn_block_counters_match_the_block_table():
+    """attn_blocks_live / attn_blocks_grid equal a brute-force count from
+    the host block table at each decode dispatch: live pages per active
+    row (non-scratch entries of the dispatched width) in blocks of
+    pages_per_block, and every row's blocks across the table's width.  One
+    prompt spans more than one block (block_size 2: 128 pages a block)."""
+    eng = engine_lib.Engine(PARAMS, CFG, ENC, max_seq=512, cache_mode="paged",
+                            slots=3, block_size=2)
+    rng = np.random.RandomState(1)
+    for uid, n in enumerate((300, 40, 5)):
+        prompt = rng.randint(1, CFG.vocab_size, n).astype(np.int32)
+        assert eng.submit(engine_lib.Request(uid=uid, prompt=prompt, max_new_tokens=3))
+    want = []
+    decode = eng.decode_fn
+
+    def counted(params, caches, *args):
+        width = caches["groups"][0]["table"].shape[-1]
+        ppb = attn.pages_per_block(eng.block_size, width)
+        live = 0
+        for s in range(eng.slots):
+            if eng.slot_req[s] is not None:
+                pages = int((eng.block_table[s, :width] != paged.SCRATCH_PAGE).sum())
+                live += -(-pages // ppb)
+        want.append((live, eng.slots * -(-width // ppb)))
+        return decode(params, caches, *args)
+
+    eng.decode_fn = counted
+    while eng.queue or any(r is not None for r in eng.slot_req):
+        eng.step()
+    recs = [r for r in eng.tracer.records(float("-inf"), float("inf")) if r.decode_rows]
+    got = [(r.attn_blocks_live, r.attn_blocks_grid) for r in recs]
+    assert got == want
+    assert all(0 < live <= grid for live, grid in got)
+    assert any(live > eng.slots for live, _ in got)  # a row with two live blocks
+    counters = eng.stats["trace"]["counters"]
+    assert counters["attn_blocks_live"] == sum(live for live, _ in want)
+    assert counters["attn_blocks_grid"] == sum(grid for _, grid in want)
+
+
 def test_watchdog_reads_the_step_span(driven):
     eng, recs, _, _ = driven
     wd = eng.stats["watchdog"]
@@ -162,4 +203,5 @@ def test_other_step_paths_use_the_same_names(kw):
     assert STEP_SPANS <= seen
     assert seen <= STEP_SPANS | PREFILL_SPANS
     assert all(r.decode_rows <= r.slots for r in recs)
+    assert all(0 < r.attn_blocks_live <= r.attn_blocks_grid for r in recs if r.decode_rows)
     assert sum(r.emitted for r in recs) == sum(len(q.generated) for q in eng.finished)
