@@ -296,11 +296,15 @@ class KVLayout:
 
     def quantize(self, x):
         """(…, hd) float -> (q (…, hd / pack_ratio) storage_dtype,
-        scale (…, 1) float32).  Symmetric absmax per (token, head) row."""
+        scale (…, 1) float32).  Symmetric absmax per (token, head) row.
+
+        The scale multiplies by qmax's float32 reciprocal: XLA compiles a
+        division by a constant to that multiply, so written out it gives
+        the same bits eagerly as inside any jitted write path."""
         assert self.quantized, f"{self.name} has no codec"
         xf = x.astype(jnp.float32)
         amax = jnp.max(jnp.abs(xf), axis=-1, keepdims=True)
-        scale = jnp.maximum(amax, 1e-8) / self.qmax
+        scale = jnp.maximum(amax, 1e-8) * jnp.float32(1.0 / self.qmax)
         q = jnp.clip(jnp.round(xf / scale), -self.qmax, self.qmax).astype(jnp.int32)
         if self.pack_ratio > 1:
             return _pack_nibbles(q), scale

@@ -290,6 +290,11 @@ def _batch_axis(path) -> int:
     return 1 if str(name) == "groups" else 0
 
 
+def _is_table(path) -> bool:
+    """A paged cache's block-table leaf (host-owned, refreshed per dispatch)."""
+    return str(getattr(path[-1], "key", "")) == "table"
+
+
 def slot_gather(caches, slots_sel: list[int]):
     """Batch rows `slots_sel` of every cache leaf, as one gather per leaf."""
     # Host-side index build (np, not jnp): these gathers run eagerly on
@@ -322,6 +327,60 @@ def slot_merge(caches, part, slots_sel: list[int], src_idx: list[int] | None = N
         return full.at[:, dst].set(rows)
 
     return jax.tree_util.tree_map_with_path(one, caches, part)
+
+
+def scatter_prefill(pool, tmp, rows, blocks, pages, count):
+    """Write prefilled K/V into the paged pool: for i < `count`, block
+    `blocks[i]` of batch row `rows[i]` of the temporary dense prefill cache
+    `tmp` lands in page `pages[i]` of every pool leaf.  `pool` is the
+    engine's cache tree with its `table` leaves taken out (None).  The
+    engine jits this once with `pool` donated, and sizes the index vectors
+    to every block of `tmp` (entries from `count` on are never read), so
+    there is one compiled program per (batch, padded length): the prefill's
+    own shapes, whatever the number of pages written.
+
+    A loop of one page per iteration, each a dynamic slice of `tmp` and a
+    dynamic-update-slice of the pool: the pool is written in place in its
+    own layout (a gather + scatter over the page axis makes XLA:TPU relayout
+    the whole pool, and copy it back, around the scatter).
+
+    The temp prefill cache is always raw bf16 (flash prefill computes
+    full-precision K/V); under a quantized layout (kv8/kv4, read from the
+    pool's storage dtype) each block is quantized HERE, and its per-token
+    scales land in the sibling `k_scale`/`v_scale` leaf at the same page."""
+    flat, treedef = jax.tree_util.tree_flatten_with_path(pool)
+    keys = [jax.tree_util.keystr(p) for p, _ in flat]
+    at_key = {k: i for i, k in enumerate(keys)}
+    tmp_by_path = {jax.tree_util.keystr(p): v
+                   for p, v in jax.tree_util.tree_flatten_with_path(tmp)[0]}
+    data = []  # (leaf index, scale leaf index or None, its tmp leaf, G axis?)
+    for i, (path, leaf) in enumerate(flat):
+        name = str(getattr(path[-1], "key", ""))
+        if name in ("k_scale", "v_scale"):
+            continue
+        scale = at_key.get(keys[i].replace(f"['{name}']", f"['{name}_scale']"))
+        data.append((i, scale, tmp_by_path[keys[i]], _batch_axis(path) == 1))
+
+    def one_page(n, leaves):
+        leaves = list(leaves)
+        for i, scale, src, stacked in data:
+            leaf = leaves[i]
+            bs = leaf.shape[-3]
+            lead = (0,) if stacked else ()
+            block = jax.lax.dynamic_slice(
+                src, (*lead, rows[n], blocks[n] * bs, 0, 0),
+                (*src.shape[: len(lead)], 1, bs, *src.shape[-2:]),
+            )
+            at = (*lead, pages[n], 0, 0, 0)
+            layout = encoding_lib.kv_layout_for_storage(leaf.dtype)
+            if layout.quantized:
+                block, sc = layout.quantize(block)
+                leaves[scale] = jax.lax.dynamic_update_slice(leaves[scale], sc, at)
+            leaves[i] = jax.lax.dynamic_update_slice(leaf, block.astype(leaf.dtype), at)
+        return leaves
+
+    leaves = jax.lax.fori_loop(0, count, one_page, [v for _, v in flat])
+    return jax.tree_util.tree_unflatten(treedef, leaves)
 
 
 def count_calls(fn):
@@ -742,6 +801,14 @@ class Engine:
                 block_size=block_size, num_pages=pool_pages,
                 kv_quant=self.kv_quant,
             )
+            # Admission's two programs around the prefill: the temp dense
+            # cache, one per (batch, padded length), and the write of its
+            # blocks into the pool, donated (in place, no pool copy).
+            def prefill_cache(batch, length):
+                return T.cache_init(cfg, batch, length)
+
+            self.prefill_cache_fn = jax.jit(prefill_cache, static_argnums=(0, 1))
+            self.scatter_fn = jax.jit(scatter_prefill, donate_argnums=(0,))
             self.block_table = np.full(
                 (slots, self.num_blocks), paged_lib.SCRATCH_PAGE, np.int32
             )
@@ -1118,7 +1185,7 @@ class Engine:
             page = self.slot_pages[s][-1]
 
             def one(path, leaf):
-                if str(getattr(path[-1], "key", "")) == "table":
+                if _is_table(path):
                     return leaf
                 poison = (
                     jnp.iinfo(leaf.dtype).max
@@ -1132,7 +1199,7 @@ class Engine:
             pos = max(int(self.slot_pos[s]) - 1, 0)
 
             def one(path, leaf):
-                if str(getattr(path[-1], "key", "")) == "table":
+                if _is_table(path):
                     return leaf
                 if leaf.ndim < 2:
                     return leaf
@@ -1217,13 +1284,12 @@ class Engine:
                 toks = np.zeros((len(batch), lp), np.int32)
                 for i, (_, r, _) in enumerate(batch):
                     toks[i, : len(r.prompt)] = r.prompt
-                tmp = T.cache_init(self.cfg, len(batch), lp)
+                tmp = self.prefill_cache_fn(len(batch), lp)
                 _, tmp = self._dispatch(
                     "prefill", "prefill_fn", self.params, jnp.asarray(toks), tmp
                 )
             self.tracer.add("prefill_tokens", sum(len(r.prompt) for _, r, _ in batch))
-            with self.tracer.span("engine.scatter"):
-                self._scatter_prefill(tmp, batch)
+            self._scatter_prefill(tmp, batch)
         for s, r, plan, lead in suffix:
             self._prefill_suffix(r, plan, lead)
         for s, r, plan in batch + [(s, r, p) for s, r, p, _ in suffix]:
@@ -1243,71 +1309,36 @@ class Engine:
 
     def _scatter_prefill(self, tmp, batch) -> None:
         """Write each admitted request's non-shared prompt blocks from the
-        temporary dense prefill cache into their pool pages — one gather +
-        one scatter per cache leaf.
-
-        The temp prefill cache is always raw bf16 (flash prefill computes
-        full-precision K/V); under a quantized layout the block gather is
-        quantized HERE, page-granular, and the per-page scales land in the
-        sibling `k_scale`/`v_scale` leaves at the same page ids.  jax sorts
-        dict keys, so within a layer the `k`/`v` data leaf is always
-        visited before its `{k,v}_scale` leaf — the data visit stashes the
-        computed scales keyed by the scale leaf's path."""
-        bs = self.block_size
-        ri: list[int] = []
-        bi: list[int] = []
-        pgs: list[int] = []
-        for i, (_, _r, plan) in enumerate(batch):
-            for j, (pg, sh) in enumerate(zip(plan.pages, plan.shared)):
-                if not sh:
-                    ri.append(i)
-                    bi.append(j)
-                    pgs.append(pg)
-        if not pgs:
-            return
-        ria = jnp.asarray(ri, jnp.int32)
-        bia = jnp.asarray(bi, jnp.int32)
-        pga = jnp.asarray(pgs, jnp.int32)
-        flat, _ = jax.tree_util.tree_flatten_with_path(tmp)
-        tmp_by_path = {jax.tree_util.keystr(p): v for p, v in flat}
-        layout = encoding_lib.kv_layout(getattr(self, "kv_quant", "bf16"))
-        pending_scales: dict[str, jax.Array] = {}
-
-        def one(path, leaf):
-            name = str(getattr(path[-1], "key", ""))
-            if name == "table":
-                return leaf
-            key = jax.tree_util.keystr(path)
-            if name in ("k_scale", "v_scale"):
-                sc = pending_scales.pop(key)
-                if _batch_axis(path) == 1:
-                    return leaf.at[:, pga].set(sc)
-                return leaf.at[pga].set(sc)
-            part = tmp_by_path[key]
-            if _batch_axis(path) == 1:  # stacked groups: (G, B, Lp, KV, HD)
-                g, nb, lpad, kvh, hd = part.shape
-                pr = part.reshape(g, nb, lpad // bs, bs, kvh, hd)
-                blocks = pr[:, ria, bia]
-                if layout.quantized:
-                    blocks, sc = layout.quantize(blocks)
-                    pending_scales[
-                        key.replace(f"['{name}']", f"['{name}_scale']")
-                    ] = sc
-                return leaf.at[:, pga].set(blocks)
-            nb, lpad, kvh, hd = part.shape
-            pr = part.reshape(nb, lpad // bs, bs, kvh, hd)
-            blocks = pr[ria, bia]
-            if layout.quantized:
-                blocks, sc = layout.quantize(blocks)
-                pending_scales[
-                    key.replace(f"['{name}']", f"['{name}_scale']")
-                ] = sc
-            return leaf.at[pga].set(blocks)
-
-        self.caches = jax.tree_util.tree_map_with_path(one, self.caches)
-        assert not pending_scales, (
-            f"scale pages never scattered: {sorted(pending_scales)}"
-        )
+        temporary dense prefill cache into their pool pages, inside the
+        `engine.scatter` span, as one call of the compiled, pool-donating
+        `scatter_prefill`.  Its (row, block, page) index vectors hold one
+        entry per block of the temp cache (`padded`), so an admission never
+        compiles a scatter its prefill's shape has not.  The step record
+        counts the pages written (`scatter_pages`); the span's args give
+        them and `padded`.  Shared prefix pages are never rewritten."""
+        idx = [(i, j, pg) for i, (_, _r, plan) in enumerate(batch)
+               for j, (pg, sh) in enumerate(zip(plan.pages, plan.shared))
+               if not sh]
+        n = len(idx)
+        padded = len(batch) * jax.tree.leaves(tmp)[0].shape[-3] // self.block_size
+        self.tracer.add("scatter_pages", n)
+        with self.tracer.span("engine.scatter", pages=n, padded=padded):
+            if not n:
+                return
+            # Host-side numpy indices: a committed device array would pin
+            # the call to one device and clash with a sharded pool.
+            rows, blocks, pages = np.zeros((3, padded), np.int32)
+            rows[:n], blocks[:n], pages[:n] = zip(*idx)
+            pool = jax.tree_util.tree_map_with_path(
+                lambda p, x: None if _is_table(p) else x, self.caches
+            )
+            flat, _ = jax.tree_util.tree_flatten_with_path(
+                self.scatter_fn(pool, tmp, rows, blocks, pages, np.int32(n))
+            )
+            written = {jax.tree_util.keystr(p): v for p, v in flat}
+            self.caches = jax.tree_util.tree_map_with_path(
+                lambda p, x: written.get(jax.tree_util.keystr(p), x), self.caches
+            )
 
     def _gather_prefix(self, tmp, pages: list[int]):
         """Copy written pool pages into the leading rows of a temp dense
@@ -1355,7 +1386,7 @@ class Engine:
         plen = len(req.prompt)
         lp = max(bs, min(1 << (plen - 1).bit_length(), self.num_blocks * bs))
         with self.tracer.span("engine.prefill"):
-            tmp = T.cache_init(self.cfg, 1, lp)
+            tmp = self.prefill_cache_fn(1, lp)
             tmp = self._gather_prefix(tmp, plan.pages[:lead])
             toks = np.zeros((1, lp - skip), np.int32)
             toks[0, : plen - skip] = np.asarray(req.prompt[skip:], np.int32)
@@ -1364,8 +1395,7 @@ class Engine:
                 tmp, skip,
             )
         self.tracer.add("prefill_tokens", plen - skip)
-        with self.tracer.span("engine.scatter"):
-            self._scatter_prefill(tmp, [(None, req, plan)])
+        self._scatter_prefill(tmp, [(None, req, plan)])
 
     def _live_table_width(self) -> int:
         """Logical block-table width the NEXT decode dispatch needs: the max
@@ -1397,7 +1427,7 @@ class Engine:
         self._table_width = tbl.shape[-1]
 
         def one(path, leaf):
-            if str(getattr(path[-1], "key", "")) == "table":
+            if _is_table(path):
                 shape = leaf.shape[:-1] + (tbl.shape[-1],)
                 return jnp.asarray(np.broadcast_to(tbl, shape))
             return leaf

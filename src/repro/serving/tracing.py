@@ -17,7 +17,10 @@ engine clock, seconds per span name, and the step's counters (`decode_rows`,
 request uids, `pages_in_use`, `pages_total`, `tables_refreshed`, and
 `attn_blocks_live` / `attn_blocks_grid`: the paged decode kernel's blocks of
 pages that hold live pages and that its grid spans, over the step's
-dispatches).  The uids link a request to the steps that served it.
+dispatches, and `scatter_pages`: the prompt pages admission wrote into the
+pool, which the `engine.scatter` span's args give too, with `padded`, the
+length of the write's index vectors).  The uids link a request to the steps
+that served it.
 
 Records live in a ring of RING_STEPS steps.  `records(t0, t1)` returns the
 steps that ended in [t0, t1], and raises where the ring has dropped a step
@@ -45,7 +48,8 @@ RING_STEPS = 65536
 # Counters summed into the tracer's lifetime totals (list-valued ones by
 # their length).  pages_in_use / pages_total / slots are gauges.
 ADDITIVE = ("decode_rows", "prefill_tokens", "emitted", "admitted", "finished",
-            "preempted", "tables_refreshed", "attn_blocks_live", "attn_blocks_grid")
+            "preempted", "tables_refreshed", "attn_blocks_live", "attn_blocks_grid",
+            "scatter_pages")
 
 _LIVE: weakref.WeakSet = weakref.WeakSet()
 
@@ -73,6 +77,7 @@ class StepRecord:
     tables_refreshed: int = 0
     attn_blocks_live: int = 0   # paged attention blocks holding live pages
     attn_blocks_grid: int = 0   # paged attention blocks the kernel grid spans
+    scatter_pages: int = 0      # prompt pages admission wrote into the pool
 
     @property
     def seconds(self) -> float:

@@ -273,3 +273,152 @@ def test_kv_capacity_math():
     # 256-token requests against a 2048-token worst case: 8x the requests.
     assert cap["paged"] == 8 * cap["dense"]
     assert cap["bytes_per_token"] == 2 * 16 * 2 * 64 * 2
+
+
+# ---------------------------------------------------------------------------
+# Admission's compiled, pool-donating prefill scatter (engine.scatter_prefill)
+
+
+def _eager_scatter(pool, tmp, rows, blocks, pages, kv_quant):
+    """The eager per-leaf write admission ran before the compiled scatter,
+    kept here as its reference: gather the non-shared blocks of the temp
+    dense cache, quantize them under kv8/kv4, `.at[pages].set` into a new
+    copy of each pool leaf."""
+    bs = next(x for x in jax.tree.leaves(pool)).shape[-3]
+    ria, bia, pga = (jnp.asarray(a, jnp.int32) for a in (rows, blocks, pages))
+    tmp_by_path = {jax.tree_util.keystr(p): v
+                   for p, v in jax.tree_util.tree_flatten_with_path(tmp)[0]}
+    layout = encoding.kv_layout(kv_quant)
+    pending = {}
+
+    def one(path, leaf):
+        name = str(path[-1].key)
+        key = jax.tree_util.keystr(path)
+        stacked = engine_lib._batch_axis(path) == 1
+        if name in ("k_scale", "v_scale"):
+            sc = pending.pop(key)
+            return leaf.at[:, pga].set(sc) if stacked else leaf.at[pga].set(sc)
+        part = tmp_by_path[key]
+        if stacked:
+            g, nb, lpad, kvh, hd = part.shape
+            got = part.reshape(g, nb, lpad // bs, bs, kvh, hd)[:, ria, bia]
+        else:
+            nb, lpad, kvh, hd = part.shape
+            got = part.reshape(nb, lpad // bs, bs, kvh, hd)[ria, bia]
+        if layout.quantized:
+            got, sc = layout.quantize(got)
+            pending[key.replace(f"['{name}']", f"['{name}_scale']")] = sc
+        return leaf.at[:, pga].set(got) if stacked else leaf.at[pga].set(got)
+
+    out = jax.tree_util.tree_map_with_path(one, pool)
+    assert not pending
+    return out
+
+
+def _check_scatter_against_eager(eng, kv_quant):
+    """Wrap the engine's compiled scatter: every call must write the pool
+    bitwise as the eager reference does, size its index vectors to every
+    block of the temp cache, write each real page once and never a page
+    that already holds written K/V (shared prefix pages are never
+    rewritten).  Returns the list of real page counts, one per call."""
+    fn, calls = eng.scatter_fn, []
+
+    def checked(pool, tmp, rows, blocks, pages, count):
+        n = int(count)
+        b, lp = jax.tree.leaves(tmp)[0].shape[-4:-2]
+        assert len(pages) == b * lp // eng.block_size >= n > 0
+        assert len(set(pages[:n].tolist())) == n
+        assert not any(eng.alloc.is_written(int(p)) for p in pages[:n])
+        want = jax.block_until_ready(
+            _eager_scatter(pool, tmp, rows[:n], blocks[:n], pages[:n], kv_quant))
+        want = {jax.tree_util.keystr(p): v
+                for p, v in jax.tree_util.tree_flatten_with_path(want)[0]}
+        got = fn(pool, tmp, rows, blocks, pages, count)
+        for path, leaf in jax.tree_util.tree_flatten_with_path(got)[0]:
+            key = jax.tree_util.keystr(path)
+            np.testing.assert_array_equal(np.asarray(leaf), np.asarray(want[key]), err_msg=key)
+        calls.append(n)
+        return got
+
+    eng.scatter_fn = checked
+    return calls
+
+
+@pytest.mark.parametrize("kv_quant", ["bf16", "kv8", "kv4"])
+def test_compiled_scatter_matches_eager_reference(kv_quant):
+    """Admissions of differing page counts, a batch in which one prompt
+    shares the other's leading blocks, and (bf16) a later radix-cache
+    suffix prefill: the pool matches the eager scatter bitwise after each,
+    on stacked ("groups") and unstacked ("tail") cache leaves alike; under
+    bf16 the engine's tokens match the dense engine's."""
+    import dataclasses
+    cfg = dataclasses.replace(registry.get_reduced("qwen2-1.5b"),
+                              block_pattern=("attn", "attn"), num_layers=5)
+    enc = dataclasses.replace(ENC, attn_backend="pallas") if kv_quant == "kv4" else ENC
+    params = T.model_init(jax.random.PRNGKey(0), cfg, enc)
+    eng = engine_lib.Engine(params, cfg, enc, slots=3, max_seq=64, cache_mode="paged",
+                            block_size=4, kv_quant=kv_quant)
+    assert eng.kv_quant == kv_quant
+    assert {"groups", "tail"} <= set(eng.caches)
+    calls = _check_scatter_against_eager(eng, kv_quant)
+    suffix = eng.suffix_prefill_fn = engine_lib.count_calls(eng.suffix_prefill_fn)
+    rng = np.random.RandomState(11)
+    base = rng.randint(1, cfg.vocab_size, 40).astype(np.int32)
+    waves = [
+        [rng.randint(1, cfg.vocab_size, n).astype(np.int32) for n in (13, 30)],
+        # One batch: the later prompts share the first's leading blocks,
+        # which the same scatter writes once (7 + 1 + 1 of 12 pages).
+        [base[:26], np.concatenate([base[:8], rng.randint(1, cfg.vocab_size, 3)])
+                      .astype(np.int32), base[:5]],
+        # Six shared blocks already written (bf16: a suffix prefill).
+        [np.concatenate([base[:24], rng.randint(1, cfg.vocab_size, 9)]).astype(np.int32)],
+    ]
+    uid = 0
+    for wave in waves:
+        for prompt in wave:
+            assert eng.submit(engine_lib.Request(uid=uid, prompt=prompt, max_new_tokens=2))
+            uid += 1
+        _drain(eng)
+    assert calls == [12, 9, 3]
+    assert eng.stats["prefix_cache"]["hit_blocks"] > 0, eng.stats["prefix_cache"]
+    assert (suffix.calls > 0) == (kv_quant == "bf16")
+    if kv_quant != "bf16":
+        return
+    dense = engine_lib.Engine(params, cfg, enc, slots=3, max_seq=64, cache_mode="dense")
+    uid = 0
+    for wave in waves:
+        for prompt in wave:
+            dense.submit(engine_lib.Request(uid=uid, prompt=prompt, max_new_tokens=2))
+            uid += 1
+        _drain(dense, audit=False)
+    assert {r.uid: r.generated for r in eng.finished} == \
+        {r.uid: r.generated for r in dense.finished}
+
+
+def test_compiled_scatter_programs_follow_the_prefill_shapes():
+    """Admissions at each of 1..64 prompt page counts compile one scatter
+    (and one temp-cache) program per padded prefill length, 7 here, not one
+    per page count, and each donates the pool: the old leaves are deleted,
+    written in place."""
+    cfg = registry.get_reduced("qwen2-1.5b")
+    params = T.model_init(jax.random.PRNGKey(0), cfg, ENC)
+    eng = engine_lib.Engine(params, cfg, ENC, slots=1, max_seq=128, cache_mode="paged",
+                            block_size=2)
+    rng = np.random.RandomState(12)
+    for n in range(1, 65):
+        prompt = rng.randint(1, cfg.vocab_size, 2 * n - 1).astype(np.int32)
+        assert eng.submit(engine_lib.Request(uid=n, prompt=prompt, max_new_tokens=1))
+    old = jax.tree.leaves(eng.caches["groups"][0]["k"])[0]
+    # jit objects of one function share their compiled programs in-process;
+    # no other test here scatters into a pool of this shape.
+    before = eng.scatter_fn._cache_size()
+    steps = 0
+    while eng.queue or any(r is not None for r in eng.slot_req):
+        eng.step()
+        steps += 1
+    assert old.is_deleted()
+    assert len(eng.finished) == 64 and steps == 64
+    assert eng.stats["trace"]["counters"]["scatter_pages"] == sum(range(1, 65))
+    lengths = 7  # prompts padded to 2, 4, ..., 128 tokens
+    assert eng.scatter_fn._cache_size() - before == lengths
+    assert eng.prefill_cache_fn._cache_size() == lengths

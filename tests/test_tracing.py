@@ -205,3 +205,32 @@ def test_other_step_paths_use_the_same_names(kw):
     assert all(r.decode_rows <= r.slots for r in recs)
     assert all(0 < r.attn_blocks_live <= r.attn_blocks_grid for r in recs if r.decode_rows)
     assert sum(r.emitted for r in recs) == sum(len(q.generated) for q in eng.finished)
+
+
+def test_scatter_pages_counter_and_padded_arg():
+    """Each admission's `scatter_pages` counts the prompt pages written into
+    the pool (here no prompt shares a block, so all of them), and its
+    `engine.scatter` span's args give the same count and `padded`, the
+    length of its index vectors: every block of the temp prefill cache."""
+    eng = _engine(n=5, slots=2, block_size=2)
+    args = []
+    span = eng.tracer.span
+
+    def recorded(name, **kw):
+        if name == "engine.scatter":
+            args.append(kw)
+        return span(name, **kw)
+
+    eng.tracer.span = recorded
+    while eng.queue or any(r is not None for r in eng.slot_req):
+        eng.step()
+    prompts = {q.uid: len(q.prompt) for q in eng.finished}
+    recs = [r for r in eng.tracer.records(float("-inf"), float("inf")) if r.admitted]
+    assert [r.scatter_pages for r in recs] == [a["pages"] for a in args]
+    for r, a in zip(recs, args):
+        lp = 1 << (max(prompts[u] for u in r.admitted) - 1).bit_length()
+        assert a["padded"] == len(r.admitted) * lp // eng.block_size
+    assert any(a["padded"] > a["pages"] for a in args)
+    want = sum(-(-n // eng.block_size) for n in prompts.values())
+    assert sum(a["pages"] for a in args) == want
+    assert eng.stats["trace"]["counters"]["scatter_pages"] == want
