@@ -191,7 +191,7 @@ def pages_per_block(bs: int, nb: int) -> int:
     return max(1, min(nb, _BLOCK_TOKENS // bs))
 
 
-def _copyable_pages(pool) -> bool:
+def copyable_pages(pool) -> bool:
     """True when Mosaic can copy one (bs, KV, Ds) page of `pool` out of HBM
     with an explicit DMA, as whole (8, 128) tiles of 32-bit words: the
     page's minor dims fill whole (sublane packing, 128 lane) tiles.  A page
@@ -396,7 +396,7 @@ def paged_decode_attention(
 
     Only the ceil((pos+L)/bs) live pages of a row are copied, and nothing
     like the (B, NB*bs, KV, D) `paged_gather` view is materialized.  Where
-    every pool's pages are copyable (`_copyable_pages`: bf16 with head_dim
+    every pool's pages are copyable (`copyable_pages`: bf16 with head_dim
     a multiple of 128 over an even number of KV heads) the pools are read
     in place (memory space ANY) and the grid is (B, ceil(NB / ppb)): each
     live block's page DMAs are started one grid step ahead, into the other
@@ -426,7 +426,7 @@ def paged_decode_attention(
     assert (k_scale is not None) == quantized, (kv_quant, k_scale is None)
     pools = [k_pool, v_pool] + ([k_scale, v_scale] if quantized else [])
     # Scale pages are never copyable: the DMA path streams unquantized pages.
-    dma = all(_copyable_pages(p) for p in pools)
+    dma = all(copyable_pages(p) for p in pools)
 
     def q_map(bi, *rest):
         return (bi, 0, 0, 0)

@@ -386,6 +386,8 @@ def attention_apply(
         and "table" in cache
     ):
         # Paged KV cache: pool (P, bs, KV, D) + per-slot block table (B, NB).
+        # The serving scan may pass every layer's pages stacked, (L*P, bs,
+        # KV, D), with a table that already names this layer's ones.
         # Row b writes token j into page table[b, (pos+j)//bs] at offset
         # (pos+j) % bs (the engine guarantees every written page exists and is
         # private to the slot — shared prefix pages are immutable full
@@ -410,19 +412,23 @@ def attention_apply(
         blk = jnp.minimum(posm // bs_page, table.shape[1] - 1)
         pg = table[jnp.arange(b)[:, None], blk]  # (B, S)
         off = posm % bs_page
-        if layout.quantized:
-            # Quantize on write: the pool only ever holds int storage plus
-            # the per-token scale pages riding at the same page ids.
-            kq, ksc = layout.quantize(k)
-            vq, vsc = layout.quantize(v)
-            k_pool = cache["k"].at[pg, off].set(kq)
-            v_pool = cache["v"].at[pg, off].set(vq)
-            k_scale = cache["k_scale"].at[pg, off].set(ksc)
-            v_scale = cache["v_scale"].at[pg, off].set(vsc)
-        else:
-            k_pool = cache["k"].at[pg, off].set(k)
-            v_pool = cache["v"].at[pg, off].set(v)
-            k_scale = v_scale = None
+        # The B x S new rows are one scatter per leaf, which XLA writes in
+        # place in the pool's own layout, also into the whole stacked pool
+        # the serving scan carries.
+        with jax.named_scope("kv_write"):
+            if layout.quantized:
+                # Quantize on write: the pool only ever holds int storage
+                # plus the per-token scale pages riding at the same page ids.
+                kq, ksc = layout.quantize(k)
+                vq, vsc = layout.quantize(v)
+                k_pool = cache["k"].at[pg, off].set(kq)
+                v_pool = cache["v"].at[pg, off].set(vq)
+                k_scale = cache["k_scale"].at[pg, off].set(ksc)
+                v_scale = cache["v_scale"].at[pg, off].set(vsc)
+            else:
+                k_pool = cache["k"].at[pg, off].set(k)
+                v_pool = cache["v"].at[pg, off].set(v)
+                k_scale = v_scale = None
         choice = registry_lib.select_attn(
             phase=Phase.DECODE, s=table.shape[1] * bs_page, target=enc.target,
             requested=enc.attn_backend, kv=layout.name,

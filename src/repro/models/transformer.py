@@ -21,6 +21,7 @@ import jax.numpy as jnp
 from repro.configs.base import ModelConfig
 from repro.core import packed
 from repro.core.encoding import Phase
+from repro.kernels import attn as attn_kernels
 from repro.models import layers as L
 from repro.models.blocks import BLOCKS
 
@@ -52,6 +53,27 @@ def _group_cache_init(cfg, pattern, batch, max_seq):
 
 def _stack_caches(cache, n):
     return jax.tree.map(lambda x: jnp.broadcast_to(x, (n,) + x.shape), cache)
+
+
+def _flat_pool(cache):
+    """A stacked paged cache's pool leaves (every leaf but its `table`) with
+    the layer and page axes merged, (L, P, ...) -> (L * P, ...), where the
+    serving scan carries them whole; None where it slices them per layer.
+
+    A cache without a block table (dense KV, recurrent state) is sliced.  So
+    is a pool with a leaf whose pages do not fill whole (8, 128) tiles of
+    32-bit words (`attn.copyable_pages`: head_dim 64, one KV head, kv8/kv4
+    storage and their (.., 1) scale pages): XLA:TPU stores such a leaf in
+    another order than the row-major pages that the kernel and the row
+    write take, so a carried pool would be relayouted whole around the loop
+    (and kv8/kv4 scale pages, padded 128-fold when row-major, at every
+    layer), where a per-layer slice relayouts one layer's pages."""
+    if not (isinstance(cache, dict) and "table" in cache):
+        return None
+    flat = {
+        k: v.reshape((-1,) + v.shape[2:]) for k, v in cache.items() if k != "table"
+    }
+    return flat if all(map(attn_kernels.copyable_pages, flat.values())) else None
 
 
 # ---------------------------------------------------------------------------
@@ -261,22 +283,51 @@ def forward(
             (x, aux), _ = tail_body((x, aux), (params["tail"], tuple([None] * len(tail))))
     else:
         groups = params["groups"]
+        group_caches = caches["groups"]
+        # Paged pools ride the scan's carry whole, viewed as (n_groups * P,
+        # bs, KV, Ds) (a bitcast): layer i's page p is page i*P + p, so each
+        # layer writes its new rows and reads its pages in place by page id.
+        # As xs/ys, the scan would slice every layer's pool out, write it
+        # back into a stacked output, and copy the whole pool around the
+        # loop.  Only the small block tables stay in xs; the caches that
+        # `_flat_pool` leaves out keep the xs/ys path.
+        pools = tuple(_flat_pool(c) for c in group_caches)
+        xs_caches = tuple(
+            {"table": c["table"]} if p is not None else c
+            for c, p in zip(group_caches, pools)
+        )
 
         def serve_body(carry, xs):
-            # The layer's parameters are sliced here, under their own scope,
-            # so that a trace tells the per-layer weight copies apart from
-            # the cache's.
+            xc, aux, pools = carry
             i, gc = xs
+            # The layer's parameters are sliced here, under their own scope,
+            # so that a trace names the per-layer weight copies.
             with jax.named_scope("layer_params"):
                 gp = jax.tree.map(
                     lambda a: jax.lax.dynamic_index_in_dim(a, i, keepdims=False), groups
                 )
-            return body(carry, (gp, gc))
+            layer_caches = tuple(
+                c if p is None else {**p, "table": c["table"] + i * whole["k"].shape[1]}
+                for c, p, whole in zip(gc, pools, group_caches)
+            )
+            (xc, aux), new_gc = body((xc, aux), (gp, layer_caches))
+            new_pools = tuple(
+                None if p is None else {k: c[k] for k in p}
+                for c, p in zip(new_gc, pools)
+            )
+            ys = tuple(c if p is None else None for c, p in zip(new_gc, pools))
+            return (xc, aux, new_pools), ys
 
-        (x, aux), new_group_caches = jax.lax.scan(
-            serve_body, (x, aux0), (jnp.arange(n_groups), caches["groups"])
+        (x, aux, pools), ys = jax.lax.scan(
+            serve_body, (x, aux0, pools), (jnp.arange(n_groups), xs_caches)
         )
-        new_caches = {"groups": new_group_caches}
+        new_caches = {"groups": tuple(
+            y if p is None else {
+                **{k: v.reshape(c[k].shape) for k, v in p.items()},
+                "table": c["table"],
+            }
+            for c, p, y in zip(group_caches, pools, ys)
+        )}
         if tail:
             xc, aux_c = x, aux
             new_tc = []

@@ -204,7 +204,7 @@ def test_paged_decode_parity_vs_gather_reference(geom, share, Lq):
     q, pool_k, pool_v, table, _ = _paged_case(rng, b, nb, bs, kv, d, h, Lq, share)
     ppb = attn_lib.pages_per_block(bs, nb)
     assert (nb > ppb) == geom.startswith("two_blocks")
-    assert attn_lib._copyable_pages(pool_k) == geom.endswith("dma")
+    assert attn_lib.copyable_pages(pool_k) == geom.endswith("dma")
     row0 = ppb * bs - 2 if nb > ppb else nb * bs // 2 + 3
     pos = jnp.asarray([row0, nb * bs - bs - 5, 0], jnp.int32)
     table = table.at[2].set(0)  # every entry the scratch page

@@ -11,16 +11,22 @@ The topology is described inside a module fixture only: describing it loads
 the TPU library, which one process at a time may hold.
 """
 
+import dataclasses
 import os
+import re
 
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.configs import registry
 from repro.core import targets
 from repro.core.encoding import Phase
+from repro.core.packed import EncodingConfig
 from repro.kernels import attn, ops
+from repro.models import transformer as T
+from repro.serving import engine
 
 D, Q, KV, FF = 1536, 1536, 256, 8960  # qwen2-1.5b projection widths
 HEADS, KV_HEADS, HEAD_DIM = 12, 2, 128
@@ -149,3 +155,51 @@ def test_flash_prefill_attention_compiles(one_chip):
         ),
         one_chip, q, kv, kv,
     )
+
+
+def test_paged_decode_step_writes_the_pool_in_place(one_chip):
+    """The engine's decode step for a 3-layer model at qwen2-1.5b's widths
+    on a paged bf16 pool, donated as the engine donates it: the layer scan
+    carries the pool whole, so the program holds no copy, dynamic slice or
+    dynamic-update-slice of one layer's pool or of the whole pool (what a
+    scan over the pool as xs/ys and the loop's whole-pool copies compile
+    to), and each pool leaf is aliased input to output."""
+    layers, pages = 3, 1 + SLOTS * MAX_SEQ // BLOCK
+    cfg = dataclasses.replace(registry.get_config("qwen2-1.5b"), num_layers=layers)
+    enc = EncodingConfig(enabled=True, backend="auto", attn_backend="auto",
+                         target=targets.TPU_V5E, interpret=False)
+    params = jax.eval_shape(lambda: T.model_init(jax.random.PRNGKey(0), cfg, enc))
+    caches = jax.eval_shape(lambda: T.cache_init(
+        cfg, SLOTS, MAX_SEQ, cache_mode="paged", block_size=BLOCK, num_pages=pages))
+    args = (params, caches, jax.ShapeDtypeStruct((SLOTS, 1), jnp.int32),
+            jax.ShapeDtypeStruct((SLOTS,), jnp.int32))
+    placed = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip), args
+    )
+    step = jax.jit(engine.make_decode_step(cfg, enc), donate_argnums=(1,), keep_unused=True)
+    hlo = step.lower(*placed).compile().as_text()
+    assert "paged_decode_attention" in hlo
+
+    layer_pool = pages * BLOCK * KV_HEADS * HEAD_DIM
+    moves = []
+    for name, dims, op in re.findall(
+        r"%(\S+) = \(?\w+\[([\d,]*)\][^ ]* (copy|copy-start|dynamic-slice|"
+        r"dynamic-update-slice)\(", hlo
+    ):
+        n = 1
+        for dim in filter(None, dims.split(",")):
+            n *= int(dim)
+        if n in (layer_pool, layers * layer_pool):
+            moves.append((name, op, dims))
+    assert not moves, moves
+
+    # Parameter and output numbers of the pool leaves: (params, caches, ...)
+    # in, (token, logits, caches) out.
+    cache_leaves = jax.tree_util.tree_flatten_with_path(caches)[0]
+    first_in = len(jax.tree.leaves(params))
+    alias = dict(
+        (int(o), int(i)) for o, i in re.findall(r"\{(\d+)\}: \((\d+), \{\}", hlo)
+    )
+    for j, (path, leaf) in enumerate(cache_leaves):
+        if leaf.ndim == 5:  # k, v: (layers, pages, bs, KV, D)
+            assert alias.get(2 + j) == first_in + j, (jax.tree_util.keystr(path), alias)

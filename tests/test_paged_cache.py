@@ -10,6 +10,7 @@ import pytest
 
 from repro.configs import registry
 from repro.core import encoding
+from repro.core.encoding import Phase
 from repro.core.packed import EncodingConfig
 from repro.models import layers as L
 from repro.models import transformer as T
@@ -422,3 +423,93 @@ def test_compiled_scatter_programs_follow_the_prefill_shapes():
     lengths = 7  # prompts padded to 2, 4, ..., 128 tokens
     assert eng.scatter_fn._cache_size() - before == lengths
     assert eng.prefill_cache_fn._cache_size() == lengths
+
+
+# ---------------------------------------------------------------------------
+# The serving scan's carried pool against per-layer pools
+
+
+def _layerwise_decode(params, caches, tokens, pos, *, cfg, enc):
+    """The decode forward with each layer run on its own pool: the scan
+    slices every layer's pool out of the stacked leaves (xs) and stacks the
+    written pools back (ys), the layout the serving scan keeps the pool out
+    of."""
+    from repro.models.blocks import BLOCKS
+
+    apply = BLOCKS["attn"][1]
+
+    def layers(x, lps, lcs):
+        new = []
+        for lp, lc in zip(lps, lcs):
+            x, lc, _ = apply(lp, x, cfg=cfg, enc=enc, phase=Phase.DECODE, cache=lc, pos=pos)
+            new.append(lc)
+        return x, tuple(new)
+
+    x = params["embed"][tokens].astype(cfg.activation_dtype)
+    x, groups = jax.lax.scan(lambda x, xs: layers(x, *xs), x,
+                             (params["groups"], caches["groups"]))
+    new = {"groups": groups}
+    if "tail" in caches:
+        x, new["tail"] = layers(x, params["tail"], caches["tail"])
+    x = L.norm_apply(params["final_norm"], x, cfg)
+    logits = jnp.einsum("bsd,vd->bsv", x, params["embed"],
+                        preferred_element_type=jnp.float32)[..., : cfg.vocab_size]
+    return logits, new
+
+
+@pytest.mark.parametrize("kv_quant,window,attn_backend", [
+    ("bf16", 1, "pallas"), ("bf16", 4, "pallas"), ("bf16", 1, "xla"), ("bf16", 4, "xla"),
+    ("kv8", 1, "pallas"), ("kv8", 4, "pallas"), ("kv4", 1, "pallas"), ("kv4", 4, "pallas"),
+])
+def test_carried_pool_decode_matches_layerwise_pools(kv_quant, window, attn_backend):
+    """Three decode (window 1) or verify (window 4) steps of `forward`
+    against a reference that runs every layer on its own sliced pool: the
+    logits and every pool leaf agree bitwise, on two scanned groups of two
+    layers and a tail layer, with an idle row writing the scratch page.
+    bf16 pools of head_dim 128 over 2 KV heads ride the scan's carry whole
+    (the kernel's page DMAs and the XLA gather both read the stacked pool
+    by offset page ids); kv8/kv4 pools keep their per-layer slices."""
+    import dataclasses
+
+    cfg = dataclasses.replace(
+        registry.get_reduced("qwen2-1.5b"), block_pattern=("attn", "attn"), num_layers=5,
+        num_kv_heads=2, head_dim=128, dtype="bfloat16",
+    )
+    enc = dataclasses.replace(ENC, attn_backend=attn_backend)
+    params = T.model_init(jax.random.PRNGKey(0), cfg, enc)
+    slots, bs, nb = 4, 8, 4
+    caches = T.cache_init(cfg, slots, nb * bs, cache_mode="paged", block_size=bs,
+                          num_pages=1 + 3 * nb, kv_quant=kv_quant)
+    carried = T._flat_pool(caches["groups"][0]) is not None
+    assert carried == (kv_quant == "bf16")
+
+    # Random pages everywhere (scratch page included); rows 0-2 own pages
+    # 1-12 in a shuffled order, row 3 is idle on the scratch page.
+    rng = np.random.RandomState(3)
+    table = np.zeros((slots, nb), np.int32)
+    table[:3] = 1 + rng.permutation(3 * nb).reshape(3, nb)
+
+    def fill(path, leaf):
+        if jax.tree_util.keystr(path).endswith("['table']"):
+            return jnp.broadcast_to(jnp.asarray(table), leaf.shape)
+        if jnp.issubdtype(leaf.dtype, jnp.integer):
+            info = jnp.iinfo(leaf.dtype)
+            return jnp.asarray(rng.randint(info.min, info.max + 1, leaf.shape), leaf.dtype)
+        return jnp.asarray(rng.uniform(0.01, 1.0, leaf.shape), leaf.dtype)
+
+    caches = jax.tree_util.tree_map_with_path(fill, caches)
+    assert "tail" in caches
+    forward = jax.jit(lambda p, c, t, q: T.forward(
+        p, {"tokens": t}, cfg=cfg, enc=enc, phase=Phase.DECODE, caches=c, pos=q)[:2])
+    reference = jax.jit(lambda p, c, t, q: _layerwise_decode(p, c, t, q, cfg=cfg, enc=enc))
+    got = want = caches
+    pos = np.array([5, 13, 18, 0], np.int32)  # the last write stays below nb * bs
+    for _ in range(3):
+        tokens = jnp.asarray(rng.randint(1, cfg.vocab_size, (slots, window)), jnp.int32)
+        logits, got = forward(params, got, tokens, jnp.asarray(pos))
+        ref_logits, want = reference(params, want, tokens, jnp.asarray(pos))
+        np.testing.assert_array_equal(np.asarray(logits), np.asarray(ref_logits))
+        assert jax.tree.structure(got) == jax.tree.structure(want)
+        for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+        pos[:3] += window

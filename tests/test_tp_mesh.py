@@ -198,6 +198,17 @@ _TP_IDENTITY_SCRIPT = textwrap.dedent(_ENV_HEADER + """
             if name == "kv8":
                 assert eng.stats["kv_quant"] == "kv8"
         print("IDENT_OK", name)
+    # head_dim 128: the paged pool rides the layer scan's carry whole
+    # (models/transformer._flat_pool), sharded on its KV heads.
+    CFG = registry.get_reduced("qwen2-1.5b", num_kv_heads=4, head_dim=128)
+    PARAMS = T.model_init(jax.random.PRNGKey(0), CFG, ENC)
+    kw = dict(slots=2, max_seq=64, cache_mode="paged", block_size=8)
+    base, beng = run(1, prompts=PROMPTS, **kw)
+    assert T._flat_pool(beng.caches["groups"][0]) is not None
+    for shards in (2, 4):
+        got, eng = run(shards, prompts=PROMPTS, **kw)
+        assert got == base, ("carried", shards, base, got)
+    print("IDENT_OK carried")
     print("TP_IDENTITY_OK")
 """)
 
