@@ -1,7 +1,7 @@
 """llama-3.2-1b — the paper's own evaluation model (Llama-3.2-1B-Instruct).
 
-Used by benchmarks/table1_parity.py and table2_throughput.py to mirror the
-paper's Tables 1-2.  [hf:meta-llama/Llama-3.2-1B-Instruct]"""
+Used by benchmarks/table1_parity.py to mirror the paper's Table 1.
+[hf:meta-llama/Llama-3.2-1B-Instruct]"""
 
 from repro.configs.base import ModelConfig
 

@@ -148,33 +148,6 @@ def select_kernel_blocks(
     return KernelBlocks(bm1=bm1, bn1=bn1, bk1=bk1)
 
 
-def decode_projection_hbm_bytes(
-    m: int,
-    n: int,
-    k: int,
-    *,
-    act_itemsize: int = 2,
-    weight_itemsize: int = 2,
-    out_itemsize: int = 4,
-) -> dict[str, int]:
-    """HBM traffic model for ONE decode projection (m live rows, W (n, k)).
-
-    Both paths stream the packed weight once (n*k bytes — the decode roofline
-    term) and read/write the plain activation row and output.  The unfused
-    path additionally materializes the packed activation and packed output in
-    HBM, paying a write+read round-trip for each; the fused GEMV keeps both
-    relayouts inside the kernel (see kernels/fused_gemv.py and docs/PERF.md).
-    """
-    base = n * k * weight_itemsize + m * k * act_itemsize + m * n * out_itemsize
-    pack_rt = 2 * m * k * act_itemsize      # packed-lhs write + read back
-    unpack_rt = 2 * m * n * out_itemsize    # packed-out write + read back
-    return {
-        "unfused": base + pack_rt + unpack_rt,
-        "fused": base,
-        "saved": pack_rt + unpack_rt,
-    }
-
-
 def quant_weight_stream_bytes(
     n: int,
     k: int,
@@ -191,8 +164,8 @@ def quant_weight_stream_bytes(
       w8a8 : n*k + n*4                                  (int8 + per-channel f32)
       w4a8 : n*k/2 + n*ceil(k/group)*scale_itemsize     (nibbles + group scales)
     With bf16 scales and g=16, w4a8 streams 0.625 bytes/weight — 1.6x less
-    than w8a8 and 3.2x less than bf16; the model-projected decode tokens/s
-    scale inversely (see decode_weight_stream_tok_s and docs/PERF.md)."""
+    than w8a8 and 3.2x less than bf16 (docs/DESIGN.md §w4a8 group-quantized
+    decode)."""
     if quant in ("none",):
         return n * k * weight_itemsize
     if quant in ("w8a8", "int8"):
@@ -200,15 +173,6 @@ def quant_weight_stream_bytes(
     if quant in ("w4a8", "int4"):
         return n * (k // 2) + n * math.ceil(k / group) * scale_itemsize
     raise ValueError(f"unknown quant mode {quant!r}")
-
-
-def decode_weight_stream_tok_s(
-    weight_bytes: int, target: targets_lib.TargetSpec = targets_lib.TPU_V5E
-) -> float:
-    """Upper-bound decode tokens/s from the weight-streaming roofline: every
-    generated token re-reads `weight_bytes` from HBM; nothing else scales
-    with the token count in the bandwidth-bound regime."""
-    return target.hbm_bytes_per_s / max(1, weight_bytes)
 
 
 # ---------------------------------------------------------------------------
@@ -358,101 +322,6 @@ def kv_bytes_per_token(
     return int(2 * num_layers * num_kv_heads * per_head)
 
 
-def decode_attn_hbm_bytes(
-    context: int,
-    *,
-    max_seq: int | None = None,
-    block_size: int = 16,
-    num_kv_heads: int,
-    head_dim: int,
-    num_layers: int = 1,
-    itemsize: int = 2,
-    kv_quant: str = "bf16",
-) -> dict[str, float]:
-    """Decode-attention HBM traffic model for ONE generated token of ONE
-    sequence at `context` cached tokens (all layers, K and V).
-
-    gather (the pre-kernel fallback): `paged_gather` materializes the full
-    logical view over the table width (ceil(max_seq / block) blocks) — the
-    pool pages are READ, the dense view is WRITTEN, and the attention
-    softmax READS it back: 3 passes over the table-width KV footprint,
-    independent of how much of it is live.
-
-    bounded_gather: the same fallback after the table is narrowed to the
-    slot's allocated page count (engine._with_tables / paged_gather
-    nb_blocks) — still 3 passes, but only over live blocks.
-
-    fused: the paged-decode kernel (kernels/attn.py) streams each live page
-    HBM->VMEM exactly once and materializes nothing: 1 pass over live
-    blocks.  This is the O(pool) -> O(live) conversion the attention op
-    class buys; `ratio` = fused / gather is the CI-gated headline
-    (<= 0.5 at 4k context — benchmarks/check_regression.py).
-
-    `kv_quant` prices the stream per KVLayout: kv8/kv4 shrink every row
-    (the kernel streams the int pages plus their scale pages instead of
-    bf16) — the second CI-gated headline is fused(kv8)/fused(bf16) <= 0.6
-    at 4k context (docs/PERF.md §Decode-attention traffic).
-    """
-    max_seq = max_seq or context
-    per_tok = kv_bytes_per_token(
-        num_layers, num_kv_heads, head_dim, itemsize=itemsize, kv_quant=kv_quant
-    )
-    view = -(-max_seq // block_size) * block_size
-    live = max(1, -(-context // block_size)) * block_size
-    gather = 3 * view * per_tok
-    fused = live * per_tok
-    return {
-        "gather": float(gather),
-        "bounded_gather": float(3 * live * per_tok),
-        "fused": float(fused),
-        "ratio": fused / gather,
-        "bytes_per_cached_token": float(per_tok),
-        "kv_quant": kv_quant,
-    }
-
-
-def attn_weight_crossover_tokens(
-    weight_stream_bytes: int,
-    *,
-    num_kv_heads: int,
-    head_dim: int,
-    num_layers: int,
-    itemsize: int = 2,
-    kv_quant: str = "bf16",
-) -> float:
-    """Context length where fused decode-attention traffic equals the
-    per-token weight stream: past this many cached tokens, KV traffic — not
-    the weight stream — is the decode roofline, which is why attention was
-    the mandatory next microkernel after the w4a8 weight path (docs/PERF.md
-    §Decode-attention traffic).  Quantized KV pushes the crossover out by
-    the bytes/token ratio (kv8 ~1.9x, kv4 ~3.6x at hd=64)."""
-    per_tok = kv_bytes_per_token(
-        num_layers, num_kv_heads, head_dim, itemsize=itemsize, kv_quant=kv_quant
-    )
-    return weight_stream_bytes / max(1, per_tok)
-
-
-def dense_kv_hbm_bytes(
-    slots: int, max_seq: int, num_layers: int, num_kv_heads: int, head_dim: int,
-    *, itemsize: int = 2, kv_quant: str = "bf16",
-) -> int:
-    """Dense serving reservation: every slot pays worst-case max_seq tokens."""
-    return slots * max_seq * kv_bytes_per_token(
-        num_layers, num_kv_heads, head_dim, itemsize=itemsize, kv_quant=kv_quant
-    )
-
-
-def paged_kv_hbm_bytes(
-    num_pages: int, block_size: int, num_layers: int, num_kv_heads: int,
-    head_dim: int, *, itemsize: int = 2, kv_quant: str = "bf16",
-) -> int:
-    """Paged pool footprint (scratch page included): pages x block tokens.
-    Quantized layouts count the scale pages too (KVLayout accounting)."""
-    return num_pages * block_size * kv_bytes_per_token(
-        num_layers, num_kv_heads, head_dim, itemsize=itemsize, kv_quant=kv_quant
-    )
-
-
 def kv_capacity_requests(
     hbm_budget: int,
     *,
@@ -471,9 +340,9 @@ def kv_capacity_requests(
     ceil(mean_tokens / block_size) pages per in-flight request (mean_tokens =
     typical prompt + generated length), so the capacity ratio is roughly
     max_seq / round_up(mean_tokens, block_size) — the serving-plan headroom
-    the paged engine converts into admitted requests (docs/PERF.md).
-    `kv_quant` shrinks bytes_per_token via the KVLayout, multiplying the
-    pool a fixed budget sustains (the kv8 bench gate pins >= 1.8x bf16)."""
+    the paged engine converts into admitted requests (docs/DESIGN.md §Paged
+    KV cache).  `kv_quant` shrinks bytes_per_token via the KVLayout,
+    multiplying the pool a fixed budget sustains."""
     ptb = kv_bytes_per_token(
         num_layers, num_kv_heads, head_dim, itemsize=itemsize, kv_quant=kv_quant
     )
@@ -485,60 +354,6 @@ def kv_capacity_requests(
         "paged": int(paged),
         "bytes_per_token": ptb,
         "blocks_per_request": blocks_per_req,
-    }
-
-
-def tp_kv_capacity_requests(
-    hbm_budget_per_shard: int,
-    *,
-    shards: int,
-    max_seq: int,
-    mean_tokens: int,
-    block_size: int,
-    num_layers: int,
-    num_kv_heads: int,
-    head_dim: int,
-    itemsize: int = 2,
-    kv_quant: str = "bf16",
-) -> dict[str, float]:
-    """`kv_capacity_requests` under head-parallel tensor parallelism
-    (docs/PERF.md §Tensor-parallel capacity math).
-
-    Each of `shards` devices holds the SAME per-shard HBM budget but only
-    its own kv-head slice of every page (num_kv_heads / shards heads), so a
-    token's per-shard KV footprint shrinks by the shard count and the pool
-    a fixed per-device budget sustains grows by it: capacity scales with
-    SHARDS, not just pool pages.  When the heads do NOT divide, the
-    sharding sanitizer replicates the KV cache instead (correctness
-    preserved, capacity win forfeited) — reported honestly as scaling 1.0.
-
-    Returns the dense/paged request capacities at this shard count plus
-    `scaling_vs_1` (paged capacity relative to the same budget at shards=1
-    — exactly `shards` for dividing heads; the bench gate pins >= 1.8 at
-    2 shards)."""
-    if shards < 1:
-        raise ValueError(f"shards must be >= 1, got {shards}")
-    divides = num_kv_heads % shards == 0
-    local_heads = num_kv_heads // shards if divides else num_kv_heads
-    base = kv_capacity_requests(
-        hbm_budget_per_shard, max_seq=max_seq, mean_tokens=mean_tokens,
-        block_size=block_size, num_layers=num_layers,
-        num_kv_heads=num_kv_heads, head_dim=head_dim, itemsize=itemsize,
-        kv_quant=kv_quant,
-    )
-    local = kv_capacity_requests(
-        hbm_budget_per_shard, max_seq=max_seq, mean_tokens=mean_tokens,
-        block_size=block_size, num_layers=num_layers,
-        num_kv_heads=local_heads, head_dim=head_dim, itemsize=itemsize,
-        kv_quant=kv_quant,
-    )
-    return {
-        "dense": local["dense"],
-        "paged": local["paged"],
-        "bytes_per_token_per_shard": local["bytes_per_token"],
-        "blocks_per_request": local["blocks_per_request"],
-        "kv_heads_divide": float(divides),
-        "scaling_vs_1": local["paged"] / max(1, base["paged"]),
     }
 
 

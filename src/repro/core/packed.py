@@ -9,7 +9,7 @@ them zero gradient).
 
 `EncodingConfig.backend` picks the mmt4d implementation per DESIGN.md §3.
 `enabled=False` stores plain (N, K) weights and runs the un-encoded reference
-contraction — the upstream-IREE baseline used by benchmarks/table2.
+contraction — the upstream-IREE baseline benchmarks/table1_parity.py compares against.
 """
 
 from __future__ import annotations
@@ -47,7 +47,7 @@ class EncodingConfig:
     # nibble-packed — kernels/mmt4d_q4.py).  Serving only.
     weight_quant: str = "none"
     # K elements per int4 scale group (weight_quant="int4" only).  Smaller
-    # groups buy accuracy with more scale bytes — see docs/PERF.md.
+    # groups buy accuracy with more scale bytes — see docs/DESIGN.md §w4a8 group-quantized decode.
     quant_group: int = 16
     # Cross-shard reduction dtype for contracting-dim-sharded matmuls:
     # "bfloat16" halves the partial-sum all-reduce bytes (in-shard MXU

@@ -25,7 +25,7 @@ Neither path materializes a packed activation or packed output in HBM: the
 pack of the LHS and the unpack of the result live inside the kernel, which at
 decode removes ~2*M*K*s + 2*M*N*4 bytes of HBM traffic per projection — the
 dominant non-weight traffic of the paper's bandwidth-bound decode regime (see
-docs/PERF.md for the full accounting).  The w8a8 path gets the same treatment:
+docs/DESIGN.md §Fused GEMV).  The w8a8 path gets the same treatment:
 `fused_gemv_q8_pallas` folds the factorized-scale epilogue into the dispatch.
 """
 

@@ -127,7 +127,7 @@ def mmt4d_q8(lhs4_q, rhs4_q, s_a, s_w) -> jnp.ndarray:
 # K elements sharing one int4 scale.  16 is the serving default: on the
 # reduced-model decision-preservation harness it halves the logit MSE of the
 # llama.cpp-Q4_0-style g=32 (rel MSE 0.035 vs 0.078) for +1/16 scale byte per
-# weight (bf16 scales) — see docs/PERF.md for the measured trade-off curve.
+# weight (bf16 scales) — see docs/DESIGN.md §w4a8 group-quantized decode.
 Q4_GROUP = 16
 
 

@@ -179,7 +179,7 @@ def serving_cache_shardings(caches, mesh: Mesh):
     `sanitize` drops the axis when kv_heads doesn't divide the shard count
     (e.g. the reduced test configs' kv=1 under tp=2) — the cache replicates
     and GSPMD still produces identical tokens, just without the capacity
-    win (docs/PERF.md §Tensor-parallel capacity).
+    win (docs/DESIGN.md §Tensor-parallel serving).
 
     Prefix-cache interaction: the radix tree, refcounts, tenant ledgers and
     LRU clock are HOST-side metadata, mirrored per shard by

@@ -8,10 +8,12 @@ pulls all of that into one frozen dataclass:
   * construction-time validation (`__post_init__`) — bad values fail at the
     config, not three layers into engine setup;
   * `resolve(model_cfg)` — the cross-field auto-downgrade rules (paged->dense
-    for sliding-window models, spec-off-under-sampling, grouped decode for
-    recurrent families, ...) applied against a concrete model config,
-    returning a NEW config whose fields are what the engine will actually
-    run, with every applied rule recorded in `downgrades`;
+    for sliding-window and recurrent models, spec-off-under-sampling, ...)
+    applied against a concrete model config, returning a NEW config whose
+    fields are what the engine will actually run, with every applied rule
+    recorded in `downgrades`;
+  * `decode_path(model_cfg)` — the decode mode and prefill batching, which
+    the model's blocks decide and no option selects;
   * `from_args(namespace)` — argparse routing for launch/serve.py;
   * the tensor-parallel fields `mesh_shape` / `tp_axis` for sharded serving
     over a jax device mesh (launch/mesh.build_serving_mesh).
@@ -36,7 +38,6 @@ import math
 from repro.core.encoding import KV_QUANTS
 
 SAMPLE_MODES = ("greedy", "temperature")
-DECODE_MODES = ("vectorized", "grouped")
 CACHE_MODES = ("paged", "dense")
 
 # The weight/cache sharding rules in parallel/sharding.py are keyed to the
@@ -49,6 +50,21 @@ def _attn_only(model_cfg) -> bool:
     return all(t == "attn" for t in model_cfg.block_pattern)
 
 
+def decode_path(model_cfg) -> tuple[str, bool]:
+    """(decode mode, batched prefill) for `model_cfg` — the one place both
+    are decided.
+
+    Attention-only models decode every active slot in one "vectorized"
+    dispatch: an idle row's write lands at a position the mask never reads.
+    Recurrent state (rec/rwkv) has no position mask, so those families take
+    the "grouped" per-position dispatch loop.  Batched prefill right-pads,
+    which only a full-attention cache absorbs: recurrent state and
+    ring-buffer (sliding-window) caches keep the exact per-slot prefill."""
+    attn_only = _attn_only(model_cfg)
+    window = getattr(model_cfg, "sliding_window", 0)
+    return ("vectorized" if attn_only else "grouped"), attn_only and window == 0
+
+
 @dataclasses.dataclass(frozen=True)
 class EngineConfig:
     """Frozen serving-engine configuration.  Field semantics match the
@@ -57,8 +73,6 @@ class EngineConfig:
 
     slots: int = 4
     max_seq: int = 256
-    decode_mode: str = "vectorized"
-    batch_prefill: bool = True
     cache_mode: str = "paged"
     block_size: int = 16
     pool_pages: int | None = None
@@ -69,7 +83,7 @@ class EngineConfig:
     # downgrades kv4 -> kv8 when the attention backend cannot dequantize
     # packed nibbles in-kernel (xla/reference fallbacks).
     kv_quant: str = "bf16"
-    # Radix-tree prefix cache (docs/PERF.md §Prefix caching): finished
+    # Radix-tree prefix cache (docs/DESIGN.md §Prefix caching): finished
     # requests park their immutable full KV blocks in a tree keyed by token
     # blocks; later admissions reuse the longest-common-prefix run and
     # prefill only the suffix.  Cached (refcount-0) pages are reclaimed by
@@ -80,7 +94,7 @@ class EngineConfig:
     # Per-tenant page quota (None = unlimited): an upper bound on the
     # worst-case page reservation any one tenant may hold across its
     # admitted requests, so one tenant's long-context jobs cannot starve
-    # the pool (docs/PERF.md §Prefix caching — tenant quotas).
+    # the pool (docs/DESIGN.md §Prefix caching — tenant quotas).
     tenant_quota: int | None = None
     sample: str = "greedy"
     seed: int = 0
@@ -90,7 +104,7 @@ class EngineConfig:
     logits_guard: bool = True
     token_budget: int | None = None
     slo_aging_steps: int = 64
-    # ---- tensor parallelism (docs/PERF.md §Tensor-parallel capacity) -------
+    # ---- tensor parallelism (docs/DESIGN.md §Tensor-parallel serving) ---
     # Device-mesh shape for sharded serving: (1,) = single device (the
     # default; nothing is device_put), (2,)/(4,) = 2/4-way tensor parallel.
     # A 2-d shape (d, t) adds a leading "data" axis (replicated serving
@@ -105,11 +119,6 @@ class EngineConfig:
     downgrades: tuple[str, ...] = ()
 
     def __post_init__(self):
-        if self.decode_mode not in DECODE_MODES:
-            raise ValueError(
-                f"decode_mode must be one of {DECODE_MODES}, "
-                f"got {self.decode_mode!r}"
-            )
         if self.cache_mode not in CACHE_MODES:
             raise ValueError(
                 f"cache_mode must be one of {CACHE_MODES}, "
@@ -198,28 +207,13 @@ class EngineConfig:
         attn_only = _attn_only(model_cfg)
         window = getattr(model_cfg, "sliding_window", 0)
 
-        # Vectorized decode is only sound for attention KV caches, where an
-        # inactive row's write lands at a masked position; recurrent state
-        # (rec/rwkv) has no position mask, so those families keep grouped.
-        decode_mode = self.decode_mode
-        if decode_mode == "vectorized" and not attn_only:
-            decode_mode = "grouped"
-            changes["decode_mode"] = decode_mode
-            notes.append("decode_mode:grouped(recurrent_blocks)")
-
-        # Paged KV needs position-masked attention reads and the per-slot
-        # pos vector of the vectorized step.
+        # Paged KV needs position-masked full-attention reads and the
+        # per-slot pos vector of the vectorized step (decode_path).
         cache_mode = self.cache_mode
-        if cache_mode == "paged" and (
-            not attn_only or window != 0 or decode_mode != "vectorized"
-        ):
+        if cache_mode == "paged" and not (attn_only and window == 0):
             cache_mode = "dense"
             changes["cache_mode"] = cache_mode
-            why = (
-                "recurrent_blocks" if not attn_only
-                else "sliding_window" if window != 0
-                else "grouped_decode"
-            )
+            why = "recurrent_blocks" if not attn_only else "sliding_window"
             notes.append(f"cache_mode:dense({why})")
 
         # Quantized KV layouts live in the paged pool (per-page scale
@@ -231,7 +225,7 @@ class EngineConfig:
         # Speculation needs greedy-exact acceptance and the masked verify
         # window; sampling has no greedy target, so it switches spec off.
         spec_ok = (
-            attn_only and window == 0 and decode_mode == "vectorized"
+            attn_only and window == 0
             and self.sample == "greedy" and self.draft_k > 0
         )
         if self.spec_decode and not spec_ok:
@@ -244,19 +238,10 @@ class EngineConfig:
             notes.append(f"spec_decode:off({why})")
 
         # The token-budget mixed window rides the same verify machinery.
-        budget_ok = (
-            attn_only and window == 0 and decode_mode == "vectorized"
-            and self.sample == "greedy"
-        )
+        budget_ok = attn_only and window == 0 and self.sample == "greedy"
         if self.token_budget is not None and not budget_ok:
             changes["token_budget"] = None
             notes.append("token_budget:off(needs_verify_window)")
-
-        # Batched prefill right-pads; recurrent state and ring-buffer caches
-        # would absorb the pad garbage.
-        if self.batch_prefill and not (attn_only and window == 0):
-            changes["batch_prefill"] = False
-            notes.append("batch_prefill:off(model_family)")
 
         if not changes and tuple(notes) == self.downgrades:
             return self

@@ -7,14 +7,14 @@ The `Engine` drives them for real batched requests (examples/serve_llama.py):
 slot-based continuous batching — new requests prefill into free slots while
 existing slots keep decoding.
 
-Decode fast path (decode_mode="vectorized", the default): every active slot
-decodes in ONE jitted call per engine step regardless of prompt-length skew —
-`pos` is a per-slot vector threaded through the model's cache indexing, the
-step donates the cache buffers (in-place update, no copy), and the returned
-caches replace the engine's wholesale (no per-slot merge scatter).  The
-pre-existing per-position-group dispatch loop is kept as
-decode_mode="grouped" — it is the baseline the vectorized path is benchmarked
-against (benchmarks/table2_throughput.py, BENCH_decode.json).
+Decode path, chosen by the model (config.decode_path): for attention-only
+models every active slot decodes in ONE jitted call per engine step
+regardless of prompt-length skew — `pos` is a per-slot vector threaded
+through the model's cache indexing, the step donates the cache buffers
+(in-place update, no copy), and the returned caches replace the engine's
+wholesale (no per-slot merge scatter).  Recurrent families (rec/rwkv), whose
+state has no position mask, decode through the per-position-group dispatch
+loop ("grouped") with a selective per-slot merge.
 
 Paged KV cache (cache_mode="paged", the default for attention-only models):
 KV memory is a global pool of fixed-size pages plus a per-slot block table
@@ -37,7 +37,7 @@ engine commits each slot's longest greedy-consistent draft prefix plus the
 model's own next token, and rolls rejected tokens back (dense: masked until
 overwritten; paged: trailing pages freed — audit() stays exact).  Output is
 token-identical to plain greedy decode for any drafter; acceptance only buys
-dispatch amortization (docs/PERF.md §Speculative decode).
+dispatch amortization (docs/DESIGN.md §Speculative decode).
 
 Hardened request lifecycle (docs/ROBUSTNESS.md): every request carries a
 status (queued -> running -> ok | cancelled | expired | error | rejected).
@@ -92,7 +92,7 @@ from repro.serving import faults as faults_lib
 from repro.serving import paged as paged_lib
 from repro.serving import spec as spec_lib
 from repro.serving import tracing as tracing_lib
-from repro.serving.config import EngineConfig
+from repro.serving.config import EngineConfig, decode_path
 
 
 def make_prefill_step(cfg, enc: EncodingConfig) -> Callable:
@@ -479,7 +479,8 @@ SLO_CLASSES = {"interactive": 0, "standard": 1, "batch": 2}
 
 class TokenBudgetScheduler:
     """Admission / budget-split / preemption policy for the token-budget
-    mixed step (`Engine(token_budget=...)`; docs/PERF.md §Token budget).
+    mixed step (`Engine(token_budget=...)`; docs/DESIGN.md §Token-budget
+    scheduling).
 
     Admission order: SLO class rank (interactive < standard < batch) with
     starvation-free aging — every `aging_steps` engine steps a request
@@ -540,27 +541,27 @@ class TokenBudgetScheduler:
 class Engine:
     """Slot-based continuous batching on a fixed decode batch.
 
-    decode_mode:
-      "vectorized" (default) — one jitted decode per step for ALL active slots:
-        per-slot `pos` vector through the model, donated cache buffers, caches
-        replaced wholesale (inactive rows absorb masked-off writes that the
-        next admission's prefill overwrites).
-      "grouped" — the per-position-group dispatch loop with selective
-        slot_merge; kept as the benchmark baseline.
+    decode_mode (read-only, from the model — config.decode_path):
+      "vectorized" (attention-only models) — one jitted decode per step for
+        ALL active slots: per-slot `pos` vector through the model, donated
+        cache buffers, caches replaced wholesale (inactive rows absorb
+        masked-off writes that the next admission's prefill overwrites).
+      "grouped" (recurrent blocks) — the per-position-group dispatch loop
+        with selective slot_merge.
 
-    batch_prefill: admit every queued request that fits in one right-padded
-    prefill call (attention-only, full-attention models; recurrent state and
-    ring-buffer caches would absorb the pad garbage, so those families keep
-    the exact per-slot prefill).  The paged path always batch-prefills — it
-    prefills into a throwaway dense cache and scatters only real prompt
-    blocks into the pool, so pad garbage never lands anywhere persistent and
-    the flag has nothing to protect against.
+    batch_prefill (read-only, from the model): admit every queued request
+    that fits in one right-padded prefill call (attention-only,
+    full-attention models; recurrent state and ring-buffer caches would
+    absorb the pad garbage, so those families keep the exact per-slot
+    prefill).  The paged path always batch-prefills — it prefills into a
+    throwaway dense cache and scatters only real prompt blocks into the
+    pool, so pad garbage never lands anywhere persistent.
 
     cache_mode:
       "paged" (default) — pool-of-pages KV with per-slot block tables,
         prefix reuse and preemption (module docstring).  Requires
-        attention-only, no sliding window, vectorized decode; anything else
-        auto-routes to dense.
+        attention-only, no sliding window; anything else auto-routes to
+        dense.
       "dense" — the worst-case (slots, max_seq) reservation (parity baseline).
 
     sample: "greedy" (default) or "temperature" — per-slot temperature
@@ -583,22 +584,21 @@ class Engine:
     K/V stays masked until overwritten) — but paged slots truncate back to
     the pages their committed length needs, returning draft-only pages to
     the pool (`audit()` stays exact).  Requires attention-only, no sliding
-    window, vectorized decode, greedy sampling; anything else switches it
-    off.
+    window, greedy sampling; anything else switches it off.
 
     token_budget: unified continuous batching (Sarathi-style).  Every step
     runs ONE mixed decode-phase dispatch whose (B, L) window packs decode
     rows (1 token each, or their spec-verify window) beside chunked-prefill
     rows (each spending a slice of the remaining budget on its prompt), so
     a long prompt admitted mid-decode streams into the cache WITHOUT ever
-    pausing decode — zero decode-stall steps by construction, gated in
-    benchmarks/check_regression.py.  Admission order, per-step budget
+    pausing decode — zero decode-stall steps by construction
+    (tests/test_token_budget.py).  Admission order, per-step budget
     split, and preemption ordering come from TokenBudgetScheduler
     (Request.slo_class + starvation-free aging).  A prefill row's final
     chunk yields its first generated token in the same dispatch, so output
     is token-identical to the phase-split engine.  Needs the spec-verify
-    machinery (attention-only, no sliding window, vectorized decode,
-    greedy); anything else turns it off and the phase-split path remains.
+    machinery (attention-only, no sliding window, greedy); anything else
+    turns it off and the phase-split path remains.
 
     stream_cb: optional callable (req, token) invoked synchronously as each
     token is committed — streaming output for servers (launch/serve.py).
@@ -622,9 +622,9 @@ class Engine:
         # `Engine(params, cfg, enc, slots=8, ...)` remains supported as a
         # deprecation shim — the legacy kwargs are folded into
         # EngineConfig(**kwargs) — but config= is the first-class path.
-        # Cross-field auto-downgrades (paged->dense, spec-off-under-sampling,
-        # grouped decode for recurrent families) happen in config.resolve(),
-        # not here; the applied rules are surfaced in stats["downgrades"].
+        # Cross-field auto-downgrades (paged->dense, spec-off-under-sampling)
+        # happen in config.resolve(), not here; the applied rules are
+        # surfaced in stats["downgrades"].
         if config is None:
             config = EngineConfig(**kwargs)
         elif kwargs:
@@ -637,7 +637,7 @@ class Engine:
         self.cfg, self.enc = cfg, enc
         self.slots = config.slots
         self.max_seq = config.max_seq
-        # ---- tensor parallelism (docs/PERF.md §Tensor-parallel capacity) ---
+        # ---- tensor parallelism (docs/DESIGN.md §Tensor-parallel serving) ----
         # mesh_shape=(N,) with N > 1 shards the serving step across a device
         # mesh: weight streams column/row-parallel (parallel/sharding.py),
         # KV caches head-parallel (serving_cache_shardings), dispatch still
@@ -700,7 +700,7 @@ class Engine:
         #   corrupt_slots / held_pages (serving/faults.FaultSchedule) —
         #   injection points, all no-ops when None.
         # logits_guard: non-finite check on committed logits; quarantines the
-        #   offending slot only (measured overhead in docs/ROBUSTNESS.md).
+        #   offending slot only (docs/ROBUSTNESS.md §Guard overhead).
         self.max_queue = config.max_queue
         self.clock = clock if clock is not None else time.perf_counter
         self.hooks = fault_hooks
@@ -716,10 +716,11 @@ class Engine:
         self.step_count = 0
         slots = config.slots
         max_seq = config.max_seq
-        # Model-dependent mode downgrades (grouped decode for recurrent
-        # families, paged->dense for sliding windows, spec/budget off where
-        # the verify window cannot run) were applied by config.resolve().
-        self.decode_mode = config.decode_mode
+        # Model-dependent mode downgrades (paged->dense for sliding windows
+        # and recurrent families, spec/budget off where the verify window
+        # cannot run) were applied by config.resolve(); the decode path and
+        # prefill batching follow from the model alone.
+        self._decode_mode, self._batch_prefill = decode_path(cfg)
         self.cache_mode = config.cache_mode
         self.sample = config.sample
         self._base_key = jax.random.PRNGKey(config.seed)
@@ -862,7 +863,14 @@ class Engine:
         self.slot_prefill_done = np.zeros(slots, np.int64)
         self.queue: collections.deque[Request] = collections.deque()
         self.finished: list[Request] = []
-        self.batch_prefill = bool(config.batch_prefill)
+
+    @property
+    def decode_mode(self) -> str:
+        return self._decode_mode
+
+    @property
+    def batch_prefill(self) -> bool:
+        return self._batch_prefill
 
     def _reject(self, req: Request, reason: str, detail: str) -> Rejected:
         req.status = "rejected"
@@ -1380,7 +1388,7 @@ class Engine:
         path chunked prefill uses), and the computed suffix blocks scatter
         into the plan's private pages — the prefill-FLOPs-saved half of the
         cache win (the write-skip half applies on every layout; see
-        docs/PERF.md §Prefix caching)."""
+        docs/DESIGN.md §Prefix caching)."""
         bs = self.block_size
         skip = lead * bs
         plen = len(req.prompt)
@@ -1526,7 +1534,7 @@ class Engine:
             # quantized paged layout (kv8/kv4) with per-page scales.
             "kv_quant": getattr(self, "kv_quant", "bf16"),
             # Serving weight format (drives the decode weight-stream roofline;
-            # see encoding.quant_weight_stream_bytes and docs/PERF.md).
+            # see encoding.quant_weight_stream_bytes and docs/DESIGN.md).
             "weight_quant": self.enc.weight_quant,
             # Resolved attention op-class backend for this engine's CURRENT
             # decode regime (kernels/registry.py select_attn; "pallas" = the
@@ -1601,7 +1609,7 @@ class Engine:
             out["config_downgrades"] = list(self.config.downgrades)
         if self.spec_decode:
             st = dict(self.spec_stats)
-            # Amortization terms (docs/PERF.md §Speculative decode): a slot's
+            # Amortization terms (docs/DESIGN.md §Speculative decode): a slot's
             # verify commits mean_accepted_len tokens per dispatch, so decode
             # dispatches per token is its reciprocal.
             st["acceptance_rate"] = st["accepted"] / max(st["proposed"], 1)
@@ -2130,7 +2138,7 @@ class Engine:
         and prefill rows spend a chunk of their remaining prompt — a long
         prompt admitted mid-decode streams into the cache beside the
         decoding slots instead of pausing them (zero decode-stall steps by
-        construction; gated in benchmarks/check_regression.py).
+        construction; tests/test_token_budget.py).
 
         The window generalizes the spec-verify machinery: row r holds
         tokens for positions start_r .. start_r + L - 1, where start_r is
@@ -2454,9 +2462,10 @@ class Engine:
                 nxt = np.asarray(nxt)
             with tr.span("engine.commit"):
                 return self._commit(active, nxt, bad)
-        # Grouped baseline: slots admitted with different prompt lengths decode
-        # on their own pos via per-pos grouping; each group's cache rows merge
-        # back selectively so other groups' histories stay untouched.
+        # Grouped (recurrent state): slots admitted with different prompt
+        # lengths decode on their own pos via per-pos grouping; each group's
+        # cache rows merge back selectively so other groups' histories stay
+        # untouched.
         with tr.span("engine.dispatch"):
             last_tokens = self._last_tokens(active)
         tr.add("decode_rows", len(active))

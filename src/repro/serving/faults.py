@@ -39,8 +39,7 @@ Fault taxonomy (docs/ROBUSTNESS.md):
 
 Schedules round-trip through JSON (`to_json`/`from_json`); the committed
 adversarial schedules live in tests/fault_schedules/ and are replayed by the
-chaos-conformance harness (tests/test_chaos.py) and the `chaos` bench section
-(benchmarks/table2_throughput.py).  `FaultSchedule.random(seed, ...)`
+chaos-conformance harness (tests/test_chaos.py).  `FaultSchedule.random(seed, ...)`
 generates new ones — by construction only from this taxonomy, so a schedule
 that finds a new failure mode can be committed verbatim.
 """

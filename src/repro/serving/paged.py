@@ -6,8 +6,8 @@ is spent on sequence positions that mostly never exist (short prompts, early
 decode).  The paged plan instead carves the per-layer cache into a global pool
 of fixed-size pages (`block_size` tokens each) and gives every slot a block
 table mapping logical block j -> physical page.  Capacity then scales with
-TOKENS IN FLIGHT, not slots x max_seq (core/encoding.py has the math; the
-capacity-vs-dense sweep lives in benchmarks/table2_throughput.py).
+TOKENS IN FLIGHT, not slots x max_seq (core/encoding.kv_capacity_requests;
+docs/DESIGN.md §Paged KV cache).
 
 This module is the host-side bookkeeping only (pure numpy/python — nothing
 here is traced):

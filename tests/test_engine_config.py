@@ -12,7 +12,7 @@ from repro.configs import registry
 from repro.core.packed import EncodingConfig
 from repro.models import transformer as T
 from repro.serving import engine as engine_lib
-from repro.serving.config import EngineConfig
+from repro.serving.config import EngineConfig, decode_path
 
 ENC = EncodingConfig(enabled=True, backend="xla")
 
@@ -20,7 +20,6 @@ ENC = EncodingConfig(enabled=True, backend="xla")
 # ---- validation ------------------------------------------------------------
 
 @pytest.mark.parametrize("kwargs,match", [
-    (dict(decode_mode="turbo"), "decode_mode"),
     (dict(cache_mode="ring"), "cache_mode"),
     (dict(sample="nucleus"), "sample"),
     (dict(slots=0), "slots"),
@@ -76,12 +75,10 @@ def test_resolve_attn_only_is_identity():
 def test_resolve_recurrent_family_downgrades():
     cfg = registry.get_reduced("rwkv6-1.6b")
     r = EngineConfig(spec_decode=True, token_budget=32).resolve(cfg)
-    assert r.decode_mode == "grouped"
+    assert decode_path(cfg) == ("grouped", False)
     assert r.cache_mode == "dense"
     assert r.spec_decode is False
     assert r.token_budget is None
-    assert r.batch_prefill is False
-    assert "decode_mode:grouped(recurrent_blocks)" in r.downgrades
     assert "cache_mode:dense(recurrent_blocks)" in r.downgrades
 
 
@@ -90,6 +87,7 @@ def test_resolve_sliding_window_forces_dense():
         "mixtral-8x22b", capacity_factor=8.0, sliding_window=6
     )
     r = EngineConfig().resolve(cfg)
+    assert decode_path(cfg) == ("vectorized", False)
     assert r.cache_mode == "dense"
     assert "cache_mode:dense(sliding_window)" in r.downgrades
 
@@ -103,11 +101,15 @@ def test_resolve_sampling_switches_spec_off():
     assert "token_budget:off(needs_verify_window)" in r.downgrades
 
 
-def test_resolve_grouped_decode_forces_dense():
+def test_resolve_attn_only_takes_vectorized_paged_batched():
     cfg = registry.get_reduced("qwen2-1.5b")
-    r = EngineConfig(decode_mode="grouped").resolve(cfg)
-    assert r.cache_mode == "dense"
-    assert "cache_mode:dense(grouped_decode)" in r.downgrades
+    r = EngineConfig().resolve(cfg)
+    assert decode_path(cfg) == ("vectorized", True)
+    assert r.cache_mode == "paged" and r.downgrades == ()
+    # The model picks the decode path; no option asks for another.
+    for kw in ({"decode_mode": "grouped"}, {"batch_prefill": False}):
+        with pytest.raises(TypeError):
+            EngineConfig(**kw)
 
 
 def test_resolve_idempotent():

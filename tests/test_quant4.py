@@ -122,7 +122,7 @@ def test_model_level_w4a8_decision_preservation():
     random-init reduced model has near-tied top-2 logits at some positions
     where any rounding flips the pick.  The claims that hold, asserted here:
       * logits stay close: relative MSE < 0.05 (measured 0.036 at the g=16
-        serving default; g=32 doubles it — docs/PERF.md),
+        serving default; g=32 doubles it — docs/DESIGN.md),
       * token-identical to the fp reference at every DECIDED position:
         fp top-2 margin above two standard deviations of the int4 noise on
         a logit difference (2 * sqrt(2) * rms logit error).  Below that the

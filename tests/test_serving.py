@@ -145,25 +145,26 @@ def _skewed_requests(cfg, n=4, seed=3, max_new=6):
 
 
 def test_engine_vectorized_matches_grouped_skewed():
-    """Position-vectorized decode == per-group baseline, token for token,
-    under maximally skewed prompt lengths."""
+    """Position-vectorized decode == naive sequential decode, token for
+    token, under maximally skewed prompt lengths."""
     cfg = registry.get_reduced("qwen2-1.5b")
     params = T.model_init(jax.random.PRNGKey(0), cfg, ENC)
-    got = {}
-    for mode in ("grouped", "vectorized"):
-        eng = engine_lib.Engine(
-            params, cfg, ENC, slots=4, max_seq=32, decode_mode=mode
-        )
-        for r in _skewed_requests(cfg):
-            eng.submit(r)
-        got[mode] = {r.uid: r.generated for r in eng.run()}
-    assert got["vectorized"] == got["grouped"]
+    eng = engine_lib.Engine(params, cfg, ENC, slots=4, max_seq=32)
+    assert eng.decode_mode == "vectorized"
+    reqs = _skewed_requests(cfg)
+    for r in reqs:
+        eng.submit(dataclasses.replace(r, generated=[]))
+    got = {r.uid: r.generated for r in eng.run()}
+    for r in reqs:
+        assert got[r.uid] == _sequential_decode(
+            params, cfg, r.prompt, r.max_new_tokens, 32
+        ), r.uid
 
 
 def test_engine_vectorized_matches_grouped_sliding_window():
     """Per-row ring-buffer scatter + (B,) age mask: vectorized decode matches
-    the grouped baseline on a sliding-window config, decoding well past the
-    window so every row's ring wraps at a different step."""
+    naive sequential decode on a sliding-window config, decoding well past
+    the window so every row's ring wraps at a different step."""
     cfg = registry.get_reduced("qwen2-1.5b", sliding_window=6)
     params = T.model_init(jax.random.PRNGKey(0), cfg, ENC)
     rng = np.random.RandomState(5)
@@ -175,16 +176,16 @@ def test_engine_vectorized_matches_grouped_sliding_window():
         )
         for i in range(4)
     ]
-    got = {}
-    for mode in ("grouped", "vectorized"):
-        eng = engine_lib.Engine(
-            params, cfg, ENC, slots=4, max_seq=32, decode_mode=mode
-        )
-        assert not eng.batch_prefill  # windowed: per-slot exact prefill
-        for r in reqs:
-            eng.submit(dataclasses.replace(r, generated=[]))
-        got[mode] = {r.uid: r.generated for r in eng.run()}
-    assert got["vectorized"] == got["grouped"]
+    eng = engine_lib.Engine(params, cfg, ENC, slots=4, max_seq=32)
+    assert eng.decode_mode == "vectorized"
+    assert not eng.batch_prefill  # windowed: per-slot exact prefill
+    for r in reqs:
+        eng.submit(dataclasses.replace(r, generated=[]))
+    got = {r.uid: r.generated for r in eng.run()}
+    for r in reqs:
+        assert got[r.uid] == _sequential_decode(
+            params, cfg, r.prompt, r.max_new_tokens, 32
+        ), r.uid
 
 
 def test_engine_vectorized_single_decode_dispatch():
@@ -200,11 +201,13 @@ def test_engine_vectorized_single_decode_dispatch():
     eng.decode_fn = _count_calls(eng.decode_fn)
     eng.step()
     assert eng.decode_fn.calls == 1
-    # The grouped baseline pays one dispatch per distinct position.
-    eng_g = engine_lib.Engine(
-        params, cfg, ENC, slots=4, max_seq=32, decode_mode="grouped"
-    )
-    for r in _skewed_requests(cfg, max_new=8):
+    # Grouped decode (recurrent state) pays one dispatch per distinct
+    # position.
+    cfg_r = registry.get_reduced("rwkv6-1.6b")
+    params_r = T.model_init(jax.random.PRNGKey(0), cfg_r, ENC)
+    eng_g = engine_lib.Engine(params_r, cfg_r, ENC, slots=4, max_seq=32)
+    assert eng_g.decode_mode == "grouped"
+    for r in _skewed_requests(cfg_r, max_new=8):
         eng_g.submit(r)
     eng_g.step()
     eng_g.decode_fn = _count_calls(eng_g.decode_fn)
@@ -229,27 +232,24 @@ def test_engine_batched_prefill_single_call():
 def test_engine_vectorized_falls_back_for_recurrent_state():
     """Recurrent state has no position mask, so an idle slot's rows would
     absorb token-0 updates each vectorized step and later admissions would
-    prefill from that garbage.  The engine must fall back to grouped decode —
-    and a late-admitted request must generate the same tokens either way."""
+    prefill from that garbage.  The engine must take grouped decode — and a
+    late-admitted request must generate what it generates served alone."""
     cfg = registry.get_reduced("rwkv6-1.6b")
     params = T.model_init(jax.random.PRNGKey(0), cfg, ENC)
     rng = np.random.RandomState(7)
     pa = rng.randint(1, cfg.vocab_size, 4).astype(np.int32)
     pb = rng.randint(1, cfg.vocab_size, 5).astype(np.int32)
-    got = {}
-    for mode in ("grouped", "vectorized"):
-        eng = engine_lib.Engine(
-            params, cfg, ENC, slots=2, max_seq=32, decode_mode=mode
-        )
-        if mode == "vectorized":
-            assert eng.decode_mode == "grouped"  # the guard itself
-        eng.submit(engine_lib.Request(uid=0, prompt=pa, max_new_tokens=6))
-        for _ in range(3):  # slot 1 idles for 3 steps before B arrives
-            eng.step()
-        eng.submit(engine_lib.Request(uid=1, prompt=pb, max_new_tokens=6))
-        eng.run()
-        got[mode] = {r.uid: r.generated for r in eng.finished}
-    assert got["vectorized"] == got["grouped"]
+    eng = engine_lib.Engine(params, cfg, ENC, slots=2, max_seq=32)
+    assert eng.decode_mode == "grouped"  # the guard itself
+    eng.submit(engine_lib.Request(uid=0, prompt=pa, max_new_tokens=6))
+    for _ in range(3):  # slot 1 idles for 3 steps before B arrives
+        eng.step()
+    eng.submit(engine_lib.Request(uid=1, prompt=pb, max_new_tokens=6))
+    eng.run()
+    got = {r.uid: r.generated for r in eng.finished}
+    alone = engine_lib.Engine(params, cfg, ENC, slots=2, max_seq=32)
+    alone.submit(engine_lib.Request(uid=1, prompt=pb, max_new_tokens=6))
+    assert got[1] == alone.run()[0].generated
 
 
 def test_engine_rejects_nonpositive_max_new_tokens():
@@ -272,8 +272,8 @@ def test_engine_rejects_nonpositive_max_new_tokens():
 
 
 # ---------------------------------------------------------------------------
-# Randomized scheduler-conformance harness: paged/vectorized, dense/vectorized,
-# dense/grouped and naive sequential decode must emit token-identical outputs
+# Randomized scheduler-conformance harness: paged/vectorized, dense/vectorized
+# and naive sequential decode must emit token-identical outputs
 # on fuzzed request streams (skewed prompt lengths, staggered arrivals, mixed
 # max_new_tokens, pool sizes that force preemption, shared prefixes).
 
@@ -366,17 +366,13 @@ def test_scheduler_conformance_randomized(seed, pool):
     got["dense_vec"], _ = _run_engine_stream(
         params, cfg, stream, slots=3, max_seq=max_seq, cache_mode="dense"
     )
-    got["dense_grouped"], _ = _run_engine_stream(
-        params, cfg, stream, slots=3, max_seq=max_seq,
-        cache_mode="dense", decode_mode="grouped",
-    )
     got["sequential"] = {
         req.uid: _sequential_decode(
             params, cfg, req.prompt, req.max_new_tokens, max_seq
         )
         for _, req in stream
     }
-    assert got["paged"] == got["dense_vec"] == got["dense_grouped"] == got["sequential"]
+    assert got["paged"] == got["dense_vec"] == got["sequential"]
     stats = eng_paged.stats
     if pool == "tight":
         assert stats["preemptions"] > 0, stats  # the stream must exercise eviction

@@ -285,7 +285,7 @@ def test_spec_decode_identity_under_adversarial_drafter(model):
 def test_spec_decode_oracle_drafter_amortizes_dispatches(model):
     """A full-knowledge drafter makes every draft accepted: per-slot verify
     dispatches collapse to ceil(T / (k+1)) — the acceptance->amortization
-    contract the bench gates (docs/PERF.md)."""
+    contract (docs/DESIGN.md §Speculative decode)."""
     cfg, params = model
     max_seq, max_new, k = 64, 12, 3
     rng = np.random.RandomState(7)

@@ -4,12 +4,11 @@ preemption ordering), and the latent-scheduler-bug sweep that rode along.
 
 The load-bearing contract is TOKEN IDENTITY: for any arrival pattern, the
 mixed engine must emit exactly what the phase-split engine emits (which is
-itself pinned token-identical between paged / dense / grouped elsewhere) —
+itself pinned token-identical between paged / dense / sequential elsewhere) —
 chunk boundaries, window padding, budget splits, and spec windows are all
 invisible in the output.  On top of that, the stall metric the whole design
 exists for: a long prompt admitted mid-decode must cost ZERO decode-stall
-steps (every live decoding slot emits every step), gated here and in
-benchmarks/check_regression.py.
+steps (every live decoding slot emits every step), gated here.
 """
 
 import jax
@@ -265,13 +264,21 @@ def test_draft_budget_split():
 
 
 def test_token_budget_degrades_like_spec():
-    """Configurations that cannot run a verify window run phase-split (the
-    spec_decode degrade convention), never a broken mixed path."""
-    eng = _engine(decode_mode="grouped", token_budget=16)
+    """Configurations that cannot run a verify window (here a sliding-window
+    model) run phase-split (the spec_decode degrade convention), never a
+    broken mixed path."""
+    cfg = registry.get_reduced("qwen2-1.5b", sliding_window=6)
+    params = T.model_init(jax.random.PRNGKey(0), cfg, ENC)
+
+    def engine(**kw):
+        return engine_lib.Engine(params, cfg, ENC, slots=3, max_seq=64, **kw)
+
+    eng = engine(token_budget=16)
     assert eng.scheduler is None and eng.token_budget is None
+    assert "token_budget:off(needs_verify_window)" in eng.config.downgrades
     prompts = _prompts(seed=2, n=2)
     _submit_all(eng, prompts, max_new=4)
-    ref = _engine(decode_mode="grouped")
+    ref = engine()
     _submit_all(ref, prompts, max_new=4)
     assert _drive(eng) == _drive(ref)
 
