@@ -6,9 +6,10 @@ configuration (the structural profile used in §Perf).
 (quant, phase, M-bucket, target) it measures the candidate kernel-block
 shapes on a representative shape and persists the winners to the checked-in
 tuned table (src/repro/kernels/tuned_table.json) that
-`repro.kernels.registry.select` consults at dispatch time.  On this CPU
-container the timings run interpret-mode Pallas — relative ordering between
-block shapes is directional; re-run --tune on real hardware to re-measure."""
+`repro.kernels.registry.select` consults at dispatch time.  The table's keys
+name the tpu-v5e target, so `--tune` runs only where JAX's backend is a TPU:
+without one every kernel would run in Pallas's interpreter, whose timings
+say nothing of the chip, and it exits without writing the table."""
 
 from __future__ import annotations
 
@@ -135,11 +136,9 @@ def _tune_attn(entries: dict, *, iters: int) -> None:
     full-precision temp cache; quantization happens at scatter.
 
     Like the matmul tuner, the recorded backend is the STATIC POLICY, never
-    a cross-backend measurement: on this interpret-mode CPU container the
-    jnp reference beats interpreted Pallas at every shape, so measuring
-    backends here would permanently route serving off the kernels.  A
-    target where the reference genuinely wins a bucket gets its entry
-    pinned by a real-hardware measurement (the same convention as the
+    a cross-backend measurement at the tuner's toy shapes.  A target where
+    the reference genuinely wins a bucket gets its entry pinned by a
+    measurement at the served shapes (the same convention as the
     hand-pinned tpu-v5e m64 "fused" matmul entries)."""
     target = targets.TPU_V5E
     rng = np.random.RandomState(0)
@@ -171,11 +170,11 @@ def _tune_attn(entries: dict, *, iters: int) -> None:
                         if layout.quantized:
                             fn = lambda: attn_lib.dense_decode_attention(
                                 q, kq, vq, pos, k_scale=ks, v_scale=vs,
-                                kv_quant=kvq, kv_chunk=kc, interpret=True,
+                                kv_quant=kvq, kv_chunk=kc, interpret=False,
                             )
                         else:
                             fn = lambda: attn_lib.dense_decode_attention(
-                                q, k, v, pos, kv_chunk=kc, interpret=True
+                                q, k, v, pos, kv_chunk=kc, interpret=False
                             )
                     else:
                         sq = min(s_rep, 256)  # prefill band; KV carries S
@@ -184,7 +183,7 @@ def _tune_attn(entries: dict, *, iters: int) -> None:
                         off = s_rep - sq
                         fn = lambda: attn_lib.flash_prefill_attention(
                             q, k, v, causal=True, q_offset=off,
-                            q_chunk=qc, kv_chunk=kc, interpret=True,
+                            q_chunk=qc, kv_chunk=kc, interpret=False,
                         )
                     t = _time(fn, iters=iters, warmup=1)
                     print(f"tune/{key}/blocks={qc}x{kc},{t * 1e6:.1f},us")
@@ -209,7 +208,17 @@ def tune(
 
     `op_classes` picks which classes to re-measure; keys of classes NOT
     re-measured this run are carried over from the existing table unchanged
-    (a partial retune must not drop the other class's entries)."""
+    (a partial retune must not drop the other class's entries).
+
+    Refuses (SystemExit, table untouched) without a TPU backend: the
+    entries are keyed tpu-v5e, and interpret-mode timings cannot stand for
+    the chip."""
+    if not targets.has_tpu_backend():
+        raise SystemExit(
+            "kernel_bench --tune: no TPU backend, so the kernels would run in "
+            "Pallas's interpreter; refusing to write tpu-v5e entries from "
+            "interpret-mode timings"
+        )
     target = targets.TPU_V5E
     n, k = 1024, 256  # N1=8, K1=2: every candidate divides the tile counts
     rng = np.random.RandomState(0)
@@ -227,17 +236,17 @@ def tune(
         if quant == "none":
             fn = lambda: ops.encoded_matmul(
                 x, packed[quant][0], n=n, phase=phase, backend=backend,
-                blocks=blocks, out_dtype=jnp.float32, interpret=True,
+                blocks=blocks, out_dtype=jnp.float32, interpret=False,
             )
         elif quant == "w8a8":
             fn = lambda: ops.encoded_matmul_q8(
                 x, *packed[quant], n=n, phase=phase, backend=backend,
-                blocks=blocks, out_dtype=jnp.float32, interpret=True,
+                blocks=blocks, out_dtype=jnp.float32, interpret=False,
             )
         else:
             fn = lambda: ops.encoded_matmul_q4(
                 x, *packed[quant], n=n, phase=phase, backend=backend,
-                blocks=blocks, out_dtype=jnp.float32, interpret=True,
+                blocks=blocks, out_dtype=jnp.float32, interpret=False,
             )
         return _time(fn, iters=iters, warmup=1)
 

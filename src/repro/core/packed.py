@@ -153,6 +153,7 @@ def linear_apply(
             out_dtype=out_dtype,
             acc_dtype=acc_dtype,
             interpret=enc.interpret,
+            layer=params.get("layer"),
         )
     else:
         w_t = params["w_t"]

@@ -34,6 +34,7 @@ from __future__ import annotations
 import functools
 from typing import Any
 
+import jax
 import jax.numpy as jnp
 
 from repro.core import encoding
@@ -127,8 +128,13 @@ def encoded_matmul(
     out_dtype: Any = None,
     acc_dtype: Any = jnp.float32,
     interpret: bool | None = None,
+    layer: jnp.ndarray | int | None = None,
 ) -> jnp.ndarray:
     """x (..., K) @ W^T where rhs4 is the packed (N1, K1, N0, K0) weight.
+
+    With `layer`, rhs4 is a layer scan's stack of packed weights
+    (L, N1, K1, N0, K0) and W is its layer `layer`: the fused decode GEMV
+    streams it in place, every other path slices it out first.
 
     Returns (..., n) in `out_dtype` (default: x.dtype). `acc_dtype` is the
     cross-shard reduction dtype (see EncodingConfig.reduce_dtype); in-shard
@@ -140,7 +146,7 @@ def encoded_matmul(
     target = targets_lib.resolve(target)
     interpret = targets_lib.resolve_interpret(interpret)
     out_dtype = out_dtype or x.dtype
-    n1, k1, n0, k0 = rhs4.shape
+    n1, k1, n0, k0 = rhs4.shape[-4:]
     k = x.shape[-1]
     assert k <= k1 * k0, (x.shape, rhs4.shape)
     lead = x.shape[:-1]
@@ -153,11 +159,6 @@ def encoded_matmul(
     backend, blocks = choice.backend, choice.blocks
     if k != k1 * k0:  # K padding lives in the packed weight; mirror it on lhs.
         x2d = jnp.pad(x2d, ((0, 0), (0, k1 * k0 - k)))
-
-    if backend == "reference":
-        w_t = ref.unpack(rhs4, (n, k1 * k0))[:, :k]
-        out = ref.matmul_reference(x2d[:, :k], w_t).astype(out_dtype)
-        return out.reshape(*lead, n)
 
     if backend == "fused":
         if phase is Phase.DECODE and m <= _FUSED_GEMV_MAX_ROWS:
@@ -183,12 +184,21 @@ def encoded_matmul(
             )
             if bn1 is not None:
                 out2d = fused_gemv_lib.fused_gemv_pallas(
-                    xp, rhs4, bn1=bn1, out_dtype=jnp.float32,
-                    interpret=interpret,
+                    xp, rhs4, bn1=bn1, layer=layer, interpret=interpret
                 )
                 return out2d[:m, :n].astype(out_dtype).reshape(*lead, n)
             # VMEM can't hold the resident row block even at bn1=1:
             # fall through to the 128-row GEMM slab path below.
+
+    if layer is not None:
+        rhs4 = jax.lax.dynamic_index_in_dim(rhs4, layer, keepdims=False)
+
+    if backend == "reference":
+        w_t = ref.unpack(rhs4, (n, k1 * k0))[:, :k]
+        out = ref.matmul_reference(x2d[:, :k], w_t).astype(out_dtype)
+        return out.reshape(*lead, n)
+
+    if backend == "fused":
         xp = _pad_rows(x2d, 128)
         want = blocks if blocks is not None else (4, 2, 4)
         # Clamp to divisors of this shape's tile counts: tuned/explicit blocks
@@ -269,13 +279,14 @@ def _fused_gemv_plan(
     (rows, bn1*N0) f32 output slab resident alongside each streamed weight
     block.  Pallas double-buffers every operand (the resident lhs too), so
     the kernel holds two of each; the weight tiles feed the MXU as stored,
-    with no relayouted copy (fused_gemv.stream_k_tiles).  That footprint
-    must fit the kernel's scoped VMEM less an eighth kept for Mosaic's own
-    scratch: at qwen2-1.5b's down projection (K = 8960, one weight tile
-    2.2 MiB) this admits bn1 = 3, the widest block the v5e compiler
-    accepts.  `per_tile_bytes` overrides the dense-rhs tile footprint for
-    formats whose streamed bytes are not k1*n0*k0*itemsize (the
-    nibble-packed w4a8 tile + its scales).
+    with no relayouted copy (fused_gemv.py).  That footprint must fit the
+    kernel's scoped VMEM less an eighth kept for Mosaic's own scratch (the
+    bf16 kernel's per-slab (rows, bn1*N0) dot result among it): at
+    qwen2-1.5b's down projection (K = 8960, one weight tile 2.2 MiB) this
+    admits bn1 = 3 at 8 rows and 2 at 64, and the v5e compiler refuses 4.
+    `per_tile_bytes` overrides the dense-rhs tile footprint for formats
+    whose streamed bytes are not k1*n0*k0*itemsize (the nibble-packed w4a8
+    tile + its scales).
     """
     budget = target.vmem_bytes * 7 // 8
     lhs_bytes = rows * k1 * k0 * lhs_itemsize

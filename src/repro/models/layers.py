@@ -511,7 +511,7 @@ def attention_apply(
         wo_w = params["wo"].get("w_packed", params["wo"].get("w_q"))
         if cfg.tp_attn_expand_kv and cfg.pad_attn_heads_to and wo_w is not None:
             hp = h + (-h) % cfg.pad_attn_heads_to
-            k1_cap = wo_w.shape[1] * wo_w.shape[3]
+            k1_cap = wo_w.shape[-3] * wo_w.shape[-1]
             keep_pad = hp * hd <= k1_cap
         # Chunked prefill: attend over previously-cached positions too
         # (static pos offset; dense cache only — window ring excluded).

@@ -192,6 +192,25 @@ def _run_encoder(params, frames, cfg, enc, phase):
     return L.norm_apply(params["enc_final_norm"], x, cfg)
 
 
+def _layer_view(tree, i):
+    """Layer i of a layer scan's stacked parameters.  Each unquantized
+    packed weight of a plain linear, a (layers, N1, K1, N0, K0) stack,
+    stays whole with `layer` = i beside it: the fused decode GEMV streams
+    layer i from the stack in place (`ops.encoded_matmul`), where a slice
+    would copy every layer's weights once more a step.  Every other leaf,
+    expert stacks included, is sliced."""
+    if isinstance(tree, dict):
+        if "w_packed" in tree and tree["w_packed"].ndim == 5:
+            rest = {k: v for k, v in tree.items() if k != "w_packed"}
+            return {**_layer_view(rest, i), "w_packed": tree["w_packed"], "layer": i}
+        return {k: _layer_view(v, i) for k, v in tree.items()}
+    if isinstance(tree, (tuple, list)):
+        return type(tree)(_layer_view(v, i) for v in tree)
+    if tree is None:
+        return None
+    return jax.lax.dynamic_index_in_dim(tree, i, keepdims=False)
+
+
 def forward(
     params: dict,
     batch: dict,
@@ -301,11 +320,10 @@ def forward(
             xc, aux, pools = carry
             i, gc = xs
             # The layer's parameters are sliced here, under their own scope,
-            # so that a trace names the per-layer weight copies.
+            # so that a trace names the per-layer copies; packed weights are
+            # not (`_layer_view`).
             with jax.named_scope("layer_params"):
-                gp = jax.tree.map(
-                    lambda a: jax.lax.dynamic_index_in_dim(a, i, keepdims=False), groups
-                )
+                gp = _layer_view(groups, i)
             layer_caches = tuple(
                 c if p is None else {**p, "table": c["table"] + i * whole["k"].shape[1]}
                 for c, p, whole in zip(gc, pools, group_caches)

@@ -66,19 +66,25 @@ def _weight(pack, n, k):
     return jax.eval_shape(pack, jax.ShapeDtypeStruct((n, k), jnp.bfloat16))
 
 
+DECODE_MATMULS = [(Q, D, "q_o"), (KV, D, "k_v"), (FF, D, "gate_up"), (D, FF, "down")]
+
+
 @pytest.mark.parametrize(
-    "n,k", [(Q, D), (KV, D), (FF, D), (D, FF)], ids=["q_o", "k_v", "gate_up", "down"]
+    "rows,n,k",
+    [pytest.param(SLOTS, n, k, id=name) for n, k, name in DECODE_MATMULS]
+    + [pytest.param(64, n, k, id=f"m64_{name}") for n, k, name in DECODE_MATMULS],
 )
-def test_decode_fused_gemv_compiles(one_chip, n, k):
-    """Decode matmul, 8 slots: the registry's fused GEMV with its tuned bn1,
-    capped by the VMEM plan (down, K = 8960, is the widest weight tile)."""
+def test_decode_fused_gemv_compiles(one_chip, rows, n, k):
+    """Decode matmul at 8 slots and at the benchmark's 64: the registry's
+    fused GEMV with its tuned bn1, capped by the VMEM plan (down, K = 8960,
+    is the widest weight tile)."""
     w = _weight(ops.pack_rhs, n, k)
     _compile(
         lambda x, w: ops.encoded_matmul(
             x, w, n=n, phase=Phase.DECODE, backend="auto",
             target=targets.TPU_V5E, interpret=False,
         ),
-        one_chip, jax.ShapeDtypeStruct((SLOTS, k), jnp.bfloat16), w,
+        one_chip, jax.ShapeDtypeStruct((rows, k), jnp.bfloat16), w,
     )
 
 
@@ -203,3 +209,41 @@ def test_paged_decode_step_writes_the_pool_in_place(one_chip):
     for j, (path, leaf) in enumerate(cache_leaves):
         if leaf.ndim == 5:  # k, v: (layers, pages, bs, KV, D)
             assert alias.get(2 + j) == first_in + j, (jax.tree_util.keystr(path), alias)
+
+
+def test_decode_step_streams_packed_weights_in_place(one_chip):
+    """The engine's decode step at the benchmark's 64 slots, 3 layers at
+    qwen2-1.5b's widths: each fused GEMV takes its projection's whole
+    (layers, N1, K1, N0, K0) stack and streams one layer's blocks from it,
+    so no op of the program makes a copy of one layer's packed weight."""
+    layers, slots, pages = 3, 64, 1 + 64 * MAX_SEQ // BLOCK
+    cfg = dataclasses.replace(registry.get_config("qwen2-1.5b"), num_layers=layers)
+    enc = EncodingConfig(enabled=True, backend="auto", attn_backend="auto",
+                         target=targets.TPU_V5E, interpret=False)
+    params = jax.eval_shape(lambda: T.model_init(jax.random.PRNGKey(0), cfg, enc))
+    caches = jax.eval_shape(lambda: T.cache_init(
+        cfg, slots, MAX_SEQ, cache_mode="paged", block_size=BLOCK, num_pages=pages))
+    args = (params, caches, jax.ShapeDtypeStruct((slots, 1), jnp.int32),
+            jax.ShapeDtypeStruct((slots,), jnp.int32))
+    placed = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip), args
+    )
+    step = jax.jit(engine.make_decode_step(cfg, enc), donate_argnums=(1,), keep_unused=True)
+    hlo = step.lower(*placed).compile().as_text()
+
+    stacks = {
+        ",".join(map(str, w.shape))
+        for path, w in jax.tree_util.tree_flatten_with_path(params["groups"])[0]
+        if jax.tree_util.keystr(path).endswith("['w_packed']")
+    }
+    assert len(stacks) == 4, stacks  # q/o, k/v, gate/up, down
+    gemv_weights = set(re.findall(
+        r"custom-call\(.*operand_layout_constraints=\{s32\[1\]\{0\}, bf16\[[\d,]+\]"
+        r"\{[\d,]+\}, bf16\[([\d,]+)\].*fused_gemv", hlo
+    ))
+    assert gemv_weights == stacks, (gemv_weights, stacks)
+    layer_copies = [
+        (name, dims) for name, dims in re.findall(r"%(\S+) = bf16\[([\d,]+)\]", hlo)
+        if any(dims == s.split(",", 1)[1] for s in stacks)
+    ]
+    assert not layer_copies, layer_copies

@@ -399,3 +399,16 @@ def test_attn_checked_in_table_covers_serving_buckets():
             choice = registry.select_attn(phase=phase, s=s)
             assert choice.source == "tuned", (phase, s)
             assert choice.backend in registry.ATTN_BACKENDS
+
+
+def test_kernel_bench_tune_refuses_interpret_mode_timings(monkeypatch, tmp_path):
+    """`kernel_bench --tune` writes tpu-v5e keys, so without a TPU backend
+    (interpret-mode kernels) it refuses before measuring and leaves the
+    table unwritten."""
+    from benchmarks import kernel_bench
+
+    monkeypatch.setattr(targets_lib, "has_tpu_backend", lambda: False)
+    out = tmp_path / "tuned_table.json"
+    with pytest.raises(SystemExit, match="interpret"):
+        kernel_bench.tune(str(out))
+    assert not out.exists()
