@@ -1288,7 +1288,7 @@ class Engine:
                 min(1 << (maxlen - 1).bit_length(),
                     self.num_blocks * self.block_size),
             )
-            with self.tracer.span("engine.prefill"):
+            with self.tracer.span("engine.prefill", seqs=len(batch), padded=lp):
                 toks = np.zeros((len(batch), lp), np.int32)
                 for i, (_, r, _) in enumerate(batch):
                     toks[i, : len(r.prompt)] = r.prompt
@@ -1297,6 +1297,7 @@ class Engine:
                     "prefill", "prefill_fn", self.params, jnp.asarray(toks), tmp
                 )
             self.tracer.add("prefill_tokens", sum(len(r.prompt) for _, r, _ in batch))
+            self.tracer.add("prefill_padded_tokens", len(batch) * lp)
             self._scatter_prefill(tmp, batch)
         for s, r, plan, lead in suffix:
             self._prefill_suffix(r, plan, lead)
@@ -1393,7 +1394,7 @@ class Engine:
         skip = lead * bs
         plen = len(req.prompt)
         lp = max(bs, min(1 << (plen - 1).bit_length(), self.num_blocks * bs))
-        with self.tracer.span("engine.prefill"):
+        with self.tracer.span("engine.prefill", seqs=1, padded=lp - skip):
             tmp = self.prefill_cache_fn(1, lp)
             tmp = self._gather_prefix(tmp, plan.pages[:lead])
             toks = np.zeros((1, lp - skip), np.int32)
@@ -1403,6 +1404,7 @@ class Engine:
                 tmp, skip,
             )
         self.tracer.add("prefill_tokens", plen - skip)
+        self.tracer.add("prefill_padded_tokens", lp - skip)
         self._scatter_prefill(tmp, [(None, req, plan)])
 
     def _live_table_width(self) -> int:
@@ -1722,9 +1724,9 @@ class Engine:
             # rounds up to a power of two so the jitted prefill compiles for
             # O(slots * log(max_seq)) shapes, not one per distinct maxlen.
             slots_sel = [s for s, _ in batch]
-            with self.tracer.span("engine.prefill"):
-                maxlen = max(len(r.prompt) for _, r in batch)
-                maxlen = min(1 << (maxlen - 1).bit_length(), self.max_seq)
+            maxlen = max(len(r.prompt) for _, r in batch)
+            maxlen = min(1 << (maxlen - 1).bit_length(), self.max_seq)
+            with self.tracer.span("engine.prefill", seqs=len(batch), padded=maxlen):
                 toks = np.zeros((len(batch), maxlen), np.int32)
                 for i, (_, r) in enumerate(batch):
                     toks[i, : len(r.prompt)] = r.prompt
@@ -1736,10 +1738,11 @@ class Engine:
                 self.caches = slot_merge(
                     self.caches, part, slots_sel, list(range(len(batch)))
                 )
+            self.tracer.add("prefill_padded_tokens", len(batch) * maxlen)
         else:
             for s, r in batch:
                 # Per-slot prefill: batch of 1 through a slot-sliced cache view.
-                with self.tracer.span("engine.prefill"):
+                with self.tracer.span("engine.prefill", seqs=1, padded=len(r.prompt)):
                     toks = jnp.asarray(r.prompt, jnp.int32)[None]
                     slot_cache = slot_slice(self.caches, s)
                     _, slot_cache = self._dispatch(
@@ -1747,6 +1750,7 @@ class Engine:
                     )
                 with self.tracer.span("engine.scatter"):
                     self.caches = slot_merge(self.caches, slot_cache, [s], [0])
+                self.tracer.add("prefill_padded_tokens", len(r.prompt))
         self.tracer.add("prefill_tokens", sum(len(r.prompt) for _, r in batch))
         for s, r in batch:
             self.tracer.note("admitted", r.uid)
@@ -2295,6 +2299,7 @@ class Engine:
             )
         tr.add("decode_rows", len(decode_rows))
         tr.add("prefill_tokens", sum(chunks.values()))
+        tr.add("prefill_padded_tokens", len(prefill_rows) * L)
         self._note_attn_blocks(active)
         bad = self._guard_slots(logits, active)
         # tgt[s, j]: the greedy token after consuming mat[s, :idx[s, j]+1].

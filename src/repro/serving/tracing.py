@@ -13,14 +13,16 @@ and times itself with the engine's clock:
 `step(index, slots)` opens the record of one `Engine.step` and its
 `engine.step` span.  A record holds the step's index, start and end on the
 engine clock, seconds per span name, and the step's counters (`decode_rows`,
-`slots`, `prefill_tokens`, `emitted`, `admitted` / `finished` / `preempted`
-request uids, `pages_in_use`, `pages_total`, `tables_refreshed`, and
-`attn_blocks_live` / `attn_blocks_grid`: the paged decode kernel's blocks of
-pages that hold live pages and that its grid spans, over the step's
-dispatches, and `scatter_pages`: the prompt pages admission wrote into the
-pool, which the `engine.scatter` span's args give too, with `padded`, the
-length of the write's index vectors).  The uids link a request to the steps
-that served it.
+`slots`, `prefill_tokens` and `prefill_padded_tokens`: the real prompt
+tokens prefilled and the token rows the prefill computed, padding included,
+which the `engine.prefill` span's args give as `seqs` x `padded`;
+`emitted`, `admitted` / `finished` / `preempted` request uids,
+`pages_in_use`, `pages_total`, `tables_refreshed`, and `attn_blocks_live` /
+`attn_blocks_grid`: the paged decode kernel's blocks of pages that hold
+live pages and that its grid spans, over the step's dispatches, and
+`scatter_pages`: the prompt pages admission wrote into the pool, which the
+`engine.scatter` span's args give too, with `padded`, the length of the
+write's index vectors).  The uids link a request to the steps that served it.
 
 Records live in a ring of RING_STEPS steps.  `records(t0, t1)` returns the
 steps that ended in [t0, t1], and raises where the ring has dropped a step
@@ -47,9 +49,9 @@ RING_STEPS = 65536
 
 # Counters summed into the tracer's lifetime totals (list-valued ones by
 # their length).  pages_in_use / pages_total / slots are gauges.
-ADDITIVE = ("decode_rows", "prefill_tokens", "emitted", "admitted", "finished",
-            "preempted", "tables_refreshed", "attn_blocks_live", "attn_blocks_grid",
-            "scatter_pages")
+ADDITIVE = ("decode_rows", "prefill_tokens", "prefill_padded_tokens", "emitted",
+            "admitted", "finished", "preempted", "tables_refreshed", "attn_blocks_live",
+            "attn_blocks_grid", "scatter_pages")
 
 _LIVE: weakref.WeakSet = weakref.WeakSet()
 
@@ -68,6 +70,7 @@ class StepRecord:
     slots: int = 0
     decode_rows: int = 0        # rows that decoded in the step's dispatch
     prefill_tokens: int = 0     # real prompt tokens prefilled
+    prefill_padded_tokens: int = 0  # token rows the prefill computed, padding included
     emitted: int = 0            # tokens committed
     admitted: list[int] = dataclasses.field(default_factory=list)
     finished: list[int] = dataclasses.field(default_factory=list)

@@ -247,3 +247,49 @@ def test_decode_step_streams_packed_weights_in_place(one_chip):
         if any(dims == s.split(",", 1)[1] for s in stacks)
     ]
     assert not layer_copies, layer_copies
+
+
+# qwen2.5-14b (benchmarks/serve/configs/qwen2.5-14b-d8.json): d_model 5120,
+# 40 query heads over 8 KV heads of 128 (groups of 5), d_ff 13824, and an
+# untied 152064-row head; 8 slots, prompts padded up to 4096, max_seq 4128.
+D14, KV14, FF14, VOCAB14 = 5120, 1024, 13824, 152064
+HEADS14, KV_HEADS14, SLOTS14, PROMPT14, MAX_SEQ14 = 40, 8, 8, 4096, 4128
+
+
+@pytest.mark.parametrize(
+    "n,k",
+    [(D14, D14), (KV14, D14), (FF14, D14), (D14, FF14), (VOCAB14, D14)],
+    ids=["q_o", "k_v", "gate_up", "down", "head"],
+)
+def test_qwen2_5_14b_decode_gemv_compiles(one_chip, n, k):
+    """The m8 decode GEMV at the 14b's widths: its VMEM plan at K = 13824
+    (down) and at the 152064-row untied head."""
+    w = _weight(ops.pack_rhs, n, k)
+    _compile(
+        lambda x, w: ops.encoded_matmul(
+            x, w, n=n, phase=Phase.DECODE, backend="auto",
+            target=targets.TPU_V5E, interpret=False,
+        ),
+        one_chip, jax.ShapeDtypeStruct((SLOTS14, k), jnp.bfloat16), w,
+    )
+
+
+def test_qwen2_5_14b_attention_compiles(one_chip):
+    """Paged decode attention with groups of 5 over the longdoc cell's pool
+    and its widest block table, and causal flash prefill over 8 prompts
+    padded to 4096."""
+    blocks = -(-MAX_SEQ14 // BLOCK)
+    pages = 1 + SLOTS14 * blocks
+    q = jax.ShapeDtypeStruct((SLOTS14, 1, HEADS14, HEAD_DIM), jnp.bfloat16)
+    pool = jax.ShapeDtypeStruct((pages, BLOCK, KV_HEADS14, HEAD_DIM), jnp.bfloat16)
+    _compile(
+        lambda q, k, v, t, p: attn.paged_decode_attention(q, k, v, t, p),
+        one_chip, q, pool, pool, jax.ShapeDtypeStruct((SLOTS14, blocks), jnp.int32),
+        jax.ShapeDtypeStruct((SLOTS14,), jnp.int32),
+    )
+    q = jax.ShapeDtypeStruct((SLOTS14, PROMPT14, HEADS14, HEAD_DIM), jnp.bfloat16)
+    kv = jax.ShapeDtypeStruct((SLOTS14, PROMPT14, KV_HEADS14, HEAD_DIM), jnp.bfloat16)
+    _compile(
+        lambda q, k, v: attn.flash_prefill_attention(q, k, v, q_chunk=128, kv_chunk=128),
+        one_chip, q, kv, kv,
+    )

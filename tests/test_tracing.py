@@ -205,6 +205,8 @@ def test_other_step_paths_use_the_same_names(kw):
     assert all(r.decode_rows <= r.slots for r in recs)
     assert all(0 < r.attn_blocks_live <= r.attn_blocks_grid for r in recs if r.decode_rows)
     assert sum(r.emitted for r in recs) == sum(len(q.generated) for q in eng.finished)
+    assert sum(r.prefill_tokens for r in recs) == sum(len(q.prompt) for q in eng.finished)
+    assert all(r.prefill_padded_tokens >= r.prefill_tokens for r in recs)
 
 
 def test_scatter_pages_counter_and_padded_arg():
@@ -234,3 +236,50 @@ def test_scatter_pages_counter_and_padded_arg():
     want = sum(-(-n // eng.block_size) for n in prompts.values())
     assert sum(a["pages"] for a in args) == want
     assert eng.stats["trace"]["counters"]["scatter_pages"] == want
+
+
+@pytest.mark.parametrize("cache_mode,slots", [("paged", 3), ("dense", 3), ("dense", 1)],
+                         ids=["paged_batched", "dense_batched", "per_slot"])
+def test_prefill_padded_tokens_count_the_rows_the_prefill_computes(cache_mode, slots):
+    """`prefill_padded_tokens` is sequences x padded length of each prefill,
+    and the `engine.prefill` span's args give both; `prefill_tokens` stays
+    the real prompt tokens.  A paged batch pads to a power of two of at
+    least one block, a dense batch to a power of two, and a dense
+    admission of one request is prefilled alone, unpadded."""
+    eng = engine_lib.Engine(PARAMS, CFG, ENC, max_seq=64, cache_mode=cache_mode, slots=slots)
+    rng = np.random.RandomState(2)
+    for uid, n in enumerate((5, 20, 9, 33, 12)):
+        prompt = rng.randint(1, CFG.vocab_size, n).astype(np.int32)
+        assert eng.submit(engine_lib.Request(uid=uid, prompt=prompt, max_new_tokens=3))
+    args = []
+    span = eng.tracer.span
+
+    def recorded(name, **kw):
+        if name == "engine.prefill":
+            args.append(kw)
+        return span(name, **kw)
+
+    eng.tracer.span = recorded
+    while eng.queue or any(r is not None for r in eng.slot_req):
+        eng.step()
+    prompts = {q.uid: len(q.prompt) for q in eng.finished}
+    recs = [r for r in eng.tracer.records(float("-inf"), float("inf")) if r.admitted]
+    want_args = []
+    for r in recs:
+        lens = [prompts[u] for u in r.admitted]
+        pow2 = 1 << (max(lens) - 1).bit_length()
+        if cache_mode == "paged":
+            pads = [(len(lens), max(eng.block_size, pow2))]
+        elif len(lens) > 1:
+            pads = [(len(lens), min(pow2, eng.max_seq))]
+        else:
+            pads = [(1, n) for n in lens]
+        want_args += [{"seqs": s, "padded": p} for s, p in pads]
+        assert r.prefill_tokens == sum(lens)
+        assert r.prefill_padded_tokens == sum(s * p for s, p in pads)
+    assert args == want_args
+    assert len(recs) > 1 and any(r.prefill_padded_tokens > r.prefill_tokens for r in recs) == (
+        slots > 1)
+    counters = eng.stats["trace"]["counters"]
+    assert counters["prefill_padded_tokens"] == sum(r.prefill_padded_tokens for r in recs)
+    assert counters["prefill_tokens"] == sum(prompts.values())
